@@ -1,6 +1,6 @@
 """Analytic per-op FLOP counting over the abstract interpreter's shapes.
 
-The MFU numbers in BENCH_r02–r05 come from XLA's own ``cost_analysis``
+bench.py's MFU comes from XLA's own ``cost_analysis``
 on the compiled train step — honest, but only available AFTER a
 compile and only for the whole program.  This pass counts FLOPs
 *statically*, per node, from the same per-node concrete shapes the

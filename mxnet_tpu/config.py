@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import os
 
-__all__ = ["get", "describe", "VARIABLES"]
+__all__ = ["get", "describe", "VARIABLES", "compile_cache_dir"]
 
 
 class _Var(object):
@@ -53,9 +53,10 @@ VARIABLES = {v.name: v for v in [
          "'auto' = the 2D row-layout Pallas kernels where their VMEM "
          "model fits, else the XLA segment; '2d'/'4d' force the row- or "
          "spatial-layout Pallas kernels (subject to their fit gates); "
-         "'xla' = always the XLA segment.  PROFILE_r05.md carries the "
-         "per-path measurements (2d > 4d; all still behind plain XLA "
-         "units on v5e, hence unit_impl='fused' is off by default)."),
+         "'xla' = always the XLA segment.  An earlier chip record, "
+         "since deleted, had 2d ahead of 4d and all of them behind "
+         "plain XLA units on v5e, hence unit_impl='fused' is off by "
+         "default."),
     _Var("MXNET_CPU_WORKER_NTHREADS", int, 4,
          "Default worker-thread count for host-side pipelines "
          "(ImageRecordIter preprocess_threads default; the reference's "
@@ -82,16 +83,17 @@ VARIABLES = {v.name: v for v in [
     _Var("MXNET_CONV_DOT_1X1", bool, False,
          "Lower channels-last 1x1 convolutions (and their dgrad/wgrad "
          "transposes) to explicit lax.dot_general MXU matmuls instead of "
-         "XLA's conv codegen.  Measured on v5e-1 (PROFILE_r04.md): SLOWER "
-         "for ResNet-50 (80.2 vs 75.9 ms biased / confirms on honest "
-         "protocol) because the step is HBM-bound and the dot forms fuse "
-         "worse, so the default stays off; kept as a measured experiment."),
+         "XLA's conv codegen.  An earlier chip record, since deleted, "
+         "had it SLOWER for ResNet-50 on v5e (80.2 vs 75.9 ms): the step "
+         "is HBM-bound and the dot forms fuse worse.  Off by default; "
+         "not measured on today's code."),
     _Var("MXNET_CONV1X1_FUSED_BWD", bool, False,
          "Compute a channels-last stride-1 1x1 convolution's dgrad AND "
          "wgrad in one Pallas kernel pass over the output gradient "
          "(XLA emits two fusions that each re-read dy from HBM; the step "
-         "is bandwidth-bound, PROFILE_r04.md).  Off by default pending "
-         "the measured verdict recorded there."),
+         "is bandwidth-bound).  Off by default: an earlier chip "
+         "record, since deleted, had it slower than the two XLA "
+         "fusions."),
     _Var("MXNET_SERVE_MAX_BATCH", int, 8,
          "Largest batch bucket the serving engine compiles and "
          "coalesces to (mxnet_tpu/serving).  Rounded up to a power of "
@@ -196,11 +198,12 @@ VARIABLES = {v.name: v for v in [
          "pre-coalescing engine."),
     _Var("MXNET_CACHE_SCATTER_IMPL", str, "auto",
          "Implementation of the _cache_write_row scatter-at-index op "
-         "(ops/cache.py): 'auto' = Pallas kernel on TPU, vmapped "
+         "(ops/cache.py): 'auto' = Pallas kernel on TPU for the rank-3 "
+         "(slots, max_len, d) pool it tiles, vmapped "
          "jax.lax.dynamic_update_slice elsewhere; 'pallas' forces the "
          "kernel; 'interpret' runs the Pallas kernel in interpreter "
-         "mode on any backend (CI's bitwise pin of the kernel on CPU "
-         "hosts); 'xla' forces the dynamic_update_slice fallback "
+         "mode on any backend (the tests' bitwise pin of the kernel on "
+         "CPU hosts); 'xla' forces the dynamic_update_slice fallback "
          "everywhere."),
     _Var("MXNET_OPT_SELECT_KERNELS", bool, True,
          "Fused-op selection stage of the graph optimizer "
@@ -556,6 +559,35 @@ def get(name):
         raise KeyError("unknown config variable %r (known: %s)"
                        % (name, sorted(VARIABLES)))
     return VARIABLES[name].read()
+
+
+def compile_cache_dir():
+    """Turn on JAX's persistent compilation cache for a script of this
+    checkout and return the directory it lives in.
+
+    The directory is placed from outside: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, and no
+    directory is set in code.  Otherwise it is ``<checkout>/.jax_cache``
+    — fixed, because the path is part of the cache key, so a temporary
+    or per-process directory never hits.  Either way the size and
+    compile-time thresholds are zeroed so the small serving programs
+    are cached too.  Call it before the first compile."""
+    import jax
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    if jax.config.jax_compilation_cache_dir != path:
+        jax.config.update("jax_compilation_cache_dir", path)
+        # jax latches "no cache" at the first compile that ran before a
+        # directory was configured; start over so this one is used
+        from jax.experimental.compilation_cache import compilation_cache
+        compilation_cache.reset_cache()
+    return path
 
 
 def describe():

@@ -89,10 +89,13 @@ class Context:
                     else jax.local_devices())
         elif dt == "tpu":
             devs = jax.local_devices(backend="tpu")
-        else:  # 'gpu' → any accelerator (tpu preferred), else cpu
+        else:  # 'gpu' → any accelerator (tpu preferred)
             devs = _accelerators()
             if not devs:
-                devs = jax.local_devices()
+                raise RuntimeError(
+                    "%s: this process has no accelerator backend (jax "
+                    "sees only %s); use mx.cpu() to run on the host"
+                    % (self, jax.default_backend()))
         if self.device_id >= len(devs):
             raise ValueError("%s: device_id out of range (%d available)"
                              % (self, len(devs)))

@@ -44,8 +44,7 @@ def imdecode(buf, to_rgb=True, flag=1):
     if _cv2 is not None:
         if flag and to_rgb and hasattr(_cv2, "IMREAD_COLOR_RGB"):
             # OpenCV >= 4.10 decodes straight to RGB — saves the BGR->RGB
-            # reversal copy (~1/3 of decode cost on 256p JPEGs, measured in
-            # PROFILE_r04.md's pipeline section)
+            # reversal copy (~1/3 of decode cost on 256p JPEGs)
             img = _cv2.imdecode(data, _cv2.IMREAD_COLOR_RGB)
             if img is None:
                 raise MXNetError("imdecode failed (invalid image data)")

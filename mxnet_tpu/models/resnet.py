@@ -91,8 +91,7 @@ def _residual_unit(data, num_filter, stride, dim_match, name,
 def _s2d_stem(data, num_filter, height, layout):
     """The 7x7/s2 stem as an EXACT space-to-depth reformulation.
 
-    The C=3 input wastes 125/128 MXU lanes (PROFILE_r03.md lever 1; the
-    MLPerf ResNet trick).  Identity used: pad the kernel's 7x7 taps to
+    The C=3 input wastes 125/128 MXU lanes (the MLPerf ResNet trick).  Identity used: pad the kernel's 7x7 taps to
     8x8 (one zero row/col in front), space-to-depth both kernel and image
     by 2, and the conv becomes 4x4/s1 over 12 channels — identical math
     (2y+i-3 = 2(y+a)+b with i+1 = 2A+b), so conv0_weight keeps its
@@ -145,7 +144,7 @@ def _resnet(units, num_stages, filter_list, num_classes, image_shape,
         elif fused_stem:
             # fused input-BN + stem conv: identical math, but backward
             # computes bn_data's dbeta via rectangle sums instead of a full
-            # stem dgrad (ops/nn.py _contrib_BNStemConv; PROFILE_r04.md).
+            # stem dgrad (ops/nn.py _contrib_BNStemConv).
             # Parameter/aux names match the unfused graph exactly, so
             # checkpoints are interchangeable.
             body = sym._contrib_BNStemConv(
@@ -197,7 +196,7 @@ def _resnet(units, num_stages, filter_list, num_classes, image_shape,
         if fuse_run:
             # chain the whole dim-match run in the 2D row layout: ONE
             # pair of reshapes per stage instead of relayout copies at
-            # every unit boundary (PROFILE_r05 blocker #2)
+            # every unit boundary
             body = sym.Reshape(body, shape=(-1, filter_list[i + 1]),
                                name="stage%d_rows" % (i + 1))
             for j in range(rest):

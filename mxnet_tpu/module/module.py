@@ -289,6 +289,16 @@ class Module(BaseModule):
         # in a launched dist job, default to the fused sharded step:
         # user-facing shapes stay LOCAL, the compiled program is GLOBAL
         self._maybe_auto_dist_plan()
+        if len(self._context) > 1 and self._plan is None:
+            # one executor binds on one device: a context list without a
+            # plan would train on self._context[0] alone, in silence
+            raise MXNetError(
+                "Module(context=%s): %d contexts but no sharding plan. "
+                "Call set_sharding_plan(ShardingPlan(make_mesh({'dp': "
+                "%d}), batch_axis='dp')) before bind() to train "
+                "data-parallel across them, or pass one context."
+                % (self._context, len(self._context),
+                   len(self._context)))
         gdata = self._global_shapes(self._data_shapes)
         glabel = self._global_shapes(self._label_shapes or []) or None
 

@@ -16,17 +16,18 @@ generated token).  ``_cache_write_row`` states the scatter directly:
 
     out[i, pos[i], :] = row[i, :]        (every other element unchanged)
 
-- **TPU**: a Pallas kernel (one grid step per slot, the write position
-  scalar-prefetched, the cache aliased input->output) touches exactly
-  the d elements being written — O(d) per slot per token, never
-  O(max_len * d);
-- **CPU / fallback**: a vmapped ``jax.lax.dynamic_update_slice`` —
-  XLA lowers it to an in-place row update when the buffer is donated,
-  so tier-1 (CPU) exercises the same graph shape and the same O(1)
-  cache discipline;
+- **TPU, rank-3 cache**: a Pallas kernel (write positions
+  scalar-prefetched, the cache aliased input->output) reads, patches
+  and writes back only the sublane tile(s) that hold the written rows
+  — O(d) per slot per token, never O(max_len * d);
+- **CPU / any other rank**: a vmapped ``jax.lax.dynamic_update_slice``
+  — XLA lowers it to an in-place row update when the buffer is
+  donated, so tier-1 (CPU) exercises the same graph shape and the same
+  O(1) cache discipline;
 - ``MXNET_CACHE_SCATTER_IMPL=interpret`` runs the Pallas kernel in
-  interpreter mode on any backend — how CPU CI pins the kernel
-  bitwise against the XLA fallback without TPU hardware.
+  interpreter mode on any backend — how the tests pin the kernel
+  bitwise against the XLA fallback without TPU hardware
+  (tests/test_chip_compile.py compiles it for a described v5e).
 
 Bitwise contract (tests/test_decode_fastpath.py): for finite cache
 values the scatter is bitwise-identical to the one-hot blend it
@@ -52,19 +53,21 @@ import numpy as np
 from .registry import register, P
 
 
-def _impl_mode():
+def _impl_mode(cache):
     """Which implementation this dispatch should trace.
 
-    ``MXNET_CACHE_SCATTER_IMPL``: ``auto`` (Pallas on TPU, XLA
+    ``MXNET_CACHE_SCATTER_IMPL``: ``auto`` (the Pallas kernel on TPU
+    for the rank-3 ``(slots, max_len, d)`` layout it tiles, XLA
     ``dynamic_update_slice`` elsewhere), ``pallas`` (force the kernel),
-    ``interpret`` (Pallas interpreter — CPU-runnable, CI's bitwise pin
-    of the kernel), ``xla`` (force the fallback everywhere).
+    ``interpret`` (Pallas interpreter — CPU-runnable, the tests'
+    bitwise pin of the kernel), ``xla`` (force the fallback).
     """
     from .. import config
     mode = str(config.get("MXNET_CACHE_SCATTER_IMPL") or "auto").lower()
     if mode == "auto":
         import jax
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        on_tpu = jax.default_backend() == "tpu"
+        return "pallas" if on_tpu and cache.ndim == 3 else "xla"
     return mode
 
 
@@ -79,58 +82,6 @@ def _scatter_xla(cache, row, idx):
         # same containment the engine's pos bookkeeping guarantees
         return jax.lax.dynamic_update_slice_in_dim(c, r[None], p, axis=0)
     return jax.vmap(write_one)(cache, row, idx)
-
-
-def _scatter_pallas(cache, row, idx, interpret):
-    """The Pallas TPU kernel: grid over slots, the per-slot write
-    position scalar-prefetched (available before the kernel body, per
-    the TPU guide), the cache kept UNBLOCKED in HBM (``pltpu.ANY``)
-    and aliased input->output.  Each grid step issues one async DMA of
-    exactly the d-wide row into ``out[i, pos[i]]`` — O(d) data
-    movement per slot per token, and the aliased buffer's other
-    ``max_len - 1`` rows are never read, copied, or written (a BLOCKED
-    VMEM output window would be copied back whole per grid step, which
-    both destroys the O(d) story and — since the kernel writes only
-    one row of the window — would ship uninitialized VMEM over the
-    aliased cache)."""
-    import jax
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n = cache.shape[0]
-    # row reshaped to (N, 1) + tail so the DMA source slice matches the
-    # (1, 1) + tail destination slice rank-for-rank
-    row3 = row.reshape((n, 1) + row.shape[1:])
-
-    def kernel(pos_ref, cache_ref, row_ref, out_ref, sem):
-        # cache_ref is the aliased input view of out_ref; it is never
-        # touched — the single DMA below IS the whole write
-        i = pl.program_id(0)
-        p = pos_ref[i]
-        copy = pltpu.make_async_copy(
-            row_ref.at[pl.ds(i, 1)],
-            out_ref.at[pl.ds(i, 1), pl.ds(p, 1)],
-            sem)
-        copy.start()
-        copy.wait()
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=[pltpu.SemaphoreType.DMA],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
-        # operand order with scalar prefetch: (idx, cache, row3) — the
-        # cache (operand 1) aliases the output for the in-place update
-        input_output_aliases={1: 0},
-        interpret=bool(interpret),
-    )(idx, cache, row3)
 
 
 def _scatter_rows_xla(cache, rows, idx, cnt):
@@ -161,47 +112,61 @@ def _scatter_rows_xla(cache, rows, idx, cnt):
 
 
 def _scatter_rows_pallas(cache, rows, idx, cnt, interpret):
-    """The widened Pallas TPU kernel: grid over (slots, K), the write
-    positions AND accepted counts scalar-prefetched, the cache kept
-    UNBLOCKED in HBM and aliased input->output (exactly the single-row
-    kernel's discipline).  Grid step (i, j) issues one async DMA of
-    row j into ``out[i, pos[i]+j]`` — predicated with ``pl.when`` on
-    ``j < count[i]``, so rejected speculative rows move zero bytes.
-    O(count * d) data movement per slot per speculative window, never
-    O(K * max_len * d)."""
+    """The Pallas TPU kernel behind both ops (the single-row write is
+    the K = 1, count = 1 case).  The chip's DMA engine moves whole
+    ``(sublane, 128)`` tiles — 8 rows of float32, 16 of bfloat16 — so
+    a one-row slice of the cache is below what Mosaic will address.
+    The kernel therefore works a tile at a time: grid step ``(i, w)``
+    takes the w-th tile of slot i's write window as a blocked VMEM
+    window (block index computed from the scalar-prefetched position),
+    patches the rows of ``rows[i]`` that land in it with ``j <
+    count[i]``, and the aliased output window is written back to the
+    same place.  Each step is a pure function of its input tile, so a
+    tile revisited because the window was clamped at the cache end
+    gets the same bytes twice.  O(K * d) traffic per slot whatever
+    ``max_len`` is; every other tile of the aliased cache is never
+    read, copied, or written.  A row whose position falls outside
+    ``[0, max_len)`` matches no tile row and is dropped."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n, K = rows.shape[0], rows.shape[1]
-    max_pos = cache.shape[1] - 1
+    if cache.ndim != 3:
+        raise ValueError(
+            "the Pallas cache scatter tiles a (slots, max_len, d) "
+            "cache; got shape %s" % (tuple(cache.shape),))
+    n, T, d = cache.shape
+    K = rows.shape[1]
+    tile = min(8 * max(1, 4 // cache.dtype.itemsize), T)
+    n_tiles = pl.cdiv(T, tile)
+    # K consecutive rows starting on a tile's last row span this many
+    span = min((K - 1 + tile - 1) // tile + 1, n_tiles)
 
-    def kernel(pos_ref, cnt_ref, cache_ref, rows_ref, out_ref, sem):
-        # cache_ref is the aliased input view of out_ref; never touched
+    def tile_of(i, w, pos_ref):
+        first = jnp.clip(pos_ref[i], 0, T - 1) // tile
+        return jnp.minimum(first + w, n_tiles - 1)
+
+    def kernel(pos_ref, cnt_ref, cache_ref, rows_ref, out_ref):
         i = pl.program_id(0)
-        j = pl.program_id(1)
-        pj = pos_ref[i] + j
-        p = jnp.minimum(jnp.maximum(pj, 0), max_pos)
+        t = tile_of(i, pl.program_id(1), pos_ref)
+        at = jax.lax.broadcasted_iota(jnp.int32, (tile, d), 0) + t * tile
+        blk = cache_ref[0]
+        for j in range(K):
+            hit = jnp.logical_and(at == pos_ref[i] + j, j < cnt_ref[i])
+            blk = jnp.where(hit, rows_ref[0, pl.ds(j, 1), :], blk)
+        out_ref[0] = blk
 
-        @pl.when(jnp.logical_and(j < cnt_ref[i],
-                                 jnp.logical_and(pj >= 0,
-                                                 pj <= max_pos)))
-        def _():
-            copy = pltpu.make_async_copy(
-                rows_ref.at[pl.ds(i, 1), pl.ds(j, 1)],
-                out_ref.at[pl.ds(i, 1), pl.ds(p, 1)],
-                sem)
-            copy.start()
-            copy.wait()
-
+    cache_spec = pl.BlockSpec(
+        (1, tile, d),
+        lambda i, w, pos_ref, cnt_ref: (i, tile_of(i, w, pos_ref), 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(n, K),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=[pltpu.SemaphoreType.DMA],
+        grid=(n, span),
+        in_specs=[cache_spec,
+                  pl.BlockSpec((1, K, d),
+                               lambda i, w, pos_ref, cnt_ref: (i, 0, 0))],
+        out_specs=cache_spec,
     )
     return pl.pallas_call(
         kernel,
@@ -245,7 +210,7 @@ def _cache_write_rows(attrs, cache, rows, pos, count):
     idx = pos.astype(jnp.int32)
     cnt = jnp.clip(count.astype(jnp.int32), 0, rows.shape[1])
     rows = jnp.asarray(rows, cache.dtype)
-    mode = _impl_mode()
+    mode = _impl_mode(cache)
     if mode in ("pallas", "interpret") and attrs.get("_training"):
         # pallas_call defines no autodiff rule (see _cache_write_row)
         mode = "xla"
@@ -265,14 +230,13 @@ def _cache_write_row(attrs, cache, row, pos):
     + tail``, ``row`` is ``(slots,) + tail``, ``pos`` a ``(slots,)``
     vector of write positions (any real dtype; cast to int32)."""
     import jax.numpy as jnp
-    idx = pos.astype(jnp.int32)
-    if attrs.get("clip", True):
-        # both backends clamp (dynamic_update_slice by contract, the
-        # kernel via this explicit clip) so the op has ONE out-of-range
-        # story instead of a per-backend one
-        idx = jnp.clip(idx, 0, cache.shape[1] - 1)
+    # clamped whatever ``clip`` says: dynamic_update_slice clamps by
+    # contract and the kernel would drop an out-of-range row, so the
+    # explicit clip is what gives the op ONE out-of-range story
+    # instead of a per-backend one
+    idx = jnp.clip(pos.astype(jnp.int32), 0, cache.shape[1] - 1)
     row = jnp.asarray(row, cache.dtype)
-    mode = _impl_mode()
+    mode = _impl_mode(cache)
     if mode in ("pallas", "interpret") and attrs.get("_training"):
         # pallas_call defines no autodiff rule: training graphs trace
         # the differentiable fallback on EVERY backend (mode_dependent
@@ -280,6 +244,7 @@ def _cache_write_row(attrs, cache, row, pos):
         # train-vs-serve parity is unaffected)
         mode = "xla"
     if mode in ("pallas", "interpret"):
-        return _scatter_pallas(cache, row, idx,
-                               interpret=(mode == "interpret"))
+        return _scatter_rows_pallas(cache, row[:, None], idx,
+                                    jnp.ones_like(idx),
+                                    interpret=(mode == "interpret"))
     return _scatter_xla(cache, row, idx)

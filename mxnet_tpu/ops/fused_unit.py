@@ -1,6 +1,6 @@
 """Block-scope fused residual unit — the Pallas kernel tier.
 
-The r4 roofline memo (PROFILE_r04.md) showed the ResNet-50 train step is
+An earlier chip record, since deleted, showed the ResNet-50 train step
 HBM-bound with XLA already within 1.4% of its per-op roofline; the only
 remaining lever is removing PASSES, and the measured failure of the
 1x1-scope attempt (ops/nn.py _fused1x1_bwd_pallas) showed a winning
@@ -146,7 +146,7 @@ def _k_conv3_fwd(x_ref, w_ref, sc_ref, sh_ref, y_ref, s_ref, ss_ref):
 
 # --- 3x3 over the 2D row layout ------------------------------------------
 #
-# PROFILE_r05 isolated two blockers in the 4D 3x3 kernels: Mosaic's
+# Two things held the 4D 3x3 kernels back on the chip: Mosaic's
 # strided spatial slicing of (BN,H,W,C) tiles runs far below line rate,
 # and every 4D<->2D crossing between Pallas and XLA pays a relayout
 # copy.  These kernels keep the SAME flattened (rows, C) layout the 1x1

@@ -34,11 +34,11 @@ from .. import config
 # (jax.checkpoint with save_only_these_names; exercised by
 # `perf/step_bench.py --remat names`) can store ONLY convolution outputs +
 # BN statistics and recompute the BatchNorm-normalize/ReLU elementwise
-# chains in backward.  Measured on v5e-1 ResNet-50 (PROFILE_r04.md): the
-# policy LOST (108.6 vs 94.7 ms/step) — the recompute chains do not fuse
-# into single reads — so nothing in the library applies it by default; the
-# tags stay because checkpoint_name is an identity outside jax.checkpoint
-# contexts and they make the experiment reproducible.
+# chains in backward.  On v5e ResNet-50 (earlier chip record, since
+# deleted) the policy LOST (108.6 vs 94.7 ms/step) — the recompute chains
+# do not fuse into single reads — so nothing in the library applies it by
+# default; the tags stay because checkpoint_name is an identity outside
+# jax.checkpoint contexts and they make the experiment reproducible.
 CKPT_CONV = "conv_out"
 CKPT_STATS = "bn_stats"
 CKPT_POOL = "pool_out"
@@ -131,7 +131,7 @@ def _deconv_fill(attrs, in_shapes):
 # --- 1x1 convolution as an explicit MXU matmul -----------------------------
 #
 # XLA's conv codegen runs ResNet's 1x1 convs (and especially their wgrad
-# transposes at 7x7/14x14 spatial) far below MXU peak (PROFILE_r03.md).
+# transposes at 7x7/14x14 spatial) far below MXU peak.
 # A 1x1 stride-1 conv IS a matmul over the flattened batch*spatial dim, and
 # the strided variants are a subsample (fwd/wgrad) or interior-dilate (dgrad)
 # away, so route them through lax.dot_general with a custom VJP whose dgrad
@@ -201,7 +201,7 @@ def _conv1x1_eligible(attrs, k, pad):
 #
 # XLA lowers a 1x1 conv's backward to two separate fusions — dgrad reads
 # (dy, W) and wgrad reads (dy, x) — so dy crosses HBM twice.  On a
-# bandwidth-bound step (PROFILE_r04.md) that second read is pure waste: a
+# bandwidth-bound step that second read is pure waste: a
 # Pallas kernel tiles over the fused batch*spatial rows, computes the dx
 # tile (dy @ W) AND accumulates the dW partial (dy^T @ x, f32) from the
 # same resident dy tile.  Gated by MXNET_CONV1X1_FUSED_BWD.
@@ -589,9 +589,9 @@ register("BatchNorm", aliases=["batch_norm", "BatchNorm_v1", "batch_norm_v1",
 # before the stem conv (symbols/resnet.py bn_data).  Under autodiff the only
 # live cotangent into that BN is dbeta = sum(dgrad of the stem conv), so the
 # graph pays a full stem dgrad (236 GFLOP at C=3 lane efficiency — 4.4 ms of
-# the 94.7 ms ResNet-50 step, PROFILE_r04.md) to produce a 3-vector.  This op
-# fuses BN+conv with a custom VJP that computes dbeta EXACTLY without the
-# dgrad conv:
+# a 94.7 ms ResNet-50 step in an earlier chip record, since deleted) to
+# produce a 3-vector.  This op fuses BN+conv with a custom VJP that
+# computes dbeta EXACTLY without the dgrad conv:
 #
 #     sum_m dx[m] = sum_{kh,kw} W[kh,kw] * (sum of g over the output
 #                   positions whose window covers tap (kh,kw))
@@ -701,7 +701,7 @@ def _bn_stem_bwd(cfg, res, g):
     # Per-tap rectangle sums via separable masked contractions.  The r4
     # integral-image form subtracted nearly-equal prefix values (magnitude
     # ~ the whole-table sum), which carried cancellation error right at the
-    # test tolerance at 40x40 and worse at 224^2 (VERDICT r4 weak #1); the
+    # test tolerance at 40x40 and worse at 224^2; the
     # masked-matmul form sums each gsum element exactly once per tap, so its
     # error is that of a plain row/column reduction.
     vh = _stem_valid_mask(kh_dim, pad[0], stride[0], in_h, gh)  # (KH, OH)

@@ -6,9 +6,8 @@ src/io/iter_image_recordio_2.cc).  Format: each record is
 where cflag (upper 3 bits) marks multi-part records for payloads containing
 the magic; `IRHeader` prepends (flag, label, id, id2) for image records.
 
-This pure-Python layer is the format/API contract; the C++ fast path
-(mxnet_tpu/src/recordio.cc via ctypes, see mxnet_tpu/lib.py) is used by the
-data pipeline for bulk sequential reads when built.
+This pure-Python layer is the whole implementation: the data pipeline
+(image/iter.py, image/mp_iter.py) reads records through it.
 """
 from __future__ import annotations
 

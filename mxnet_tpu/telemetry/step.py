@@ -2,7 +2,7 @@
 
 The serving stack answers "where did THIS request's 40 ms go?" per
 request; the training loop could not answer the same question per
-step — BENCH_r02–r05 pinned MFU at 0.34–0.42 with no attribution data
+step — bench.py reports one MFU with no attribution data
 to say whether the missing time is input wait, h2d upload, compute
 dispatch, kvstore traffic, the optimizer, or host sync (ROADMAP 5b
 needs exactly that evidence before sharding the weight update).

@@ -79,6 +79,20 @@ def assert_almost_equal(a, b, rtol=None, atol=None, names=("a", "b"),
            af[idx], bf[idx], float(np.nanmax(rel[bad]))))
 
 
+def few_ulp_tol(want):
+    """``(rtol, atol)`` for float32 outputs of two DIFFERENT XLA programs
+    that compute the same rows — the same graph at another batch extent
+    or under another partition.  They may vectorize reductions and exp
+    differently and round apart in the last places, so they compare
+    within 64 ulp of the element or 4 ulp of the output's largest value
+    (a softmax output inherits its logit's rounding scaled by the
+    logit's magnitude: 29 ulp seen on outputs near 1e-15 under jax
+    0.9).  One compiled program against itself stays bitwise; a row
+    served from the wrong request is off by O(1)."""
+    eps = float(np.finfo(np.float32).eps)
+    return 64 * eps, 4 * eps * float(np.abs(_as_np(want)).max())
+
+
 def almost_equal(a, b, rtol=None, atol=None):
     try:
         assert_almost_equal(a, b, rtol, atol)

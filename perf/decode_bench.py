@@ -1078,6 +1078,10 @@ def main(argv=None):
                     help="append the result row to this JSON file "
                          "(BENCH_*.json bookkeeping)")
     args = ap.parse_args(argv)
+    # jax's own cache at the externally placed / fixed in-checkout path:
+    # the temporary AOT entry directories below then never carry it
+    from mxnet_tpu import config
+    config.compile_cache_dir()
 
     if args.spec:
         ks = tuple(sorted({int(t) for t in args.spec_ks.split(",")

@@ -1,5 +1,5 @@
 """dist_async straggler simulation — the measurement behind the decision
-(VERDICT r3 missing #2: close dist_async with numbers, not fiat).
+(close dist_async with numbers, not fiat).
 
 Two measurable quantities decide sync-vs-async:
 

@@ -1,5 +1,4 @@
-"""Structural trace of the n=8 fused data-parallel train step
-(VERDICT r4 item #9).
+"""Structural trace of the n=8 fused data-parallel train step.
 
 The north-star dist configuration (BASELINE.json v5e-16 dist_sync)
 cannot run on this 1-chip harness, so the scaling argument rests on

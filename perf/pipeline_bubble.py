@@ -1,5 +1,4 @@
-"""Pipeline bubble-overhead measurement (VERDICT r4 item #6 'done'
-criterion).
+"""Pipeline bubble-overhead measurement.
 
 GPipe's schedule runs m + n - 1 ticks for m microbatches over n stages;
 the (n-1)/(m+n-1) idle fraction is the bubble.  This measures it as the

@@ -236,6 +236,10 @@ def main(argv=None):
     ap.add_argument("--record", metavar="PATH",
                     help="write the JSON document (BENCH_aot.json)")
     args = ap.parse_args(argv)
+    # jax's own cache at the externally placed / fixed in-checkout path:
+    # the temporary AOT entry directories below then never carry it
+    from mxnet_tpu import config
+    config.compile_cache_dir()
     doc = run_bench(feature=args.feature, hidden=args.hidden,
                     classes=args.classes, layers=args.layers,
                     requests=args.requests,

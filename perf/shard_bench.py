@@ -20,8 +20,11 @@ prescribes.  Three phases:
   persistent AOT cache — the sharded entries must load with ZERO
   traces and serve bitwise (key sharding component, residual b2).
 
-Gates: bitwise equality, 0 warm retraces, and warm-restart
-0-compiles are HARD (they are the correctness contract; host noise
+Gates: equality with the unsharded engine (bitwise for decode tokens
+and for a warm restart of the same programs; to a few ulp for one-shot
+outputs, which the sharded fleet computes at other batch extents and
+under another partition — different XLA programs), 0 warm retraces, and
+warm-restart 0-compiles are HARD (they are the correctness contract; host noise
 cannot excuse them).  Wall-clock ratios are **advisory-only** per the
 README host-noise protocol — this forced-host-device CPU container
 cannot resolve real multi-chip scaling (the BENCH file records the
@@ -80,8 +83,11 @@ def run_serve_shard_sweep(requests=256, offered_batch=8, feature=256,
                           hidden=512, classes=10, layers=4,
                           batch_timeout_ms=2.0, repeats=3,
                           replicas=2, group=2):
-    """Bitwise + retrace HARD gates, advisory rps ratio sharded (N
-    replicas x G-device plans) vs the unsharded single-device engine."""
+    """Equality + retrace HARD gates, advisory rps ratio sharded (N
+    replicas x G-device plans) vs the unsharded single-device engine.
+    Equality is to a few ulp (``test_utils.few_ulp_tol``): the
+    reference answers one request at a time, the
+    fleet from coalesced batches split over each plan's devices."""
     from mxnet_tpu import serving
     net, params = build_model(feature=feature, hidden=hidden,
                               classes=classes, layers=layers)
@@ -96,16 +102,20 @@ def run_serve_shard_sweep(requests=256, offered_batch=8, feature=256,
         eng.warmup()
         return eng
 
-    # hard gates first: bitwise vs the unsharded reference, compile
+    # hard gates first: equal to the unsharded reference, compile
     # counter pinned across the whole request stream
+    from mxnet_tpu.test_utils import few_ulp_tol
+
+    def same(got, want):
+        return np.allclose(got, want, *few_ulp_tol(want))
+
     ref = build(1, 1)
     wants = [ref.predict(x, timeout=300) for x in X[:64]]
     ref.close()
     eng = build(group, replicas)
     c0 = eng.compile_count
     futs = [eng.submit(x) for x in X[:64]]
-    bitwise = all(np.array_equal(f.result(300), w)
-                  for f, w in zip(futs, wants))
+    matches = all(same(f.result(300), w) for f, w in zip(futs, wants))
     retraces = eng.compile_count - c0
     shard_desc = eng.stats()["replicas"]
     eng.close()
@@ -125,7 +135,7 @@ def run_serve_shard_sweep(requests=256, offered_batch=8, feature=256,
             "replicas": replicas, "group": group,
             "device_count": _device_count(),
             "plan": serve_plan(group),
-            "bitwise_identical": bool(bitwise),
+            "matches_unsharded_few_ulp": bool(matches),
             "retraces": int(retraces),
             "replica_shards": [r.get("shards") for r in shard_desc],
             "rps": {str(k): v for k, v in best.items()},
@@ -276,6 +286,10 @@ def main(argv=None):
                          "document (serve/decode/aot sections)")
     args = ap.parse_args(argv)
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # jax's own cache at the externally placed / fixed in-checkout path:
+    # the temporary AOT entry directories below then never carry it
+    from mxnet_tpu import config
+    config.compile_cache_dir()
 
     need = args.replicas * args.group
     if _device_count() < need:
@@ -304,12 +318,14 @@ def main(argv=None):
 
     ok = True
     for name, row in rows.items():
-        gate_ok = row["bitwise_identical"] and \
+        equal = row["matches_unsharded_few_ulp"] if name == "serve" \
+            else row["bitwise_identical"]
+        gate_ok = equal and \
             row.get("retraces", 0) == 0 and \
             (name != "aot" or row["warm_compiles"] == 0)
         ok = ok and gate_ok
-        print("%-6s  bitwise=%s  retraces=%s  %s  [%s]"
-              % (name, row["bitwise_identical"],
+        print("%-6s  equal=%s  retraces=%s  %s  [%s]"
+              % (name, equal,
                  row.get("retraces", "-"),
                  ("speedup=%.2fx (advisory)"
                   % row["speedup_vs_unsharded"])

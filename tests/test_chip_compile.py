@@ -1,0 +1,226 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+topology that is described, not attached
+(``jax.experimental.topologies``).  Nothing runs, so these tests say
+nothing about results or speed — they say what Mosaic or XLA:TPU would
+refuse on a v5e, and whether a program fits its 16 GB, before a chip run
+pays to find out.  chip_smoke.py's sizes are the sizes compiled here.
+
+Everything that touches the topology lives in fixtures of THIS file and
+runs in the test's own process: only one process at a time may load the
+TPU library, every xdist worker imports every test file, and a second
+file would land on another worker and skip in silence.
+"""
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+HBM_BYTES = 16e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    env = pytest.MonkeyPatch()
+    env.setenv("TPU_LOG_DIR", "disabled")    # else the compiler logs to /tmp
+    try:
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip("no v5e:2x2 topology can be described here: %r"
+                        % (e,))
+        yield desc
+    finally:
+        env.undo()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Sharding on the first described chip, with JAX's persistent
+    compilation cache off while the module's tests run: a described
+    compile can be written to it but never read back without a chip."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def _described(tree, sharding):
+    """Arrays (or shapes) -> ShapeDtypeStructs placed on the described
+    chip: there is no device to hold an array."""
+    import jax
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _total_bytes(compiled):
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+
+
+# ---------------------------------------------------------------------------
+# the KV-cache write ops, in the implementation 'auto' picks on a TPU
+# ---------------------------------------------------------------------------
+
+def _impl_auto_picks_on_tpu(monkeypatch, cache):
+    """What ``MXNET_CACHE_SCATTER_IMPL=auto`` resolves to where the
+    default backend is a TPU — asked with the backend query steered,
+    then undone so the compile below sees the real process."""
+    import jax
+    from mxnet_tpu.ops.cache import _impl_mode
+    monkeypatch.delenv("MXNET_CACHE_SCATTER_IMPL", raising=False)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        return _impl_mode(cache)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", [(32, 4096, 2048), (8, 2048, 1024),
+                                   (8, 128, 32)],
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("op,window", [("_cache_write_row", None),
+                                       ("_cache_write_rows", 5)])
+def test_cache_write_compiles_for_v5e(one_chip, monkeypatch, op, window,
+                                      shape, dtype):
+    """Both scatter ops compile for the chip at real pool shapes in
+    float32 and bfloat16, as the Pallas kernel 'auto' picks there."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.registry import get_op
+    slots, max_len, d = shape
+    dt = jnp.dtype(dtype)
+    cache = jax.ShapeDtypeStruct(shape, dt)
+    impl = _impl_auto_picks_on_tpu(monkeypatch, cache)
+    assert impl == "pallas"
+    monkeypatch.setenv("MXNET_CACHE_SCATTER_IMPL", impl)
+    vec = jax.ShapeDtypeStruct((slots,), jnp.float32)
+    if window is None:
+        args = (cache, jax.ShapeDtypeStruct((slots, d), dt), vec)
+    else:
+        args = (cache, jax.ShapeDtypeStruct((slots, window, d), dt),
+                vec, vec)
+    opdef = get_op(op)
+    fn = jax.jit(opdef.bound(opdef.normalize({})), donate_argnums=0)
+    compiled = fn.lower(*_described(args, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # in place: nothing the size of the pool besides the pool itself
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < cache.size * dt.itemsize // 8
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke.py's step programs at its sizes
+# ---------------------------------------------------------------------------
+
+def _compile_described(program, sharding):
+    """Compile one of chip_smoke's (jitted fn, args) pairs with the
+    arguments described on the chip."""
+    fn, args = program
+    return fn.lower(*_described(args, sharding)).compile()
+
+
+def test_lstm_decode_step_compiles_for_v5e(one_chip):
+    """The ``decode`` phase's persistent step program: 32 slots over
+    the vocab-10,000 / hidden-1,500 stacked LSTM."""
+    import chip_smoke
+    from mxnet_tpu.serving.decode import StepProgram
+    sz = chip_smoke.SIZES["decode"]
+    step, params, state_info = chip_smoke._lstm_model(sz, seed=0)
+    prog = StepProgram(step, params, {}, state_info,
+                       num_slots=sz["slots"], ctx=mx.cpu(0))
+    compiled = _compile_described(chip_smoke.decode_step_program(prog),
+                                  one_chip)
+    assert _total_bytes(compiled) < HBM_BYTES
+
+
+def test_kv_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The ``kv`` phase's step program as the engine builds it (select
+    pass included), with the cache writes in the kernel 'auto' picks on
+    the chip."""
+    import jax
+    import chip_smoke
+    from mxnet_tpu import serving
+    sz = chip_smoke.SIZES["kv"]
+    pool = jax.ShapeDtypeStruct((sz["slots"], sz["max_len"], sz["d"]),
+                                np.float32)
+    impl = _impl_auto_picks_on_tpu(monkeypatch, pool)
+    monkeypatch.setenv("MXNET_CACHE_SCATTER_IMPL", impl)
+    target, params, t_info = chip_smoke._kv_model(sz, seed=0)
+    eng = serving.DecodeEngine(target, params, {}, t_info,
+                               num_slots=sz["slots"],
+                               max_len=sz["max_len"], ctx=mx.cpu(0),
+                               start=False)
+    try:
+        assert [s["op"] for s in eng.selection] \
+            == ["_cache_write_row"] * (2 * sz["blocks"])
+        compiled = _compile_described(
+            chip_smoke.decode_step_program(eng._replicas[0].program),
+            one_chip)
+    finally:
+        eng.close()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _total_bytes(compiled) < HBM_BYTES
+
+
+def test_resnet50_module_step_fits_v5e(one_chip):
+    """The fused forward+backward program ``Module`` compiles for the
+    ``train`` phase, at its batch and in the dtype Module trains in,
+    fits one chip's 16 GB."""
+    import chip_smoke
+    mod = chip_smoke.bound_train_module(chip_smoke.SIZES["train"],
+                                        mx.cpu(0))
+    compiled = _compile_described(chip_smoke.train_step_program(mod),
+                                  one_chip)
+    assert mod._exec.arg_dict["data"].dtype == np.float32
+    assert _total_bytes(compiled) < HBM_BYTES
+
+
+# ---------------------------------------------------------------------------
+# the static memory planner against the number it exists to predict
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", ["mlp", "lenet", "resnet18"])
+def test_planner_peak_within_25pct_of_v5e(one_chip, name):
+    """analysis/memory.py's predicted peak against the v5e compiler's
+    own memory_analysis() for the same inference program — the
+    two-sided calibration pin.  (tests/test_memory.py keeps the CPU
+    compiler to what it can hold: it now counts a repacked copy of the
+    convolution weights among its temporaries, which no chip pays.)"""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.analysis.memory import plan_memory
+    from mxnet_tpu.executor import build_graph_fn
+    from test_memory import _zoo
+    net, shapes = _zoo(name)
+    plan, report = plan_memory(net, shapes)
+    assert plan is not None and not report.errors
+    g = build_graph_fn(net, net.list_arguments(),
+                       net.list_auxiliary_states())
+    arg_shapes, _, aux_shapes = net.infer_shape(**shapes)
+    avals = _described(
+        tuple(tuple(jax.ShapeDtypeStruct(tuple(s), jnp.float32)
+                    for s in group) for group in (arg_shapes, aux_shapes)),
+        one_chip)
+    compiled = jax.jit(lambda a, x: g(a, x, None, False)[0]) \
+        .lower(*avals).compile()
+    chip = _total_bytes(compiled)
+    assert chip > 0
+    assert abs(plan["peak_bytes"] - chip) / chip < 0.25, \
+        "planner %d vs v5e compiler %d" % (plan["peak_bytes"], chip)

@@ -45,7 +45,7 @@ def test_conv1x1_dot_matches_native(monkeypatch, stride, h):
 def test_conv1x1_pallas_fused_bwd_matches_native(monkeypatch):
     """MXNET_CONV1X1_FUSED_BWD (Pallas dgrad+wgrad single-pass kernel,
     interpret mode off-TPU) must be numerically identical to the native
-    path.  Measured slower on v5e-1 (PROFILE_r04.md) — kept off by
+    path.  Slower on v5e in an earlier chip record, since deleted — kept off by
     default as a documented experiment."""
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.standard_normal((4, 8, 8, 6)), jnp.float32)  # R=256

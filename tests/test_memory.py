@@ -124,11 +124,22 @@ def test_sharded_bytes_divide_along_plan_axes():
 
 @pytest.mark.parametrize("name", ["mlp", "lenet", "resnet18"])
 def test_predicted_peak_within_25pct_of_xla(name):
-    """The planner's watermark vs XLA's own memory_analysis() for the
-    same inference program (arguments + outputs + temporaries).  The
-    pin is deliberately loose — XLA fuses and rematerializes — but a
-    planner regression that double-counts or leaks liveness blows
-    well past 25%."""
+    """The planner's watermark vs the CPU compiler's memory_analysis()
+    for the same inference program (arguments + outputs + temporaries).
+
+    What the CPU compiler can hold: the resident part (params + inputs +
+    outputs) must agree with XLA's arguments + outputs to 2%, and the
+    whole prediction may exceed XLA's total by at most 25% — a planner
+    regression that double-counts or leaks liveness blows well past
+    that.  The two-sided 25% pin runs against the v5e compiler, the
+    number the preflight exists to predict
+    (tests/test_chip_compile.py::test_planner_peak_within_25pct_of_v5e):
+    the installed XLA:CPU (jax 0.9.0) counts a repacked copy of the
+    convolution weights among its temporaries (resnet18: 47.7 MB of
+    temp beside 44.8 MB of arguments, against a planned transient of
+    3.2 MB; the planner's side is a function of the shapes and did not
+    move), so from below the CPU total says nothing about the
+    planner."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.executor import build_graph_fn
@@ -148,11 +159,16 @@ def test_predicted_peak_within_25pct_of_xla(name):
                  for s in aux_shapes)
     ma = jax.jit(lambda a, x: g(a, x, None, False)[0]) \
         .lower(args, auxs).compile().memory_analysis()
-    xla = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-           + ma.temp_size_in_bytes)
-    assert xla > 0
-    assert abs(plan["peak_bytes"] - xla) / xla < 0.25, \
-        "planner %d vs XLA %d" % (plan["peak_bytes"], xla)
+    resident = ma.argument_size_in_bytes + ma.output_size_in_bytes
+    xla = resident + ma.temp_size_in_bytes
+    planned = (plan["param_bytes"] + plan["input_bytes"]
+               + plan["output_bytes"])
+    assert resident > 0
+    assert abs(planned - resident) / resident < 0.02, \
+        "planner resident %d vs XLA %d" % (planned, resident)
+    assert resident <= plan["peak_bytes"] < 1.25 * xla, \
+        "planner %d vs XLA %d (resident %d)" % (plan["peak_bytes"], xla,
+                                                resident)
 
 
 # ---------------------------------------------------------------------------

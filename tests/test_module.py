@@ -45,6 +45,34 @@ def test_module_bind_and_forward():
     np.testing.assert_allclose(p.sum(axis=1), np.ones(8), rtol=1e-5)
 
 
+def test_module_context_list_needs_a_sharding_plan():
+    """One executor binds on one device: a context list without a plan
+    must say so, not train on the first context alone; with a plan over
+    the same devices the batch is sharded across them."""
+    from mxnet_tpu.parallel.mesh import ShardingPlan, make_mesh
+    ctxs = [mx.cpu(0), mx.cpu(1)]
+    shapes = dict(data_shapes=[("data", (8, 16))],
+                  label_shapes=[("softmax_label", (8,))])
+    mod = mx.mod.Module(_mlp_symbol(), context=ctxs)
+    with pytest.raises(mx.MXNetError, match="set_sharding_plan"):
+        mod.bind(**shapes)
+    assert not mod.binded
+    mod.set_sharding_plan(ShardingPlan(
+        make_mesh({"dp": 2}, devices=[c.jax_device() for c in ctxs]),
+        batch_axis="dp"))
+    mod.bind(**shapes)
+    assert len(mod._exec.arg_dict["data"]._data.devices()) == 2
+
+
+def test_gpu_context_raises_without_an_accelerator():
+    """mx.gpu(i) names the i-th accelerator; on a CPU-only process it
+    raises like mx.tpu(i) instead of resolving to a host device."""
+    for ctx in (mx.gpu(0), mx.tpu(0)):
+        with pytest.raises(RuntimeError):
+            ctx.jax_device()
+    assert mx.cpu(0).jax_device().platform == "cpu"
+
+
 def test_module_fit_converges():
     """End-to-end convergence: the reference's tests/python/train pattern."""
     X, y = _toy_classification()

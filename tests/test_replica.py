@@ -33,6 +33,7 @@ import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import serving, telemetry
+from mxnet_tpu.test_utils import assert_almost_equal, few_ulp_tol
 from mxnet_tpu.serving import (DecodeEngine, ServingEngine, StepProgram,
                                greedy_decode, GreedySampler,
                                TemperatureSampler, replica_contexts)
@@ -149,6 +150,9 @@ def test_env_replicas_clamp_warns(monkeypatch):
 # ---------------------------------------------------------------------------
 
 def test_serving_replicas_route_and_match_single():
+    """Sequential requests through one replica (batch 1 each) against
+    the same requests offered at once to two (coalesced batches):
+    different batch extents, so equal to a few ulp."""
     net, params = _mlp()
     rng = np.random.default_rng(1)
     X = rng.standard_normal((24, 6)).astype(np.float32)
@@ -161,7 +165,7 @@ def test_serving_replicas_route_and_match_single():
     futs = [e2.submit(x) for x in X]
     got = [f.result(timeout=60) for f in futs]
     for a, b in zip(ref, got):
-        np.testing.assert_array_equal(a, b)
+        assert_almost_equal(b, a, *few_ulp_tol(a))
     st = e2.stats()
     assert len(st["replicas"]) == 2
     assert all(r["healthy"] for r in st["replicas"])
@@ -398,11 +402,17 @@ def test_decode_routed_requests_reroute_off_failed_replica():
 # ---------------------------------------------------------------------------
 
 def test_reload_loop_leak_gate_with_replicas(_fresh_telemetry):
+    """Counts are taken against what the process held when the test
+    began: the training loops' default StepTimers (telemetry/step.py)
+    live for the process by design, so an earlier test file in this
+    worker that ran fit() leaves its ``train.*`` heartbeats and
+    watchdog rules behind, and they are not this gate's to judge."""
     reg = telemetry.registry()
     mgr = telemetry.default_manager()
     net, params = _mlp()
     step, sparams, state_info = _lstm_step()
     rules0 = len(mgr)
+    heartbeats0 = set(telemetry.heartbeats())
     for _ in range(3):
         se = ServingEngine(net, params, {}, {"data": (6,)},
                            ctx=[mx.cpu(0), mx.cpu(0)])
@@ -455,7 +465,7 @@ def test_reload_loop_leak_gate_with_replicas(_fresh_telemetry):
         assert fam is None or fam.series() == [], fam_name
     assert reg._callbacks == []
     assert len(mgr) == rules0
-    assert telemetry.heartbeats() == {}
+    assert set(telemetry.heartbeats()) == heartbeats0
     assert telemetry.get_recorder() is None
     # second, independent gate (PR 19): the STATIC reclaim-pairing
     # lint must agree that every dynamic-label series has a close()-
@@ -574,6 +584,9 @@ def test_alert_rules_file_loads_and_is_idempotent(tmp_path, monkeypatch,
     path.write_text(json.dumps(rules))
     monkeypatch.setenv("MXNET_TELEMETRY_ALERT_RULES", str(path))
     mgr = telemetry.default_manager()
+    # rules other subsystems own (a neighbour test file's training
+    # watchdogs live for the process) are not this test's: count from here
+    rules0 = len(mgr)
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")
         added = telemetry.load_rules_file()
@@ -581,10 +594,10 @@ def test_alert_rules_file_loads_and_is_idempotent(tmp_path, monkeypatch,
     assert any("invalid" in str(x.message) for x in w)
     rule = added[0]
     assert rule.annotations["source"] == str(path)
-    assert len(mgr) == 1
+    assert len(mgr) == rules0 + 1
     # idempotent reload (every engine-driven recorder rebuild re-runs it)
     assert telemetry.load_rules_file() == []
-    assert len(mgr) == 1
+    assert len(mgr) == rules0 + 1
     mgr.remove_rule("ops_queue_depth_high")
 
     # the recorder build path loads it too — operator SLOs are live the

@@ -15,6 +15,7 @@ import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import serving
+from mxnet_tpu.test_utils import assert_almost_equal, few_ulp_tol
 from mxnet_tpu.serving import (BucketPolicy, DeadlineExceededError,
                                EngineClosedError, QueueFullError,
                                ServerOverloadError)
@@ -62,8 +63,10 @@ def test_bucket_policy_grid():
 
 
 def test_concurrent_clients_bitwise_match_predictor():
-    """16 threads hammer one engine; every answer must be bitwise what a
-    single-request Predictor computes for that example."""
+    """16 threads hammer one engine; every answer must be what a
+    single-request Predictor computes for that example — to a few ulp:
+    the engine answers from whichever batch bucket the request rode in,
+    a different XLA program from the batch-1 Predictor."""
     net, params = _mlp()
     rng = np.random.default_rng(1)
     X = rng.standard_normal((64, 6)).astype(np.float32)
@@ -84,14 +87,16 @@ def test_concurrent_clients_bitwise_match_predictor():
                                 ctx=mx.cpu())
     for i in range(len(X)):
         ref = pred.forward(data=X[i][None]).get_output(0)[0]
-        np.testing.assert_array_equal(results[i], ref)
+        assert_almost_equal(results[i], ref, *few_ulp_tol(ref))
     assert st["requests_served"] == len(X)
     assert st["batches"] <= len(X)          # some coalescing happened
 
 
 def test_staged_batch_coalesces_and_pads():
     """Requests staged against a stopped engine go out as ONE padded
-    batch: 5 requests -> bucket 8, occupancy 5/8."""
+    batch: 5 requests -> bucket 8, occupancy 5/8.  The reference is a
+    Predictor bound at that padded shape — the same program, so the
+    comparison stays bitwise."""
     net, params = _mlp()
     rng = np.random.default_rng(2)
     X = rng.standard_normal((5, 6)).astype(np.float32)
@@ -102,11 +107,13 @@ def test_staged_batch_coalesces_and_pads():
     outs = [f.result(timeout=30) for f in futs]
     st = eng.stats()
     eng.close()
-    pred = mx.predict.Predictor(net, params, {}, {"data": (1, 6)},
+    pred = mx.predict.Predictor(net, params, {}, {"data": (8, 6)},
                                 ctx=mx.cpu())
+    padded = np.zeros((8, 6), np.float32)
+    padded[:5] = X
+    ref = pred.forward(data=padded).get_output(0)
     for i in range(5):
-        ref = pred.forward(data=X[i][None]).get_output(0)[0]
-        np.testing.assert_array_equal(outs[i], ref)
+        np.testing.assert_array_equal(outs[i], ref[i])
     assert st["batches"] == 1
     assert st["batch_occupancy"] == pytest.approx(5 / 8)
 

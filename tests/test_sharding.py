@@ -604,7 +604,7 @@ row = shard_bench.run_serve_shard_sweep(
     requests=24, repeats=1, feature=32, hidden=32, layers=1,
     replicas=2, group=2)
 assert row["device_count"] >= 4, row
-assert row["bitwise_identical"], row
+assert row["matches_unsharded_few_ulp"], row
 assert row["retraces"] == 0, row
 assert row["replica_shards"] == [2, 2], row
 row2 = shard_bench.run_decode_shard_sweep(
@@ -624,6 +624,7 @@ assert row3["warm_hits"] > 0 and row3["warm_rejects"] == 0, row3
 # fleet, not the reference engine
 from shard_bench import build_model, serve_plan
 from mxnet_tpu import serving
+from mxnet_tpu.test_utils import assert_almost_equal, few_ulp_tol
 net, params = build_model(feature=32, hidden=32, layers=1)
 ref = serving.ServingEngine(net, params, {}, {"data": (32,)})
 ref.warmup()
@@ -644,7 +645,9 @@ for x, w in zip(xs, wants):
     except Exception:
         failed += 1
         continue
-    assert np.array_equal(got, w)
+    # sharded fleet vs the unsharded reference engine: another
+    # partition, another program
+    assert_almost_equal(got, w, *few_ulp_tol(w))
 health = [r["healthy"] for r in eng.stats()["replicas"]]
 assert failed == 1 and health == [False, True], (failed, health)
 eng.close()

@@ -8,7 +8,7 @@ deliberate-defect fixtures under tests/fixtures/ pin that the linter
 still FIRES (a lint that cannot fail gates nothing), and the runtime
 sanitizer half (MXNET_LOCK_SANITIZER=1, mxnet_tpu/locks.py surfaced as
 serving.locks) is pinned to observe zero inversions on a live engine
-with bitwise-identical outputs sanitizer-on vs -off.
+with the same outputs sanitizer-on vs -off.
 """
 import json
 import os
@@ -16,6 +16,7 @@ import subprocess
 import sys
 import threading
 
+import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -252,7 +253,7 @@ def test_sanitizer_condition_wait_releases_held_set():
 
 
 _SMOKE = r"""
-import hashlib, json, os, sys
+import json, os, sys
 import numpy as np
 import mxnet_tpu as mx
 from mxnet_tpu import serving
@@ -267,7 +268,6 @@ params = {
     "fc1_bias": mx.nd.zeros((8,)),
 }
 X = rng.standard_normal((32, 6)).astype(np.float32)
-h = hashlib.sha256()
 with serving.ServingEngine(net, params, {}, {"data": (6,)},
                            ctx=mx.cpu(), batch_timeout_ms=2.0) as eng:
     import threading
@@ -278,12 +278,10 @@ with serving.ServingEngine(net, params, {}, {"data": (6,)},
     ts = [threading.Thread(target=client, args=(t,)) for t in range(4)]
     for t in ts: t.start()
     for t in ts: t.join()
-for o in outs:
-    h.update(np.ascontiguousarray(o).tobytes())
 from mxnet_tpu import locks as L
 from mxnet_tpu import telemetry
 print(json.dumps({
-    "digest": h.hexdigest(),
+    "outs": np.asarray(outs, np.float32).tolist(),
     "enabled": L.enabled(),
     "inversions": len(L.observed_inversions()),
     "edges": len(L.observed_edges()),
@@ -305,12 +303,17 @@ def _run_smoke(sanitizer):
 def test_sanitizer_smoke_bitwise_identical_and_no_inversions():
     """The acceptance pin: a concurrent serving run under
     MXNET_LOCK_SANITIZER=1 observes zero inversions, and its outputs
-    are BITWISE identical to the sanitizer-off run (the sanitizer may
-    measure, never steer).  Off-mode performs zero instrument calls
-    and records nothing."""
+    are those of the sanitizer-off run (the sanitizer may measure,
+    never steer) — to a few ulp, not bitwise: four client threads
+    coalesce into whatever batch extents the timing gives, and another
+    extent is another XLA program (test_utils.few_ulp_tol).  Off-mode
+    performs zero instrument calls and records nothing."""
+    from mxnet_tpu.test_utils import assert_almost_equal, few_ulp_tol
     off = _run_smoke("0")
     on = _run_smoke("1")
-    assert off["digest"] == on["digest"]
+    for got, want in zip(np.asarray(on["outs"], np.float32),
+                         np.asarray(off["outs"], np.float32)):
+        assert_almost_equal(got, want, *few_ulp_tol(want))
     assert not off["enabled"] and off["edges"] == 0
     assert off["instrument_calls"] == 0
     assert on["enabled"] and on["inversions"] == 0

@@ -9,7 +9,12 @@ by mxnet_tpu.kvstore_dist (DMLC_* names kept for CLI compatibility):
   python tools/launch.py -n 4 --launcher local python train.py ...
 
 Local mode is the test harness for multi-host logic on one machine
-(reference tests/nightly pattern: N processes over loopback).
+(reference tests/nightly pattern: N processes over loopback).  The
+launcher itself never imports JAX; every worker does, with the inherited
+environment.  A chip belongs to one process, so on a host with one chip
+run local workers on the CPU (``JAX_PLATFORMS=cpu`` in the environment,
+as tests/test_dist.py does): N workers that all reach for the one chip
+fail at start-up or hang.
 """
 import argparse
 import os
