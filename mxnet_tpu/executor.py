@@ -28,6 +28,7 @@ from .context import Context, current_context
 from . import random as _random
 from .ndarray.ndarray import NDArray, _wrap
 from .symbol.symbol import Symbol, _topo
+from .telemetry import timeline as _timeline
 
 __all__ = ["Executor", "build_graph_fn"]
 
@@ -120,11 +121,17 @@ def build_graph_fn(symbol, arg_names, aux_names):
             attrs = {k: v for k, v in n.attrs.items() if not k.startswith("__")}
             attrs = n.op.normalize(attrs)
             f = n.op.bound(attrs, training)
-            if n.op.stochastic:
-                k = jax.random.fold_in(key, sto_index[id(n)])
-                outs = f(k, *ins)
-            else:
-                outs = f(*ins)
+            # the node's name rides every HLO op it lowers to (op_name
+            # metadata), so a device trace can say which layer a fusion
+            # belongs to; metadata only, the program is unchanged (and
+            # so is the persistent compile cache's key: a cached
+            # executable keeps the names it was first compiled with)
+            with jax.named_scope(n.name):
+                if n.op.stochastic:
+                    k = jax.random.fold_in(key, sto_index[id(n)])
+                    outs = f(k, *ins)
+                else:
+                    outs = f(*ins)
             if probes is not None and id(n) in probes:
                 outs = (outs[0] + probes[id(n)],) + tuple(outs[1:])
             if id(n) in capture:
@@ -188,6 +195,10 @@ class Executor:
         self._base_key = None
         self._step = 0
         self._pending_train_fwd = False
+        # span seam: each graph dispatch is one timeline.span (ring,
+        # profiler trace, mx.profiler's Chrome ring, the request trace
+        # current on the thread); None = plane off
+        self._tl = _timeline.get() if _timeline.enabled() else None
         self._build()
         self._resolve_grad_storage()
         for n in self.arg_names:
@@ -549,14 +560,12 @@ class Executor:
             self._materialized = False
             self.outputs = _LazyOutputs(self)
             return self.outputs
-        from . import profiler
-        from . import telemetry
         _count_dispatch("forward")
-        with telemetry.maybe_span("executor.forward", "executor"):
-            with profiler.record_span("forward", "forward"):
-                outs, new_aux = self._get_fwd(False)(self._arg_vals(),
-                                                     self._aux_vals(),
-                                                     self._key())
+        with _timeline.span("executor.forward", "executor", "executor",
+                            chrome=("forward", "forward"), tl=self._tl):
+            outs, new_aux = self._get_fwd(False)(self._arg_vals(),
+                                                 self._aux_vals(),
+                                                 self._key())
         self._set_outputs(outs)
         self._pending_train_fwd = False
         return self.outputs
@@ -567,14 +576,14 @@ class Executor:
         key = getattr(self, "_pending_key", None)
         if key is None:
             key = self._key()
-        from . import profiler
-        from . import telemetry
         _count_dispatch("forward_backward")
         fn = self._get_fwd_bwd(out_grads is not None)
         grad_names = self._grad_names
         old = tuple(self.grad_dict[n]._data for n in self._dense_grad_names)
-        with telemetry.maybe_span("executor.forward_backward", "executor"), \
-                profiler.record_span("forward_backward", "backward"):
+        with _timeline.span("executor.forward_backward", "executor",
+                            "executor",
+                            chrome=("forward_backward", "backward"),
+                            tl=self._tl):
             if out_grads is None:
                 outs, new_aux, new_grads = fn(self._arg_vals(),
                                               self._aux_vals(), key, old)

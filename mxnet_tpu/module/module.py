@@ -487,22 +487,32 @@ class Module(BaseModule):
         """Apply optimizer to gradients (module.py:629 → model.py:126)."""
         assert self.binded and self.params_initialized \
             and self.optimizer_initialized
-        from .. import profiler
         from ..telemetry import step as step_mod
         self._params_dirty = True
         # step attribution: self-time is the optimizer math — nested
-        # kv_push/kv_pull phases (kvstore.py) subtract themselves
-        with step_mod.active_phase("optimizer"):
-            with profiler.record_span("update", "update"):
-                self._update_impl()
+        # kv_push/kv_pull phases (kvstore.py) subtract themselves.  One
+        # span feeds the phase histogram, the timeline ring, the
+        # profiler's trace and mx.profiler's "update" region
+        with step_mod.active_phase("optimizer",
+                                   chrome=("update", "update")) as sp:
+            self._update_impl(sp)
 
-    def _update_impl(self):
+    def _update_impl(self, sp):
+        """One updater call a parameter that has a gradient.  ``sp`` is
+        what ``active_phase`` yielded, the open ``fit.optimizer`` span:
+        with the timeline plane on each call is marked
+        ``mx:update/<parameter>`` in the profiler's trace, and their
+        count rides the span as ``updates``."""
+        mark = sp.child
+        updates = 0
         if self._update_on_kvstore:
             for name in self._param_names:
                 if self._exec.grad_dict.get(name) is None:
                     continue
-                self._kvstore.push(name, self._exec.grad_dict[name])
-                self._kvstore.pull(name, out=self._exec.arg_dict[name])
+                with mark("update/" + name):
+                    self._kvstore.push(name, self._exec.grad_dict[name])
+                    self._kvstore.pull(name, out=self._exec.arg_dict[name])
+                updates += 1
         else:
             if self._kvstore and not self._dist_fused:
                 for name in self._param_names:
@@ -515,7 +525,10 @@ class Module(BaseModule):
                 g = self._exec.grad_dict.get(name)
                 if g is None:
                     continue
-                self._updater(idx, g, self._exec.arg_dict[name])
+                with mark("update/" + name):
+                    self._updater(idx, g, self._exec.arg_dict[name])
+                updates += 1
+        sp.args = {"updates": updates}
 
     def get_outputs(self, merge_multi_context=True):
         assert self.binded and self.params_initialized

@@ -108,8 +108,9 @@ class Request(object):
     resolution).  None = unattributed (no tenant given, or the
     efficiency plane is off).
     """
-    __slots__ = ("inputs", "group", "future", "t_enqueue", "deadline",
-                 "out_rows", "trace", "on_expire", "cost", "tenant")
+    __slots__ = ("inputs", "group", "future", "t_enqueue", "t_submit",
+                 "deadline", "out_rows", "trace", "on_expire", "cost",
+                 "tenant")
 
     def __init__(self, inputs, group, future, deadline=None,
                  out_rows=None, trace=None, on_expire=None, cost=None,
@@ -117,7 +118,8 @@ class Request(object):
         self.inputs = inputs
         self.group = group
         self.future = future
-        self.t_enqueue = time.monotonic()
+        self.t_enqueue = time.monotonic()     # the deadlines' clock
+        self.t_submit = time.perf_counter()   # the spans' clock
         self.deadline = deadline            # absolute time.monotonic()
         self.out_rows = out_rows
         self.trace = trace

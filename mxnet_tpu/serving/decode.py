@@ -110,7 +110,6 @@ from .replica import DecodeReplica, resolve_replica_placements
 __all__ = ["DecodeEngine", "DecodeResult", "StepProgram", "greedy_decode",
            "Sampler", "GreedySampler", "TemperatureSampler"]
 
-
 class Sampler(object):
     """Pluggable token-selection head for the decode step (ROADMAP 1a).
 
@@ -703,6 +702,15 @@ class StepProgram(object):
         self._tick = 0          # per-step sample counter (stochastic
         #                         samplers fold it into the key; dead
         #                         and DCE'd under the greedy head)
+        # span seam (telemetry/timeline.py): the engine that owns this
+        # program hands it its own ring (one gate: ``_new_replica``).
+        # With a ring, each step's host round trip is split where it
+        # happens — building and enqueuing the dispatch against the
+        # blocking read of the ids — and left in ``last_split`` for the
+        # scheduler's ``decode.step`` event; None = untimed,
+        # byte-for-byte
+        self._tl = None
+        self.last_split = None  # (dispatch seconds, read seconds)
         seed = getattr(self.sampler, "seed", None)
         if seed is not None:
             self._key = jax.random.PRNGKey(int(seed))
@@ -859,15 +867,40 @@ class StepProgram(object):
             raise MXNetError("this StepProgram compiled a speculative "
                              "draft-k-verify step: dispatch through "
                              "step_spec()")
+        (sampled,), outs = self._run(tokens, pos, valid, states, reset,
+                                     None, 1)
+        new_states = {name: outs[1 + i]
+                      for i, name in enumerate(self.state_names)}
+        return sampled, new_states
+
+    def _dispatch(self, tokens, pos, valid, states, reset, spec):
+        """Build the flat argument vector and enqueue the step kernel;
+        returns its device outputs without waiting for them."""
         if reset is None:
             reset = np.zeros((self.num_slots,), np.float32)
         flat = self._build_flat(tokens, pos, valid, states)
-        kernel = self._ensure_kernel(reset, flat)
+        kernel = self._ensure_kernel(reset, flat, spec_m=spec)
         self._tick = (self._tick + 1) & 0x7fffffff
-        outs = kernel(self._key, np.int32(self._tick), reset, *flat)
-        new_states = {name: outs[1 + i]
-                      for i, name in enumerate(self.state_names)}
-        return np.asarray(outs[0]), new_states
+        lead = (reset,) if spec is None else (reset, spec)
+        return kernel(self._key, np.int32(self._tick), *lead, *flat)
+
+    def _run(self, tokens, pos, valid, states, reset, spec, n_read):
+        """One dispatch and the blocking read of its first ``n_read``
+        outputs (the only device->host traffic of a step); returns
+        ``(host arrays, device outputs)``.  ``decode.step.read`` holds
+        the device's own step time while the host waits for it."""
+        tl = self._tl
+        if tl is None:
+            outs = self._dispatch(tokens, pos, valid, states, reset, spec)
+            return [np.asarray(o) for o in outs[:n_read]], outs
+        t0 = time.perf_counter()
+        with tl.annotate("decode.step.dispatch"):
+            outs = self._dispatch(tokens, pos, valid, states, reset, spec)
+        t1 = time.perf_counter()
+        with tl.annotate("decode.step.read"):
+            host = [np.asarray(o) for o in outs[:n_read]]
+        self.last_split = (t1 - t0, time.perf_counter() - t1)
+        return host, outs
 
     def _build_flat(self, tokens, pos, valid, states):
         """Assemble the full flat argument vector: params from the
@@ -903,16 +936,11 @@ class StepProgram(object):
         if self._spec is None:
             raise MXNetError("step_spec() needs a StepProgram built "
                              "with a SpecConfig")
-        if reset is None:
-            reset = np.zeros((self.num_slots,), np.float32)
-        flat = self._build_flat(tokens, pos, valid, states)
-        kernel = self._ensure_kernel(reset, flat, spec_m=spec)
-        self._tick = (self._tick + 1) & 0x7fffffff
-        outs = kernel(self._key, np.int32(self._tick), reset, spec,
-                      *flat)
+        (toks, counts), outs = self._run(tokens, pos, valid, states,
+                                         reset, spec, 2)
         keys = list(self.state_names) + list(self.draft_state_keys)
         new_states = {key: outs[2 + i] for i, key in enumerate(keys)}
-        return np.asarray(outs[0]), np.asarray(outs[1]), new_states
+        return toks, counts, new_states
 
     def probe_step(self):
         """One fixed-key, fixed-tick dispatch over an all-zero scratch
@@ -1605,6 +1633,12 @@ class DecodeEngine(object):
                       "valid_name": valid_name, "dtype": dtype,
                       "prefill_sym": prefill_sym,
                       "prefill_buckets": prefill_buckets}
+        # unified fleet timeline (telemetry/timeline.py): cached ring
+        # reference, None when the plane is off — the disabled path
+        # appends nothing and decodes bitwise-identically.  Read once,
+        # before the replicas: their step programs time with this ring
+        self._tl = (_telemetry.timeline.get()
+                    if _telemetry.timeline.enabled() else None)
         self._replicas = []
         placements = resolve_replica_placements(replicas, ctx,
                                                 self._sharding_spec)
@@ -1617,11 +1651,6 @@ class DecodeEngine(object):
         self._slot_free = threading.Event()
         self._tm = (_DecodeTelemetry(self)
                     if _telemetry.enabled() else None)
-        # unified fleet timeline (telemetry/timeline.py): cached ring
-        # reference, None when the plane is off — the disabled path
-        # appends nothing and decodes bitwise-identically
-        self._tl = (_telemetry.timeline.get()
-                    if _telemetry.timeline.enabled() else None)
         # serving efficiency plane (ISSUE 18): per-dispatch FLOPs
         # ledger + MFU/goodput gauges + per-tenant accounting.  Step
         # programs are priced ONCE here (memoized on the program);
@@ -1743,6 +1772,9 @@ class DecodeEngine(object):
                            ctx=rctx, dtype=c["dtype"],
                            sampler=self._sampler, aot=self._aot,
                            plan=plan, spec=self._spec_cfg)
+        # the engine's gate is the program's: a replica rebuilt later
+        # (rehabilitate) splits its steps iff ``_step_once`` reads them
+        prog._tl = self._tl
         rep = DecodeReplica(index, rctx, prog, plan=plan)
         prefill_sym = c["prefill_sym"]
         if prefill_sym is not None:
@@ -2934,18 +2966,36 @@ class DecodeEngine(object):
         rep.reset_np[slot] = 0.0        # prefill rows are live data
         req.prompt_i = len(req.prompt)
         req.tokens.append(int(first))
-        now = time.monotonic()
-        req.t_first_tok = req.t_last_tok = now
+        req.t_last_tok = self._first_token(req, time.perf_counter())
         rep.tokens_np[slot] = first
         rep.pos_np[slot] = float(len(req.prompt))
         with self._lock:
             self._tokens_out += 1
         if self._tm is not None:
             self._tm.tokens.inc()
-            self._tm.ttft.observe(now - req.t_enqueue)
         self._emit_token(req, first)
         if req.on_token is not None:
             self._fire_on_token(rep, req, int(first))
+
+    def _first_token(self, req, t_tok):
+        """A request's first generated token, once: the TTFT sample,
+        and the timeline's split of it into the wait for a slot
+        (submit to seat) and the prompt's feeding (seat to first
+        token), on the one ``perf_counter`` clock.  The instant is
+        back-dated through its ``enqueued`` argument, so it goes to
+        the ring only.  Returns ``t_tok``."""
+        req.t_first_tok = t_tok
+        if self._tm is not None:
+            self._tm.ttft.observe(t_tok - req.t_submit)
+        if self._tl is not None:
+            self._tl.instant(
+                "decode.first_token", "decode", "decode.tokens",
+                args={"enqueued": req.t_submit,
+                      "queue_wait_ms": (req.t_join - req.t_submit) * 1e3,
+                      "prompt_feed_ms": (t_tok - req.t_join) * 1e3,
+                      "prompt_len": len(req.prompt),
+                      "request": req.sse_id})
+        return t_tok
 
     def _emit_token(self, req, tok):
         """Publish one generated token onto the /events EventHub as a
@@ -3010,22 +3060,75 @@ class DecodeEngine(object):
             return False
 
     def _step_once(self, rep):
-        t0 = time.perf_counter()
+        tl = self._tl
+        if tl is None:
+            # plane off: no span object, no annotation, no append
+            self._step_body(rep, _telemetry.timeline.NO_SPAN,
+                            time.perf_counter())
+            return
+        with _telemetry.timeline.span(
+                "decode.step", "decode", "decode:%s" % rep.label,
+                tl=tl) as sp:
+            done = self._step_body(rep, sp, sp.t0)
+            if done is None:
+                sp.drop()       # empty pool: not a step
+                return
+            disp_s, read_s = rep.program.last_split
+            sp.args = {"live": done[0], "tokens": done[1],
+                       "dispatch_ms": disp_s * 1e3,
+                       "read_ms": read_s * 1e3}
+
+    def _booked(self, rep, live, new_tokens, t0):
+        """Book one scheduler iteration begun at ``t0`` (``stats()``
+        and the scraped series) and return ``(live, new_tokens)``.
+        Called by the step's body while its host arrays are alive:
+        dropping the view of the sampled ids frees a device buffer,
+        which lets a caller woken by its last token run, and it may
+        read ``stats()`` straight away."""
+        dt_ms = (time.perf_counter() - t0) * 1e3
+        with self._lock:
+            self._steps += 1
+            self._tokens_out += new_tokens
+            self._step_ms.append(dt_ms)
+        if self._tm is not None:
+            self._tm.steps.inc()
+            if new_tokens:
+                self._tm.tokens.inc(new_tokens)
+            rep.tm_step_ms.observe(dt_ms)
+            # slot-occupancy split of this dispatch (ISSUE 18
+            # satellite): the persistent step computed num_slots rows
+            # whatever the occupancy — scraped, not inferred
+            self._tm.slot_steps_live.inc(live)
+            dead = self.num_slots - live
+            if dead:
+                self._tm.slot_steps_dead.inc(dead)
+        return live, new_tokens
+
+    def _step_body(self, rep, sp, t0):
+        """One scheduler iteration, begun at ``t0``: deadline scan, the
+        step program, delivery of the sampled tokens, the step's
+        booking.  Returns ``(live slots, new tokens)``, or None when
+        no slot was occupied.  ``sp`` is the open ``decode.step`` span
+        (its inert stand-in with the plane off): the scan and the
+        delivery are marked inside it in the profiler's trace
+        (``mx:decode.step.scan`` / ``.deliver``); the step program
+        marks its own dispatch and read."""
         now = time.monotonic()
         # per-iteration deadline check folded into ONE slot scan: an
         # expired slot-resident request completes with its partial
         # tokens and frees the slot for queued work — mid-generation
         # eviction, not failure
-        occ = []
-        for i, req in enumerate(rep.slots):
-            if req is None:
-                continue
-            if req.deadline is not None and now >= req.deadline:
-                self._finish_slot(rep, i, "deadline")
-            else:
-                occ.append(i)
+        with sp.child("decode.step.scan"):
+            occ = []
+            for i, req in enumerate(rep.slots):
+                if req is None:
+                    continue
+                if req.deadline is not None and now >= req.deadline:
+                    self._finish_slot(rep, i, "deadline")
+                else:
+                    occ.append(i)
         if not occ:
-            return
+            return None
         if _faults.ACTIVE:
             # chaos seam: a raise retires this replica through the
             # real step-failure path (partial-output eviction +
@@ -3051,19 +3154,22 @@ class DecodeEngine(object):
                         _goodput.price_step_program(rep.program),
                         len(occ), self.num_slots, committed,
                         self._spec_k + 1))
-            new_tokens = self._advance_spec(rep, occ, toks_mat, counts)
-        else:
-            sampled, rep.states = rep.program.step(
-                rep.tokens_np, rep.pos_np, rep.valid_np, rep.states,
-                reset=rep.reset_np)
-            rep.reset_np.fill(0.0)      # consumed: rows are zeroed now
-            if self._eff is not None:
-                self._ledger_step(
-                    rep, occ,
-                    self._eff.record_step(
-                        rep.label,
-                        _goodput.price_step_program(rep.program),
-                        len(occ), self.num_slots))
+            with sp.child("decode.step.deliver"):
+                new_tokens = self._advance_spec(rep, occ, toks_mat,
+                                                counts)
+            return self._booked(rep, len(occ), new_tokens, t0)
+        sampled, rep.states = rep.program.step(
+            rep.tokens_np, rep.pos_np, rep.valid_np, rep.states,
+            reset=rep.reset_np)
+        rep.reset_np.fill(0.0)      # consumed: rows are zeroed now
+        if self._eff is not None:
+            self._ledger_step(
+                rep, occ,
+                self._eff.record_step(
+                    rep.label,
+                    _goodput.price_step_program(rep.program),
+                    len(occ), self.num_slots))
+        with sp.child("decode.step.deliver"):
             # one C-level conversion instead of num_slots
             # ndarray-scalar __getitem__ calls: the slot loop below is
             # the scheduler's per-step GIL cost, and with replica
@@ -3071,7 +3177,7 @@ class DecodeEngine(object):
             # every microsecond here is paid per step per replica
             sampled_l = sampled.tolist()
             new_tokens = 0
-            t_tok = time.monotonic()    # one stamp serves every slot
+            t_tok = time.perf_counter()  # one stamp serves every slot
             for i in occ:
                 req = rep.slots[i]
                 req.n_steps += 1
@@ -3087,39 +3193,14 @@ class DecodeEngine(object):
                     rep.tokens_np[i] = tok
                     new_tokens += 1
                     if req.t_first_tok is None:
-                        req.t_first_tok = t_tok
-                        if self._tm is not None:
-                            self._tm.ttft.observe(t_tok
-                                                  - req.t_enqueue)
+                        self._first_token(req, t_tok)
                     req.t_last_tok = t_tok
                     self._emit_token(req, tok)
                     if req.on_token is not None \
                             and not self._fire_on_token(rep, req, tok):
                         continue    # evicted by its own callback
                 self._check_finish(rep, i)
-        t1 = time.perf_counter()
-        dt_ms = (t1 - t0) * 1e3
-        with self._lock:
-            self._steps += 1
-            self._tokens_out += new_tokens
-            self._step_ms.append(dt_ms)
-        if self._tl is not None:
-            self._tl.complete("decode.step", "decode",
-                              "decode:%s" % rep.label, t0, t1,
-                              args={"live": len(occ),
-                                    "tokens": new_tokens})
-        if self._tm is not None:
-            self._tm.steps.inc()
-            if new_tokens:
-                self._tm.tokens.inc(new_tokens)
-            rep.tm_step_ms.observe(dt_ms)
-            # slot-occupancy split of this dispatch (ISSUE 18
-            # satellite): the persistent step computed num_slots rows
-            # whatever the occupancy — scraped, not inferred
-            self._tm.slot_steps_live.inc(len(occ))
-            dead = self.num_slots - len(occ)
-            if dead:
-                self._tm.slot_steps_dead.inc(dead)
+        return self._booked(rep, len(occ), new_tokens, t0)
 
     def _ledger_step(self, rep, occ, useful):
         """Spread one step dispatch's useful FLOPs over the live slots
@@ -3149,7 +3230,7 @@ class DecodeEngine(object):
         counts_l = counts.tolist()
         new_tokens = 0
         drafted = accepted = spec_slots = 0
-        t_tok = time.monotonic()
+        t_tok = time.perf_counter()
         for i in occ:
             req = rep.slots[i]
             req.n_steps += 1
@@ -3178,9 +3259,7 @@ class DecodeEngine(object):
                 req.tokens.append(tok)
                 new_tokens += 1
                 if req.t_first_tok is None:
-                    req.t_first_tok = t_tok
-                    if self._tm is not None:
-                        self._tm.ttft.observe(t_tok - req.t_enqueue)
+                    self._first_token(req, t_tok)
                 req.t_last_tok = t_tok
                 self._emit_token(req, tok)
                 if req.on_token is not None \

@@ -1783,16 +1783,21 @@ class ServingEngine(object):
             # batch and keeps serving
             _faults.trip("serve.dispatch", replica=rep.label)
         c0 = rep.cache.compile_count
-        t_disp0 = time.perf_counter()
-        with profiler.record_span(
-                "serve.dispatch[b=%d,n=%d,r=%d]" % (b, n, rep.index)
-                if self._multi else
-                "serve.dispatch[b=%d,n=%d]" % (b, n), "serve"):
+        # one span: the ring's serve.dispatch event, mx:serve.dispatch
+        # in a profiler trace, mx.profiler's serve.dispatch[...] region
+        with _telemetry.timeline.span(
+                "serve.dispatch", "serve", "replica:%d" % rep.index,
+                chrome=("serve.dispatch[b=%d,n=%d,r=%d]"
+                        % (b, n, rep.index) if self._multi else
+                        "serve.dispatch[b=%d,n=%d]" % (b, n), "serve"),
+                tl=self._tl) as sp:
             if self._pad_check:
                 outs = self._pad_probe(feeds, reqs, rep)
             else:
                 outs = rep.cache.run(feeds)
-        t_disp1 = time.perf_counter()
+            sp.args = {"bucket": b, "live": n,
+                       "compiled": rep.cache.compile_count - c0}
+        t_disp0, t_disp1 = sp.t0, sp.t1
         compiled = self._count_compiles(c0, feeds, rep)
         now = time.monotonic()
         # scatter first: unblock the waiting clients before doing any
@@ -1832,9 +1837,6 @@ class ServingEngine(object):
         tl = self._tl
         if tl is not None:
             lane = "replica:%d" % rep.index
-            tl.complete("serve.dispatch", "serve", lane, t_disp0,
-                        t_disp1, args={"bucket": b, "live": n,
-                                       "compiled": compiled})
             tl.counter("serve.batch_occupancy", "serve", lane,
                        n / float(b))
             tl.counter("serve.queue_depth", "serve", "serve",
@@ -2011,8 +2013,12 @@ class ServingEngine(object):
                 # live traffic must never pay a trace whichever
                 # replica the router picks
                 for rep in self._replicas:
-                    with profiler.record_span(
-                            "serve.warmup[b=%d]" % bb, "serve"):
+                    with _telemetry.timeline.span(
+                            "serve.warmup", "serve",
+                            "replica:%d" % rep.index,
+                            args={"bucket": bb},
+                            chrome=("serve.warmup[b=%d]" % bb, "serve"),
+                            tl=self._tl):
                         rep.cache.run(feeds)
                     rep.dispatched_keys.add(key)
                     with self._lock:

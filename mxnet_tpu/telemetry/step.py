@@ -64,6 +64,8 @@ import contextlib
 import contextvars
 import time
 
+from . import timeline
+
 __all__ = ["StepTimer", "PHASES", "STEP_SECONDS_BUCKETS",
            "PEAKS_TFLOPS", "peak_flops_for", "active_timer", "activate",
            "active_phase", "ensure_step", "observe_active",
@@ -171,24 +173,29 @@ _PHASE_DOC = ("training-step wall time attributed per phase (self-time: "
 class _Phase(object):
     """Slotted context manager for one phase frame — the per-phase hot
     path runs a few times per training step and a generator-based
-    @contextmanager pair measured ~3x this object's cost."""
-    __slots__ = ("st", "name", "t0", "child")
+    @contextmanager pair measured ~3x this object's cost.  The interval
+    is one :class:`timeline.span` (``<loop>.<phase>``): the histogram,
+    the step's span tree, the ring and the profiler's trace all get the
+    stamps that span read.  ``with`` yields the span, for ``args``."""
+    __slots__ = ("st", "name", "sp", "child")
 
-    def __init__(self, st, name):
+    def __init__(self, st, name, chrome=None):
         self.st = st
         self.name = name
+        self.sp = timeline.span(st._prefix + name, "train", st._lane,
+                                chrome=chrome, tl=st._tl)
 
     def __enter__(self):
-        self.t0 = time.perf_counter()
         self.child = 0.0
         self.st._stack.append(self)
-        return self
+        return self.sp.__enter__()
 
     def __exit__(self, exc_type, exc, tb):
-        t1 = time.perf_counter()
+        sp = self.sp
+        sp.__exit__(exc_type, exc, tb)
         st = self.st
         st._stack.pop()
-        st._record(self.name, self.t0, t1, t1 - self.t0 - self.child)
+        st._record(self.name, sp.t0, sp.t1, sp.t1 - sp.t0 - self.child)
         return False
 
 
@@ -218,6 +225,12 @@ class StepTimer(object):
         self._spans = []            # (name, t0, t1) intervals for the trace
         self._traces0 = 0.0
         self._mem_ok = True         # device.memory_stats support probe
+        # span seam: the step and each phase are one timeline.span
+        # (``<loop>.step``, ``<loop>.<phase>``); None = plane off
+        self._tl = timeline.get() if timeline.enabled() else None
+        self._prefix = self.loop + "."
+        self._lane = "train:" + self.loop
+        self._step_sp = None        # the open step's span
         if not self._on:
             return
         self._trace_counter = trace_counter
@@ -322,8 +335,13 @@ class StepTimer(object):
         if not self._on:
             return
         self._hb_stamp = time.monotonic()
-        self._t0 = time.perf_counter() if t0 is None else t0
-        self._stack = []
+        self.abort_step()           # a step left open is not one
+        sp = self._step_sp = timeline.span(
+            self._prefix + "step", "train", self._lane,
+            tl=self._tl).__enter__()
+        if t0 is not None:
+            sp.t0 = t0
+        self._t0 = sp.t0
         self._phase_self = {}
         self._spans = []
         self._traces0 = self._trace_count()
@@ -333,12 +351,19 @@ class StepTimer(object):
         iterator probe that raised StopIteration is not a step)."""
         self._t0 = None
         self._stack = []
+        if self._step_sp is not None:
+            self._step_sp.drop()
+            self._step_sp = None
 
     def end_step(self, t1=None):
         if not self._on or self._t0 is None:
             return
         self._hb_stamp = time.monotonic()
-        t1 = time.perf_counter() if t1 is None else t1
+        compiles = self._trace_count() - self._traces0
+        sp, self._step_sp = self._step_sp, None
+        sp.args = {"step": self.steps + 1, "compiles": int(compiles)}
+        sp.end(t1)
+        t1 = sp.t1
         t0, self._t0 = self._t0, None
         wall = max(t1 - t0, 0.0)
         self.steps += 1
@@ -351,7 +376,6 @@ class StepTimer(object):
                                                  phase=name)
                 self._h_phase[name] = child
             child.observe(secs)
-        compiles = self._trace_count() - self._traces0
         if compiles > 0:
             self._c_compiles.inc()
         if self.flops_per_step and self.peak_flops and wall > 0:
@@ -478,18 +502,26 @@ def activate(timer):
         _ACTIVE.reset(token)
 
 
-_NOOP = contextlib.nullcontext()    # stateless; safe to share
+_NOOP = timeline.NO_SPAN            # stateless; safe to share
 
 
-def active_phase(name):
+def active_phase(name, chrome=None):
     """Phase on the ambient timer when a step is open; a shared no-op
     (zero allocations, zero instrument calls) otherwise — the hook
     library code (executor, fit loop, trainers) calls this a few times
-    per step/forward, so it must stay allocation-free when inert."""
+    per step/forward, so it must stay allocation-free when inert.
+    ``chrome = (name, cat)`` names a region ``mx.profiler``'s Chrome
+    ring has always carried (``Module.update``): with no step open it
+    is still a bare ``fit.<phase>`` span, so that ring keeps it.
+    ``with`` yields the open :class:`timeline.span`, or its inert
+    stand-in: either way ``child()`` and ``args`` are there."""
     st = _ACTIVE.get()
     if st is None or st._t0 is None:
-        return _NOOP
-    return _Phase(st, name)
+        if chrome is None:
+            return _NOOP
+        return timeline.span("fit." + name, "train", "train:fit",
+                             chrome=chrome)
+    return _Phase(st, name, chrome)
 
 
 def observe_active(name, t0, t1=None):

@@ -37,6 +37,17 @@ NTP quality, which is why the cross-rank merge
 (tools/telemetry_dump.py) reports a skew estimate instead of
 pretending alignment is exact.
 
+The span seam: :class:`span` is the one context manager the
+program's own intervals go through (decode scheduler steps, training
+steps and their phases, executor and serving dispatches).  While open
+it holds a ``jax.profiler.TraceAnnotation("mx:" + name)``, so whenever
+a ``jax.profiler`` trace is being taken the interval is in it, on the
+device's clock; on exit it appends one ``X`` event to the ring with
+the ``perf_counter`` stamps it read, and hands the same stamps to the
+Chrome ring (``mx.profiler``, when running) and to the request trace
+current on the thread.  :meth:`Timeline.annotate` is the trace-only
+form for detail too fine for the ring's budget.
+
 Export: :func:`export_chrome_trace` renders a window as Chrome
 ``trace_event`` JSON — ``pid`` = rank, ``tid`` = lane
 (``replica:N``, ``decode:N``, ``locks``, ``alerts`` ...), ``B``/``E``
@@ -54,7 +65,7 @@ import time
 __all__ = [
     "enabled", "get", "reset", "wall_anchor", "wall_of_perf",
     "wall_of_mono", "Timeline", "export_chrome_trace",
-    "complete", "instant", "counter", "lock_feed",
+    "complete", "instant", "counter", "lock_feed", "span", "NO_SPAN",
 ]
 
 # one anchor, captured back-to-back at import: converts the monotonic
@@ -171,6 +182,12 @@ class Timeline(object):
         self._last = ev["seq"]
         self._ring.append(ev)
 
+    def annotate(self, name):
+        """Trace-only mark: a ``TraceAnnotation("mx:" + name)`` and no
+        ring event — for detail finer than the ring's budget (one event
+        a decode step, three a request)."""
+        return _annotation("mx:" + name)
+
     # -- read -------------------------------------------------------------
     def appended(self):
         """Lifetime append count — the zero-append pin reads this."""
@@ -206,6 +223,120 @@ class Timeline(object):
 
     def clear(self):
         self._ring.clear()
+
+
+# ---------------------------------------------------------------- the seam
+
+_PLANE = object()       # span(tl=_PLANE): gate on enabled() per call
+
+
+_TRACE_ANNOTATION = None
+
+
+def _annotation(name):
+    # the one place the program touches the profiler's trace; costs
+    # ~0.5 us while no jax.profiler session is active
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        import jax
+        _TRACE_ANNOTATION = jax.profiler.TraceAnnotation
+    return _TRACE_ANNOTATION(name)
+
+
+class span(object):
+    """One interval of the program's own work, through every sink that
+    is live: the profiler's trace (``mx:<name>``, the device's clock),
+    this ring (one ``X`` event on exit, ``perf_counter`` stamps), the
+    Chrome ring of ``mx.profiler`` under its established ``chrome =
+    (name, cat)`` when that is running, and the request trace current
+    on this thread.  ``t0``/``t1`` are the stamps every sink gets, for
+    callers that account the same interval (step phases).  ``args`` may
+    be set while the span is open; it rides the ring event.
+
+    Sites that cache the ring pass it (``tl=self._tl``; hot ones make
+    no call at all with the plane off); without ``tl`` the span gates
+    on ``enabled()`` per call.  With no sink live it is two clock
+    reads."""
+    __slots__ = ("name", "cat", "lane", "args", "chrome", "t0", "t1",
+                 "_tl", "_ann")
+
+    def __init__(self, name, cat, lane, args=None, chrome=None,
+                 tl=_PLANE):
+        self.name = name
+        self.cat = cat
+        self.lane = lane
+        self.args = args
+        self.chrome = chrome
+        self._tl = (get() if enabled() else None) if tl is _PLANE else tl
+        self._ann = None
+        self.t1 = None
+
+    def __enter__(self):
+        if self._tl is not None:
+            self._ann = _annotation("mx:" + self.name)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def child(self, name):
+        """:meth:`Timeline.annotate` inside this span; nothing with the
+        plane off."""
+        return NO_SPAN if self._tl is None else self._tl.annotate(name)
+
+    def drop(self):
+        """Close without recording (a step that turned out not to be
+        one); the ``with`` block's exit then records nothing."""
+        self._end_annotation()
+        self.name = None
+
+    def _end_annotation(self):
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+
+    def __exit__(self, exc_type, exc, tb):
+        self.end()
+        return False
+
+    def end(self, t1=None):
+        """Close at ``t1`` (an interval the caller already measured)
+        or now; every sink gets the one pair of stamps."""
+        t0 = self.t0
+        self.t1 = t1 = time.perf_counter() if t1 is None else t1
+        if self.name is None:
+            return
+        self._end_annotation()
+        if self._tl is not None:
+            self._tl.complete(self.name, self.cat, self.lane, t0, t1,
+                              args=self.args)
+        if self.chrome is not None:
+            from .. import profiler
+            profiler.add_span_event(self.chrome[0], self.chrome[1], t0, t1)
+        from .tracing import current_trace  # lazy: tools load this file alone
+        tc = current_trace()
+        if tc is not None and not tc.finished:
+            tc.add(self.name, t0, t1, self.cat)
+
+
+class _NoSpan(object):
+    """What a site holds in place of a :class:`span` when there is
+    nothing to record: enters, marks and keeps nothing."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        return False
+
+    def child(self, name):
+        return self
+
+    def __setattr__(self, name, value):
+        pass                    # ``sp.args = ...``: shared, so dropped
+
+
+NO_SPAN = _NoSpan()
 
 
 # ---------------------------------------------------------------- singleton

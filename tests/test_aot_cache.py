@@ -392,10 +392,30 @@ def test_unwritable_cache_dir_degrades_to_uncached(tmp_path,
 # telemetry + alerting: rejects are pageable, series reclaim at close
 # ---------------------------------------------------------------------------
 
+def _close_train_loops():
+    """A worker process runs several files, and a train loop of an
+    earlier one (``fit()``, ``Trainer.step``) never closes its
+    StepTimer: its watchdog rule and ``train.<loop>`` heartbeat stay in
+    the process-wide tables.  They are not these engines' to reclaim,
+    so the leak gates below start from tables without them."""
+    from mxnet_tpu.telemetry import recorder, step as step_mod
+    for _gen, st in list(step_mod._DEFAULT.values()):
+        st.close()
+    for loop in list(step_mod._HB_LOOPS):   # timers collected unclosed
+        del step_mod._HB_LOOPS[loop]
+        recorder.unregister_heartbeat("train.%s" % loop)
+    mgr = telemetry.default_manager()
+    for rule in mgr.rules():
+        if rule.name.startswith("train_") and rule.name.endswith("_stalled"):
+            while mgr.state_of(rule.name) is not None:
+                mgr.remove_rule(rule.name)
+
+
 @pytest.fixture
 def _fresh_telemetry():
     telemetry.set_enabled(None)
     telemetry.reset()
+    _close_train_loops()
     telemetry.stop_server()
     telemetry.stop_recorder()
     yield

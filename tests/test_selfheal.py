@@ -801,8 +801,11 @@ def test_chaos_acceptance(tmp_path, monkeypatch):
         # the re-warm is AOT-drawn
         deadline = time.monotonic() + 30
         while time.monotonic() < deadline:
+            # (the supervisor books a rehab after the replica it
+            # re-admitted reads healthy)
             if all(r.healthy for r in eng_s._replicas) and \
-                    all(r.healthy for r in eng_d._replicas):
+                    all(r.healthy for r in eng_d._replicas) and \
+                    sup.state()["rehabs_ok"] >= 2:
                 break
             time.sleep(0.05)
         st_s, st_d = eng_s.stats(), eng_d.stats()

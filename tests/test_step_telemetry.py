@@ -177,6 +177,138 @@ def test_fit_results_bitwise_identical_on_vs_off(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the span seam (ISSUE 24): step and phases on the timeline ring
+# ---------------------------------------------------------------------------
+
+def _ring(name=None):
+    from mxnet_tpu.telemetry import timeline
+    tl = timeline.peek()
+    evs = tl.events() if tl is not None else []
+    return [e for e in evs if e["cat"] == "train" and e["lane"] != "trace"
+            and (name is None or e["name"] == name)]
+
+
+def test_fit_ring_holds_one_step_event_and_its_phases(monkeypatch):
+    from mxnet_tpu.telemetry import timeline
+    monkeypatch.setenv("MXNET_TELEMETRY_TRACE_SAMPLE", "1")
+    timeline.reset()
+    mod = _toy_fit()                    # 3 steps
+    steps = _ring("fit.step")
+    assert [e["args"]["step"] for e in steps] == [1, 2, 3]
+    assert steps[0]["args"]["compiles"] >= 1
+    assert steps[-1]["args"]["compiles"] == 0
+    # the updates counter: one updater call a parameter with a gradient
+    with_grad = [n for n in mod._param_names
+                 if mod._exec.grad_dict.get(n) is not None]
+    assert len(with_grad) == 4
+    opt = _ring("fit.optimizer")
+    assert [e["args"]["updates"] for e in opt] == [4, 4, 4]
+    for name in ("fit.fwd_bwd", "fit.h2d", "fit.metric"):
+        assert len(_ring(name)) == 3, name
+    # the ring's budget: a step and its phases, about 8 events a step
+    assert len(_ring()) <= 3 * 8 + 1
+    # phases lie inside their step, on the one perf_counter clock
+    for st, ph in zip(steps, opt):
+        assert st["mono"] <= ph["mono"]
+        assert ph["mono"] + ph["dur"] <= st["mono"] + st["dur"]
+    # ring, phase histogram and the step's span tree read ONE interval
+    hist = {s["labels"]["phase"]: s for s in telemetry.registry().collect()[
+        "mxnet_train_step_phase_seconds"]["series"]}
+    assert sum(e["dur"] for e in opt) == pytest.approx(
+        hist["optimizer"]["sum"], rel=1e-9)
+    trees = [t for t in telemetry.all_traces().values()
+             if t["root"]["name"] == "train.step[fit]"]
+    kid = [c for c in trees[-1]["root"]["children"]
+           if c["name"] == "optimizer"][0]
+    assert kid["dur_ms"] == pytest.approx(opt[-1]["dur"] * 1e3, abs=1e-3)
+    timeline.reset()
+
+
+def test_fit_with_the_plane_off_appends_and_annotates_nothing(monkeypatch):
+    """Telemetry on, timeline plane off: the phase histograms still
+    fill, the ring never materializes, no profiler annotation is made,
+    and the fitted parameters are bitwise those of the plane on."""
+    from mxnet_tpu.telemetry import timeline
+    marks = []
+    inner = timeline._annotation
+    monkeypatch.setattr(timeline, "_annotation",
+                        lambda name: marks.append(name) or inner(name))
+
+    def run(plane):
+        monkeypatch.setenv("MXNET_TELEMETRY_TIMELINE", plane)
+        telemetry.reset()
+        timeline.reset()
+        mod = _toy_fit(num_epoch=2)
+        return {k: v.asnumpy() for k, v in mod.get_params()[0].items()}
+
+    off = run("0")
+    assert timeline.peek() is None and marks == []
+    doc = telemetry.registry().collect()
+    assert doc["mxnet_train_steps_total"]["series"][0]["value"] == 6
+    assert {s["labels"]["phase"] for s in
+            doc["mxnet_train_step_phase_seconds"]["series"]} >= {
+                "fwd_bwd", "optimizer", "metric"}
+    on = run("1")
+    assert "mx:fit.step" in marks and "mx:update/fc1_weight" in marks
+    assert len(_ring("fit.step")) == 6
+    for k in off:
+        assert np.array_equal(off[k], on[k]), k
+    timeline.reset()
+
+
+def test_update_region_reaches_the_chrome_ring_outside_any_step():
+    """``Module.update`` by hand, telemetry off: no step is open and no
+    plane is on, and ``mx.profiler`` still gets its "update" region."""
+    from mxnet_tpu import profiler
+    telemetry.set_enabled(False)
+    mod = mx.mod.Module(_mlp(), context=mx.cpu())
+    mod.bind(data_shapes=[("data", (8, 6))],
+             label_shapes=[("softmax_label", (8,))])
+    mod.init_params()
+    mod.init_optimizer(optimizer_params={"learning_rate": 0.1})
+    batch = mx.io.DataBatch(data=[mx.nd.ones((8, 6))],
+                            label=[mx.nd.zeros((8,))])
+    profiler.clear()
+    profiler.profiler_set_state("run")
+    try:
+        mod.forward_backward(batch)
+        mod.update()
+    finally:
+        profiler.profiler_set_state("stop")
+    cats = [e["cat"] for e in json.loads(profiler.dumps())["traceEvents"]]
+    assert cats.count("update") == 1 and cats.count("backward") == 1
+    profiler.clear()
+    assert telemetry.registry().instrument_calls() == 0
+
+
+@pytest.mark.parametrize("program", ["forward", "forward_backward"])
+def test_lowered_hlo_names_the_symbol_nodes(program):
+    """Each Symbol node lowers under ``jax.named_scope(<node name>)``:
+    the bound executor's HLO carries the graph's names (and the
+    backward's ops their forward node's), which is what lets a device
+    trace say which layer an op belongs to."""
+    import jax
+    mod = mx.mod.Module(_mlp(), context=mx.cpu())
+    mod.bind(data_shapes=[("data", (8, 6))],
+             label_shapes=[("softmax_label", (8,))])
+    mod.init_params()
+    ex = mod._exec
+    if program == "forward":
+        low = ex._get_fwd(False).lower(ex._arg_vals(), ex._aux_vals(),
+                                       ex._key())
+    else:
+        fn = ex._get_fwd_bwd(False)
+        old = tuple(ex.grad_dict[n]._data for n in ex._dense_grad_names)
+        low = fn.lower(ex._arg_vals(), ex._aux_vals(), ex._key(), old)
+    text = low.as_text(debug_info=True)
+    scope = "/%s/" if program == "forward" else "/jvp(%s)/"
+    for node in ("fc1", "relu1", "fc2", "softmax"):
+        assert scope % node in text, node
+    if program == "forward_backward":
+        assert "/transpose(jvp(fc1))/dot_general" in text
+
+
+# ---------------------------------------------------------------------------
 # analytic FLOPs + MFU
 # ---------------------------------------------------------------------------
 
@@ -280,6 +412,28 @@ def test_nested_phases_record_self_time():
     assert by_phase["optimizer"] >= 0.018
     assert by_phase["optimizer"] + by_phase["kv_push"] <= wall * 1.0001
     st.close()
+
+
+def test_step_measured_by_the_caller_reaches_every_sink_alike():
+    """``begin_step(t0)`` / ``end_step(t1)`` with stamps the caller
+    took: the histogram and the ring's ``<loop>.step`` event hold that
+    one interval."""
+    from mxnet_tpu.telemetry import timeline
+    timeline.reset()
+    st = step_mod.StepTimer(loop="stamp_test", retention=None)
+    t0 = time.perf_counter() - 1.0
+    st.begin_step(t0)
+    st.end_step(t0 + 0.25)
+    wall = [s for s in telemetry.registry().collect()[
+        "mxnet_train_step_seconds"]["series"]
+        if s["labels"]["loop"] == "stamp_test"]
+    st.close()
+    ev = [e for e in timeline.get().events()
+          if e["name"] == "stamp_test.step"]
+    assert len(ev) == 1 and ev[0]["mono"] == t0
+    assert ev[0]["dur"] == pytest.approx(0.25, rel=1e-9)
+    assert len(wall) == 1 and wall[0]["sum"] == pytest.approx(0.25, rel=1e-9)
+    timeline.reset()
 
 
 # ---------------------------------------------------------------------------

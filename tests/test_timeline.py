@@ -293,13 +293,34 @@ def test_disabled_plane_is_bitwise_and_appends_nothing(monkeypatch):
     net, params = _mlp()
     x = np.ones((6,), np.float32)
 
+    marks = []
+    inner = timeline._annotation
+    monkeypatch.setattr(timeline, "_annotation",
+                        lambda name: marks.append(name) or inner(name))
+    step, sparams, state_info = _lstm_step()
+
+    def decode():
+        de = DecodeEngine(step, sparams, {}, state_info, num_slots=2,
+                          max_len=32)
+        try:
+            futs = [de.submit([1, 2, 3], max_new_tokens=5),
+                    de.submit([4], max_new_tokens=4)]
+            return de, [list(f.result(timeout=120).tokens) for f in futs]
+        finally:
+            de.close()
+
     monkeypatch.setenv("MXNET_TELEMETRY_TIMELINE", "0")
     timeline.reset()
     eng = ServingEngine(net, params, {}, {"data": (6,)}, ctx=mx.cpu())
     eng.warmup()
     off = eng.predict(x, timeout=60)
     assert eng._tl is None
+    de, toks_off = decode()
+    assert all(r.program._tl is None and r.program.last_split is None
+               for r in de._replicas)
+    # no append and no profiler annotation anywhere on the serving path
     assert timeline.peek() is None or timeline.peek().appended() == 0
+    assert marks == []
     eng.close()
 
     monkeypatch.setenv("MXNET_TELEMETRY_TIMELINE", "1")
@@ -309,8 +330,11 @@ def test_disabled_plane_is_bitwise_and_appends_nothing(monkeypatch):
     on = eng.predict(x, timeout=60)
     assert eng._tl is not None
     assert timeline.get().appended() > 0
+    _de, toks_on = decode()
+    assert "mx:decode.step" in marks and "mx:serve.dispatch" in marks
     eng.close()
     np.testing.assert_array_equal(off, on)
+    assert toks_off == toks_on
 
 
 def test_telemetry_off_zero_instrument_calls_and_zero_appends():
@@ -327,6 +351,243 @@ def test_telemetry_off_zero_instrument_calls_and_zero_appends():
     eng.close()
     assert reg.instrument_calls() == base
     assert timeline.peek() is None
+
+
+# ---------------------------------------------------------------------------
+# the span seam (ISSUE 24): one interval, every live sink
+# ---------------------------------------------------------------------------
+
+def test_span_feeds_ring_chrome_ring_and_current_trace(tmp_path):
+    from mxnet_tpu import profiler
+    telemetry.set_enabled(True)
+    profiler.clear()
+    profiler.profiler_set_config(filename=str(tmp_path / "p.json"))
+    profiler.profiler_set_state("run")
+    try:
+        with telemetry.trace("by-hand") as tc:
+            with timeline.span("executor.forward", "executor", "executor",
+                               chrome=("forward", "forward")) as sp:
+                sp.args = {"k": 1}
+    finally:
+        profiler.profiler_set_state("stop")
+    # (a kept trace mirrors its tree into the ring too, on lane "trace")
+    ev = [e for e in timeline.get().events()
+          if e["name"] == "executor.forward" and e["lane"] == "executor"]
+    assert len(ev) == 1 and ev[0]["args"] == {"k": 1}
+    # every sink got the stamps the span read
+    assert ev[0]["mono"] == sp.t0
+    assert ev[0]["dur"] == pytest.approx(sp.t1 - sp.t0)
+    chrome = [e for e in json.loads(profiler.dumps())["traceEvents"]
+              if e["name"] == "forward"]
+    assert len(chrome) == 1 and chrome[0]["cat"] == "forward"
+    assert chrome[0]["dur"] == pytest.approx((sp.t1 - sp.t0) * 1e6)
+    kids = telemetry.get_trace(tc.trace_id)["root"]["children"]
+    assert [k["name"] for k in kids] == ["executor.forward"]
+    profiler.clear()
+
+
+def test_span_drop_and_plane_off_record_nothing(monkeypatch):
+    telemetry.set_enabled(True)
+    tl = timeline.get()
+    with timeline.span("decode.step", "decode", "decode:0", tl=tl) as sp:
+        sp.drop()
+    assert tl.appended() == 0 and sp.t1 is not None
+    monkeypatch.setenv("MXNET_TELEMETRY_TIMELINE", "0")
+    marks = []
+    monkeypatch.setattr(timeline, "_annotation", marks.append)
+    with timeline.span("fit.optimizer", "train", "train:fit") as sp:
+        with sp.child("update/w"):
+            pass
+    # plane off: still a context manager with stamps, and nothing else
+    assert sp.t1 >= sp.t0 and marks == [] and tl.appended() == 0
+
+
+def test_decode_ring_splits_the_step_and_the_first_token():
+    """One ``decode.step`` a step, its host round trip split where it
+    happens, and one ``decode.first_token`` a request whose two parts
+    add up to first token minus enqueue."""
+    telemetry.set_enabled(True)
+    step, sparams, state_info = _lstm_step()
+    de = DecodeEngine(step, sparams, {}, state_info, num_slots=2,
+                      max_len=32)
+    de.warmup()
+    base = timeline.get().appended()
+    steps0 = de.stats()["decode"]["steps"]
+    firsts = {}
+    subs = {}
+    futs = []
+    for i, prompt in enumerate(([1, 2, 3], [4, 5], [6])):
+        def on_token(tok, _i=i):
+            firsts.setdefault(_i, time.perf_counter())
+        subs[i] = time.perf_counter()
+        futs.append(de.submit(prompt, max_new_tokens=3, on_token=on_token))
+    for f in futs:
+        f.result(timeout=120)
+    steps = de.stats()["decode"]["steps"] - steps0
+    ttft = telemetry.registry().collect()[
+        "mxnet_serve_decode_ttft_seconds"]["series"][0]
+    de.close()
+    # (a request trace the sampler kept mirrors its tree on lane "trace")
+    evs = [e for e in timeline.get().events()
+           if e["seq"] > base and e["cat"] == "decode"
+           and e["lane"] != "trace"]
+    by = {}
+    for e in evs:
+        by.setdefault(e["name"], []).append(e)
+    assert len(by["decode.step"]) == steps
+    for e in by["decode.step"]:
+        a = e["args"]
+        assert {"live", "tokens", "dispatch_ms", "read_ms"} <= set(a)
+        assert a["dispatch_ms"] > 0 and a["read_ms"] > 0
+        assert a["dispatch_ms"] + a["read_ms"] <= e["dur"] * 1e3
+    # the ring's budget: one event a step, three a request
+    assert len(by["decode.first_token"]) == 3
+    assert len(by["decode.join"]) == len(by["decode.leave"]) == 3
+    assert set(by) == {"decode.step", "decode.first_token", "decode.join",
+                       "decode.leave"}
+    total = 0.0
+    for e, i in zip(sorted(by["decode.first_token"],
+                           key=lambda e: e["args"]["enqueued"]), range(3)):
+        a = e["args"]
+        assert a["prompt_len"] == 3 - i
+        # enqueue stamp and first-token stamp bracket the caller's own
+        assert subs[i] <= a["enqueued"]
+        first = a["enqueued"] + (a["queue_wait_ms"]
+                                 + a["prompt_feed_ms"]) / 1e3
+        assert a["enqueued"] <= first <= firsts[i]
+        assert a["queue_wait_ms"] >= 0 and a["prompt_feed_ms"] > 0
+        total += (a["queue_wait_ms"] + a["prompt_feed_ms"]) / 1e3
+    # the same interval the TTFT histogram observed, on one clock
+    assert ttft["count"] == 3
+    assert total == pytest.approx(ttft["sum"], rel=1e-6)
+
+
+def _host_spans(trace_dir):
+    """One list of ``(name, start_ns, end_ns)`` a thread line: the
+    program's ``mx:`` annotations on the trace's ``/host:`` planes."""
+    import glob
+    from jax.profiler import ProfileData
+    files = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    assert files, "the profiler wrote no .xplane.pb"
+    out = []
+    for plane in ProfileData.from_file(files[-1]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events if e.name.startswith("mx:")]
+            if evs:
+                out.append(evs)
+    return out
+
+
+@pytest.mark.parametrize("built_on", [True, False])
+def test_rehabilitated_replica_times_with_the_engines_gate(monkeypatch,
+                                                           built_on):
+    """A replica rebuilt after the plane's variable flipped follows the
+    engine that owns it: its step program splits its steps iff
+    ``_step_once`` reads the split, so the rebuilt replica serves."""
+    import warnings
+    telemetry.set_enabled(True)
+    monkeypatch.setenv("MXNET_TELEMETRY_TIMELINE", "1" if built_on else "0")
+    timeline.reset()
+    step, sparams, state_info = _lstm_step()
+    de = DecodeEngine(step, sparams, {}, state_info, num_slots=2,
+                      max_len=32, default_deadline_ms=0,
+                      ctx=[mx.cpu(0), mx.cpu(0)])
+    de.warmup()
+    want = list(de.generate([1, 2], max_new_tokens=4, timeout=120).tokens)
+    bad = de._replicas[0]
+    bad.program.step = lambda *a, **k: (
+        (_ for _ in ()).throw(RuntimeError("induced step failure")))
+    monkeypatch.setenv("MXNET_TELEMETRY_TIMELINE", "0" if built_on else "1")
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        for _ in range(10):
+            if not bad.healthy:
+                break
+            de.generate([1], max_new_tokens=2, timeout=120)
+        assert not bad.healthy
+        assert de.rehabilitate() == [{"replica": "0", "ok": True,
+                                      "reason": None}]
+        assert all((r.program._tl is not None) == built_on
+                   and r.program._tl is de._tl for r in de._replicas)
+        base = timeline.get().appended() if built_on else 0
+        ticks0 = [r.program._tick for r in de._replicas]
+        # more requests at once than one replica has slots: both step
+        futs = [de.submit([1, 2], max_new_tokens=4) for _ in range(6)]
+        for f in futs:
+            assert list(f.result(timeout=120).tokens) == want
+    st = de.stats()["decode"]
+    ticks = [r.program._tick for r in de._replicas]
+    de.close()
+    assert [r["healthy"] for r in st["replicas"]] == [True, True]
+    assert all(t > t0 for t, t0 in zip(ticks, ticks0))
+    if built_on:
+        steps = [e for e in timeline.get().events()
+                 if e["seq"] > base and e["name"] == "decode.step"]
+        assert {e["lane"] for e in steps} == {"decode:0", "decode:1"}
+        assert all(e["args"]["dispatch_ms"] > 0 for e in steps)
+    else:
+        # (feeds that gate per call, as a kept request trace's mirror,
+        # follow the variable; the engine's own do not)
+        tl = timeline.peek()
+        assert tl is None or not [e for e in tl.events()
+                                  if e["cat"] == "decode"
+                                  and e["lane"] != "trace"]
+
+
+def test_profiler_trace_holds_the_programs_spans(tmp_path):
+    """A ``jax.profiler`` trace around a few decode steps and a short
+    ``fit`` holds the program's spans on a ``/host:`` plane — the
+    device's clock — children inside their parents."""
+    import jax
+    telemetry.set_enabled(True)
+    step, sparams, state_info = _lstm_step()
+    de = DecodeEngine(step, sparams, {}, state_info, num_slots=2,
+                      max_len=32)
+    de.warmup()
+    X = np.random.RandomState(0).randn(16, 6).astype(np.float32)
+    Y = np.array([0, 1, 2, 3] * 4, np.float32)
+    it = mx.io.NDArrayIter(X, Y, batch_size=8)
+    mod = mx.mod.Module(_mlp()[0], context=mx.cpu())
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        de.submit([1, 2, 3], max_new_tokens=3).result(timeout=120)
+        mod.fit(it, num_epoch=1, optimizer_params={"learning_rate": 0.1})
+    finally:
+        jax.profiler.stop_trace()
+        de.close()
+    lines = _host_spans(str(tmp_path))
+    names = {n for evs in lines for n, _s, _e in evs}
+    assert {"mx:decode.step", "mx:decode.step.scan",
+            "mx:decode.step.dispatch", "mx:decode.step.read",
+            "mx:decode.step.deliver", "mx:fit.step", "mx:fit.fwd_bwd",
+            "mx:executor.forward_backward", "mx:fit.optimizer",
+            "mx:update/fc1_weight", "mx:update/fc2_bias"} <= names
+
+    def inside(child, parent):
+        """Every ``child`` event lies inside a ``parent`` event of its
+        own thread."""
+        n = 0
+        for evs in lines:
+            parents = [(s, e) for name, s, e in evs if name == parent]
+            for name, s, e in evs:
+                if name == child:
+                    n += 1
+                    assert any(ps <= s and e <= pe for ps, pe in parents), \
+                        "%s outside every %s" % (child, parent)
+        assert n, "no %s event" % child
+    inside("mx:decode.step.dispatch", "mx:decode.step")
+    inside("mx:decode.step.read", "mx:decode.step")
+    inside("mx:fit.optimizer", "mx:fit.step")
+    inside("mx:update/fc1_weight", "mx:fit.optimizer")
+    inside("mx:executor.forward_backward", "mx:fit.fwd_bwd")
+    # two steps of two batches: one updater call a parameter a step
+    upd = [n for evs in lines for n, _s, _e in evs
+           if n.startswith("mx:update/")]
+    assert len(upd) == 2 * 4
 
 
 # ---------------------------------------------------------------------------
