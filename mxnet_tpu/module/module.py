@@ -498,11 +498,13 @@ class Module(BaseModule):
             self._update_impl(sp)
 
     def _update_impl(self, sp):
-        """One updater call a parameter that has a gradient.  ``sp`` is
-        what ``active_phase`` yielded, the open ``fit.optimizer`` span:
-        with the timeline plane on each call is marked
-        ``mx:update/<parameter>`` in the profiler's trace, and their
-        count rides the span as ``updates``."""
+        """One updater call with every parameter that has a gradient
+        (on the kvstore, one push and pull each).  ``sp`` is what
+        ``active_phase`` yielded, the open ``fit.optimizer`` span: the
+        number of update programs dispatched rides it as ``updates``
+        (1 where the optimizer has a multi-tensor rule and takes it),
+        and with the timeline plane on each is marked in the profiler's
+        trace, ``mx:update/multi_tensor`` or ``mx:update/<parameter>``."""
         mark = sp.child
         updates = 0
         if self._update_on_kvstore:
@@ -521,13 +523,16 @@ class Module(BaseModule):
                         continue
                     self._kvstore.push(name, g)
                     self._kvstore.pull(name, out=g)
-            for idx, name in enumerate(self._param_names):
-                g = self._exec.grad_dict.get(name)
-                if g is None:
-                    continue
-                with mark("update/" + name):
-                    self._updater(idx, g, self._exec.arg_dict[name])
-                updates += 1
+            names = self._param_names
+            idxs = [idx for idx, name in enumerate(names)
+                    if self._exec.grad_dict.get(name) is not None]
+            if idxs:
+                updates = self._updater(
+                    idxs, [self._exec.grad_dict[names[i]] for i in idxs],
+                    [self._exec.arg_dict[names[i]] for i in idxs],
+                    mark=lambda idx: mark(
+                        "update/" + ("multi_tensor" if idx is None
+                                     else names[idx])))
         sp.args = {"updates": updates}
 
     def get_outputs(self, merge_multi_context=True):

@@ -5,10 +5,13 @@ mp_sgd_update:111, mp_sgd_mom_update:128, adam_update:146, rmsprop_update:195,
 rmspropalex_update:245, ftrl_update:286).
 
 The reference fuses optimizer math into single kernels to avoid temporaries;
-here each update is one jitted XLA computation (and the Module/Trainer fast
-path additionally fuses updates for *all* parameters into the train step —
-the `update_on_kvstore` collapse, see mxnet_tpu.kvstore).  State (momentum
-etc.) is an input returned updated via ``mutate_aux``.
+here each registered update is one jitted XLA computation a parameter, keyed
+by its attributes (a new `lr` is a new program).  State (momentum etc.) is
+an input returned updated via ``mutate_aux``.  ``multi_sgd_update`` applies
+`sgd_update` / `sgd_mom_update` to a whole list of parameters in one
+program with the hyper-parameters as operands: what `SGD.update_multi`, and
+through it `Module.update`, dispatches once a step.  Every other caller
+(`gluon.Trainer`, `KVStore`, the other optimizers) runs one op a parameter.
 
 All updates implement: weight' = f(weight, grad * rescale_grad clipped, state)
 with weight-decay folded in exactly as the reference does.
@@ -58,6 +61,36 @@ def sgd_mom_update(attrs, weight, grad, mom):
     new_mom = attrs["momentum"] * mom - attrs["lr"] * (g + attrs["wd"] * weight)
     new_w = weight + new_mom
     return new_w, new_mom
+
+
+def multi_sgd_update(weights, grads, moms, lrs, wds, rescale_grad, momentum,
+                     clip_gradient=-1.0):
+    """`sgd_update` (where a parameter's ``moms`` entry is None) or
+    `sgd_mom_update` over equally long tuples, the reference's
+    multi_sgd(_mom)_update.  ``lrs`` and ``wds`` are float32 vectors with
+    one entry a parameter, ``rescale_grad`` and ``momentum`` float32
+    scalars: operands, each cast to the dtype a Python float would take
+    beside that array, so the arithmetic per element is the registered
+    op's (to the bit for float32 arrays, the only ones `SGD.update_multi`
+    sends; a narrower one gets its rate through float32).  What picks the expression is not an operand: ``clip_gradient``
+    is a Python float, and ``rescale_grad`` None stands for the 1.0 that
+    XLA drops from the registered op (a product kept there would pair up
+    differently into fused multiply-adds).  Returns (new weights, new
+    momenta)."""
+    new_ws, new_moms = [], []
+    for i, (w, g, m) in enumerate(zip(weights, grads, moms)):
+        attrs = {"lr": lrs[i].astype(w.dtype), "wd": wds[i].astype(w.dtype),
+                 "rescale_grad": 1.0 if rescale_grad is None
+                 else rescale_grad.astype(g.dtype),
+                 "clip_gradient": clip_gradient}
+        if m is None:
+            (new_w,), new_m = sgd_update(attrs, w, g), None
+        else:
+            attrs["momentum"] = momentum.astype(m.dtype)
+            new_w, new_m = sgd_mom_update(attrs, w, g, m)
+        new_ws.append(new_w)
+        new_moms.append(new_m)
+    return tuple(new_ws), tuple(new_moms)
 
 
 @register("mp_sgd_update", nin=3, input_names=["weight", "grad", "weight32"],

@@ -10,12 +10,15 @@ TPU-native redesign: the hot optimizers (SGD/Adam/RMSProp/Ftrl/SignSGD) call
 the fused update *ops* (mxnet_tpu/ops/optimizer_ops.py), so every update is a
 single XLA computation on-device, and the Module/Trainer fast path can inline
 these same impls into the jitted train step (the `update_on_kvstore` collapse).
-The long-tail optimizers are jnp math through the same invoke path.  All
-hyper-params (lr, wd) stay Python scalars passed per call — jit caches one
-program per op config, not per lr value.
+The long-tail optimizers are jnp math through the same invoke path.  An
+update op's hyper-params (lr, wd) are attributes, so jit caches one program
+per value; `update_multi` (SGD) applies a whole list of parameters in one
+program whose hyper-params are operands.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import logging
 import math
 import pickle
@@ -32,6 +35,10 @@ __all__ = ["Optimizer", "SGD", "DCASGD", "NAG", "SGLD", "ccSGD", "Adam",
            "AdaGrad", "RMSProp", "AdaDelta", "Ftrl", "Adamax", "Nadam",
            "Signum", "SignSGD", "Test", "Updater", "get_updater", "create",
            "register"]
+
+
+def _no_mark(index):
+    return contextlib.nullcontext()
 
 
 class Optimizer(object):
@@ -89,6 +96,19 @@ class Optimizer(object):
 
     def update(self, index, weight, grad, state):
         raise NotImplementedError()
+
+    def update_multi(self, indices, weights, grads, states, mark=_no_mark):
+        """Update a list of parameters; returns how many update programs
+        were dispatched.  The default is the loop over `update`; an
+        optimizer with a multi-tensor rule (SGD) overrides it.
+        ``mark(index)`` gives a context manager held around that
+        parameter's update, ``mark(None)`` one held around a program
+        that updates them all: the caller's trace marks."""
+        for index, weight, grad, state in zip(indices, weights, grads,
+                                              states):
+            with mark(index):
+                self.update(index, weight, grad, state)
+        return len(indices)
 
     def set_lr_mult(self, args_lr_mult):
         """Per-param lr multipliers, seeded from symbol __lr_mult__ attrs."""
@@ -298,6 +318,72 @@ class SGD(Optimizer):
                                   out=weight, **kwargs)
             else:
                 mp_sgd_update(weight, grad, state[1], out=weight, **kwargs)
+
+    def update_multi(self, indices, weights, grads, states, mark=_no_mark):
+        """One `multi_sgd_update` program for the whole list where every
+        weight, gradient and momentum is dense and float32 (the rates are
+        float32 operands: a narrower weight would see its rate rounded
+        twice, the loop rounds it once), no state is a (momentum, float32
+        master) tuple and no subclass has its own `update`; else the
+        loop.  Host side it is the loop's bookkeeping in the loop's
+        order; lr, wd, rescale_grad and momentum are operands, so a
+        scheduler's new rate compiles nothing.  Results land in the same
+        NDArray objects."""
+        def fusable(weight, grad, state):
+            return (weight.stype == "default" and grad.stype == "default"
+                    and weight.dtype == numpy.float32
+                    and grad.dtype == weight.dtype
+                    and (state is None or (
+                        isinstance(state, NDArray)
+                        and state.stype == "default"
+                        and state.dtype == weight.dtype)))
+        if type(self).update is not SGD.update \
+                or not all(map(fusable, weights, grads, states)):
+            return super().update_multi(indices, weights, grads, states,
+                                        mark)
+        lrs, wds = [], []
+        for index in indices:
+            self._update_count(index)
+            lrs.append(self._get_lr(index))
+            wds.append(self._get_wd(index))
+        # arrays fresh from an initializer or `zeros` are uncommitted and
+        # come back committed: left so, the first call would compile one
+        # program and every later call another.  A momentum goes where
+        # its weight lives (under a ShardingPlan, on every chip)
+        import jax
+        for weight, state in zip(weights, states):
+            sharding = weight._data.sharding
+            for array in (weight, state):
+                if array is not None and not array._data.committed:
+                    array._data = jax.device_put(array._data, sharding)
+        with mark(None):
+            new_weights, new_moms = _multi_sgd_jit()(
+                tuple(w._data for w in weights),
+                tuple(g._data for g in grads),
+                tuple(None if s is None else s._data for s in states),
+                numpy.asarray(lrs, numpy.float32),
+                numpy.asarray(wds, numpy.float32),
+                None if self.rescale_grad == 1.0
+                else numpy.float32(self.rescale_grad),
+                numpy.float32(self.momentum if self.momentum > 0 else 0.0),
+                clip_gradient=float(self.clip_gradient or -1.0))
+        for weight, state, new_w, new_m in zip(weights, states, new_weights,
+                                               new_moms):
+            weight._data = new_w
+            if state is not None:
+                state._data = new_m
+        return 1
+
+
+@functools.cache
+def _multi_sgd_jit():
+    """`ops.optimizer_ops.multi_sgd_update` under one `jax.jit`, built at
+    the first multi-tensor update (nothing at import).  Nothing is
+    donated: `copyto` and `detach` share buffers, so an array a caller
+    holds may alias a weight or a momentum, and must outlive the step."""
+    import jax
+    from .ops.optimizer_ops import multi_sgd_update
+    return jax.jit(multi_sgd_update, static_argnames=("clip_gradient",))
 
 
 @register
@@ -701,7 +787,18 @@ class Updater(object):
         self.states = {}
         self.states_synced = {}
 
-    def __call__(self, index, grad, weight):
+    def __call__(self, index, grad, weight, mark=_no_mark):
+        """Update one key, or lists of keys, gradients and weights in one
+        call (``mark``: see `Optimizer.update_multi`); returns how many
+        update programs the optimizer dispatched."""
+        if not isinstance(index, (list, tuple)):
+            self.optimizer.update(index, weight, grad,
+                                  self._state(index, weight))
+            return 1
+        states = [self._state(i, w) for i, w in zip(index, weight)]
+        return self.optimizer.update_multi(index, weight, grad, states, mark)
+
+    def _state(self, index, weight):
         if index not in self.states:
             self.states[index] = self.optimizer.create_state(index, weight)
             self.states_synced[index] = True
@@ -709,7 +806,7 @@ class Updater(object):
             self.states[index] = self.sync_state_context(self.states[index],
                                                          weight.context)
             self.states_synced[index] = True
-        self.optimizer.update(index, weight, grad, self.states[index])
+        return self.states[index]
 
     def sync_state_context(self, state, context):
         if isinstance(state, NDArray):

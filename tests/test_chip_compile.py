@@ -192,6 +192,33 @@ def test_resnet50_module_step_fits_v5e(one_chip):
     assert _total_bytes(compiled) < HBM_BYTES
 
 
+def test_resnet50_update_program_compiles_for_v5e(one_chip):
+    """The one program ``Module.update`` dispatches a step under SGD
+    with momentum: every ResNet-50 parameter that has a gradient, the
+    rates and decays as operands.  Nothing is donated (a caller's array
+    may share a weight's or a momentum's buffer): new weights and momenta
+    live beside the old ones, five copies of the 102 MB (weights,
+    gradients, momenta, new weights, new momenta)."""
+    import jax
+    import chip_smoke
+    from mxnet_tpu import optimizer as opt
+    mod = chip_smoke.bound_train_module(chip_smoke.SIZES["train"],
+                                        mx.cpu(0))
+    weights = tuple(mod._exec.arg_dict[n]._data for n in mod._param_names
+                    if mod._exec.grad_dict.get(n) is not None)
+    n, nbytes = len(weights), sum(w.nbytes for w in weights)
+    assert n == 157 and 100e6 < nbytes < 105e6
+    vec = jax.ShapeDtypeStruct((n,), np.float32)
+    scalar = jax.ShapeDtypeStruct((), np.float32)
+    args = _described((weights, weights, weights, vec, vec, scalar, scalar),
+                      one_chip)
+    compiled = opt._multi_sgd_jit().lower(*args).compile()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes == 0
+    assert ma.output_size_in_bytes >= 2 * nbytes
+    assert _total_bytes(compiled) < 5.5 * nbytes
+
+
 # ---------------------------------------------------------------------------
 # the static memory planner against the number it exists to predict
 # ---------------------------------------------------------------------------

@@ -1,6 +1,7 @@
 """Module API tests, incl. the end-to-end training slice (SURVEY §7 stage 4;
 reference tests/python/unittest/test_module.py + tests/python/train/)."""
 import os
+import pickle
 
 import numpy as np
 import pytest
@@ -285,3 +286,252 @@ def test_resnet_s2d_stem_exact_equivalence():
                        grad_req={n: "null" for n in names})
         outs[stem] = exe.forward(is_train=False)[0].asnumpy()
     np.testing.assert_allclose(outs["conv7"], outs["s2d"], atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# Module.update through the multi-tensor SGD rule (ISSUE 26)
+# ---------------------------------------------------------------------------
+
+class _Span(object):
+    """Stands in for the open ``fit.optimizer`` span: keeps ``args`` and
+    the names of the child marks."""
+
+    def __init__(self):
+        import contextlib
+        self.args, self.marks = None, []
+        self._null = contextlib.nullcontext()
+
+    def child(self, name):
+        self.marks.append(name)
+        return self._null
+
+
+def _per_parameter(monkeypatch):
+    """SGD as it was before it had a multi-tensor rule: the loop."""
+    from mxnet_tpu import optimizer as opt
+    monkeypatch.setattr(opt.SGD, "update_multi", opt.Optimizer.update_multi)
+
+
+@pytest.mark.parametrize("momentum", [0.0, 0.9])
+def test_fit_sgd_matches_the_per_parameter_path(monkeypatch, momentum):
+    X, y = _toy_classification()
+
+    def fit():
+        np.random.seed(3)
+        mx.random.seed(3)
+        mod = mx.mod.Module(_mlp_symbol(), context=mx.cpu())
+        mod.fit(mio.NDArrayIter(X, y, batch_size=32), optimizer="sgd",
+                optimizer_params={"learning_rate": 0.1, "wd": 1e-3,
+                                  "momentum": momentum},
+                num_epoch=2, initializer=mx.init.Xavier())
+        states = {k: None if v is None else v.asnumpy()
+                  for k, v in mod._updater.states.items()}
+        return ({k: v.asnumpy() for k, v in mod.get_params()[0].items()},
+                states, mod)
+    got, got_states, mod = fit()
+    sp = _Span()
+    mod._update_impl(sp)
+    assert sp.args == {"updates": 1} and sp.marks == ["update/multi_tensor"]
+    _per_parameter(monkeypatch)
+    want, want_states, mod = fit()
+    sp = _Span()
+    mod._update_impl(sp)
+    assert sp.args == {"updates": 4}
+    assert sp.marks == ["update/" + n for n in mod._param_names]
+    assert sorted(got) == sorted(want) and len(got) == 4
+    for k in want:
+        assert np.array_equal(got[k], want[k]), k
+    for k in want_states:
+        assert (want_states[k] is None) == (momentum == 0.0)
+        assert np.array_equal(got_states[k], want_states[k]), k
+
+
+def test_update_program_compiles_once():
+    """Parameters come uncommitted from the initializer and momenta
+    from `zeros`, and both come back committed: the first update commits
+    them where the weight lives, so the second one compiles nothing."""
+    from test_optimizer import _Compiles
+    X, y = _toy_classification(n=32)
+    # a width no other test of this process has compiled an update for
+    mod = mx.mod.Module(_mlp_symbol(num_hidden=29), context=mx.cpu())
+    mod.bind(data_shapes=[("data", (32, 16))],
+             label_shapes=[("softmax_label", (32,))])
+    mod.init_params(mx.init.Xavier())
+    mod.init_optimizer(kvstore=None, optimizer="sgd", optimizer_params={
+        "learning_rate": 0.1, "momentum": 0.9})
+    assert not mod._exec.arg_dict["fc1_weight"]._data.committed
+    batch = mio.DataBatch(data=[mx.nd.array(X)], label=[mx.nd.array(y)])
+    counts = []
+    for _ in range(3):
+        mod.forward_backward(batch)
+        with _Compiles() as c:
+            mod.update()
+        counts.append(c.n)
+    assert counts[0] >= 1 and counts[1:] == [0, 0], counts
+
+
+def _embedding_net():
+    data = mx.sym.Variable("data")
+    emb = mx.sym.Embedding(data, input_dim=40, output_dim=8,
+                           sparse_grad=True, name="embed")
+    fc = mx.sym.FullyConnected(mx.sym.Flatten(emb), num_hidden=4, name="fc")
+    return mx.sym.SoftmaxOutput(fc, name="softmax")
+
+
+def _half_net():
+    w1 = mx.sym.Variable("fc1_weight", dtype=np.float16)
+    b1 = mx.sym.Variable("fc1_bias", dtype=np.float16)
+    fc1 = mx.sym.FullyConnected(mx.sym.Variable("data"), weight=w1, bias=b1,
+                                num_hidden=8, name="fc1")
+    fc2 = mx.sym.FullyConnected(mx.sym.Activation(fc1, act_type="relu"),
+                                num_hidden=4, name="fc2")
+    return mx.sym.SoftmaxOutput(mx.sym.Cast(fc2, dtype="float32"),
+                                name="softmax")
+
+
+@pytest.mark.parametrize("case", ["row_sparse", "multi_precision"])
+def test_update_takes_the_loop_where_sgd_cannot_fuse(monkeypatch, case):
+    """A row-sparse gradient, or float16 weights with float32 master
+    copies: one program a parameter, with the lazy rows and the master
+    copies the loop always gave."""
+    rng = np.random.RandomState(0)
+    if case == "row_sparse":
+        net, dtype = _embedding_net(), np.float32
+        data = rng.randint(0, 20, (8, 5)).astype(dtype)  # rows 20.. unseen
+    else:
+        net, dtype = _half_net(), np.float16
+        data = rng.randn(8, 6).astype(dtype)
+    batch = mio.DataBatch(data=[mx.nd.array(data, dtype=dtype)],
+                          label=[mx.nd.array(np.arange(8) % 4)])
+
+    def two_steps():
+        np.random.seed(5)
+        mod = mx.mod.Module(net, context=mx.cpu())
+        mod.bind(data_shapes=[mio.DataDesc("data", data.shape, dtype)],
+                 label_shapes=[mio.DataDesc("softmax_label", (8,))])
+        mod.init_params(mx.init.Xavier())
+        before = {k: v.asnumpy() for k, v in mod.get_params()[0].items()}
+        mod.init_optimizer(kvstore=None, optimizer="sgd", optimizer_params={
+            "learning_rate": 0.1, "momentum": 0.9, "wd": 1e-2,
+            "multi_precision": case == "multi_precision"})
+        for _ in range(2):
+            mod.forward_backward(batch)
+            sp = _Span()
+            mod._update_impl(sp)
+        return mod, sp, before
+    mod, sp, before = two_steps()
+    names = mod._param_names
+    assert sp.args == {"updates": len(names)}
+    assert sp.marks == ["update/" + n for n in names]
+    after = {k: v.asnumpy() for k, v in mod.get_params()[0].items()}
+    if case == "row_sparse":
+        assert mod._exec.grad_dict["embed_weight"].stype == "row_sparse"
+        # lazy update: rows no batch touched keep even their weight decay
+        # (but the last: the gradient's padding rows carry index -1 and
+        # `_rsp_sgd_update` takes that for row 39, as it did before)
+        assert np.array_equal(after["embed_weight"][20:39],
+                              before["embed_weight"][20:39])
+        assert not np.array_equal(after["embed_weight"][:20],
+                                  before["embed_weight"][:20])
+    else:
+        state = mod._updater.states[names.index("fc1_weight")]
+        assert isinstance(state, tuple) and state[1].dtype == np.float32
+        assert after["fc1_weight"].dtype == np.float16
+        assert np.array_equal(after["fc1_weight"],
+                              state[1].asnumpy().astype(np.float16))
+    _per_parameter(monkeypatch)
+    want = {k: v.asnumpy()
+            for k, v in two_steps()[0].get_params()[0].items()}
+    for k in want:
+        assert not np.array_equal(after[k], before[k]), k
+        assert np.array_equal(after[k], want[k]), k
+
+
+def test_arrays_read_before_an_update_stay_readable():
+    """The update program is given no buffer to keep: what
+    `get_params()`, the arrays the caller initialised from (the
+    executor's alias them), the executor's own handles and the momenta's
+    held before a step is what they hold after it, and a `get_states()`
+    taken before still loads."""
+    X, y = _toy_classification(n=32)
+    mod = mx.mod.Module(_mlp_symbol(), context=mx.cpu())
+    mod.bind(data_shapes=[("data", (32, 16))],
+             label_shapes=[("softmax_label", (32,))])
+    rng = np.random.RandomState(0)
+    given = {n: mx.nd.array(rng.randn(*mod._exec.arg_dict[n].shape) * 0.1)
+             for n in mod._param_names}
+    given_np = {k: v.asnumpy() for k, v in given.items()}
+    mod.init_params(arg_params=given, aux_params={})
+    mod.init_optimizer(kvstore=None, optimizer="sgd", optimizer_params={
+        "learning_rate": 0.1, "momentum": 0.9})
+    batch = mio.DataBatch(data=[mx.nd.array(X)], label=[mx.nd.array(y)])
+    mod.forward_backward(batch)
+    mod.update()
+    held = dict(mod.get_params()[0])
+    raw = {n: mod._exec.arg_dict[n]._data for n in mod._param_names}
+    moms = {k: v._data for k, v in mod._updater.states.items()}
+    blob = mod._updater.get_states()
+    saved = pickle.loads(blob)
+    want = {k: v.asnumpy() for k, v in held.items()}
+    mod.forward_backward(batch)
+    mod.update()
+    for k in held:
+        assert np.array_equal(given[k].asnumpy(), given_np[k]), k
+        assert np.array_equal(held[k].asnumpy(), want[k]), k
+        assert np.array_equal(np.asarray(raw[k]), want[k]), k
+        assert not np.array_equal(mod._exec.arg_dict[k].asnumpy(), want[k])
+    for k, m in moms.items():
+        new = mod._updater.states[k]
+        assert new._data is not m and np.isfinite(new.asnumpy()).all()
+        # nothing is donated: a handle taken off the state before the
+        # step still reads what the state then held
+        assert np.array_equal(np.asarray(m), saved[k]), k
+    assert sorted(saved) == sorted(moms)
+    mod._updater.set_states(blob)
+    mod.forward_backward(batch)
+    mod.update()
+
+
+def test_borrowed_optimizer_shares_the_momenta(monkeypatch):
+    """A module bound with `shared_module` borrows the updater, momenta
+    included, and its weights start as aliases of the other's: each
+    module's step leaves the other's arrays readable, and the two take
+    the steps the per-parameter path takes."""
+    X, y = _toy_classification(n=32)
+    batch = mio.DataBatch(data=[mx.nd.array(X)], label=[mx.nd.array(y)])
+
+    def run():
+        np.random.seed(5)
+        mx.random.seed(5)
+        first = mx.mod.Module(_mlp_symbol(), context=mx.cpu())
+        first.bind(data_shapes=[("data", (32, 16))],
+                   label_shapes=[("softmax_label", (32,))])
+        first.init_params(mx.init.Xavier())
+        first.init_optimizer(kvstore=None, optimizer="sgd", optimizer_params={
+            "learning_rate": 0.1, "momentum": 0.9})
+        second = mx.mod.Module(_mlp_symbol(), context=mx.cpu())
+        second.bind(data_shapes=[("data", (32, 16))],
+                    label_shapes=[("softmax_label", (32,))],
+                    shared_module=first)
+        assert second._updater is first._updater
+        seen = []
+        for mod in (first, second, first):
+            mod.forward_backward(batch)
+            states = dict(mod._updater.states)
+            mod.update()
+            assert all(mod._updater.states[k] is v
+                       for k, v in states.items())
+            seen.append({k: v.asnumpy()
+                         for k, v in first.get_params()[0].items()})
+            seen.append({k: v.asnumpy()
+                         for k, v in second.get_params()[0].items()})
+        seen.append({k: v.asnumpy() for k, v in first._updater.states.items()})
+        return seen
+    got = run()
+    _per_parameter(monkeypatch)
+    want = run()
+    for a, b in zip(got, want):
+        assert sorted(a) == sorted(b)
+        for k in b:
+            assert np.array_equal(a[k], b[k]), k
+    assert not np.array_equal(got[0]["fc1_weight"], got[1]["fc1_weight"])

@@ -598,3 +598,63 @@ def test_hetero_pipeline_aux_matches_serial():
             np.testing.assert_allclose(
                 np.asarray(aux_now[j][n]), np.asarray(aux_ref[j][n]),
                 rtol=1e-5, atol=1e-6, err_msg="stage %d %s" % (j, n))
+
+
+def test_multi_tensor_update_keeps_sharding_on_four_devices():
+    """One update program over replicated and tp-sharded parameters
+    (ISSUE 26): weights and momenta come back with the sharding they
+    went in with, and equal the one-device run."""
+    import jax
+    X, y = _toy(n=64)
+    batch = mio.DataBatch(data=[mx.nd.array(X)], label=[mx.nd.array(y)])
+
+    def run(plan):
+        np.random.seed(11)
+        mod = mx.mod.Module(_mlp_symbol(), context=mx.cpu())
+        if plan is not None:
+            mod.set_sharding_plan(plan)
+        mod.bind(data_shapes=[("data", (64, 16))],
+                 label_shapes=[("softmax_label", (64,))])
+        mod.init_params(mx.init.Xavier())
+        mod.init_optimizer(kvstore=None, optimizer="sgd", optimizer_params={
+            "learning_rate": 0.1, "momentum": 0.9, "wd": 1e-3,
+            "rescale_grad": 1. / 64})
+        shardings = []
+        for _ in range(3):
+            mod.forward_backward(batch)
+            mod.update()
+            shardings.append(
+                {n: (mod._exec.arg_dict[n]._data.sharding,
+                     mod._updater.states[i]._data.sharding)
+                 for i, n in enumerate(mod._param_names)})
+        params = {n: mod._exec.arg_dict[n].asnumpy()
+                  for n in mod._param_names}
+        moms = {n: mod._updater.states[i].asnumpy()
+                for i, n in enumerate(mod._param_names)}
+        return mod, shardings, params, moms
+
+    four = jax.devices()[:4]
+    for plan in (data_parallel_plan(devices=four),
+                 ShardingPlan(make_mesh({"dp": 2, "tp": 2}, devices=four),
+                              batch_axis="dp",
+                              param_rules=[(r"fc1_weight", ("tp", None))])):
+        mod, shardings, params, moms = run(plan)
+        for name in mod._param_names:
+            w_sh, m_sh = shardings[0][name]
+            want = plan.param_sharding(name, params[name].shape)
+            assert w_sh.is_equivalent_to(want, params[name].ndim), name
+            assert len(w_sh.device_set) == 4
+            for later in shardings[1:]:
+                assert later[name][0].is_equivalent_to(w_sh,
+                                                       params[name].ndim)
+                assert later[name][1].is_equivalent_to(m_sh,
+                                                       params[name].ndim)
+            # the momentum lives where its weight lives, from the first
+            # step (its zeros are created on one device, uncommitted)
+            assert m_sh.is_equivalent_to(w_sh, params[name].ndim), name
+        _mod, _sh, ref_params, ref_moms = run(None)
+        for name in ref_params:
+            np.testing.assert_allclose(params[name], ref_params[name],
+                                       rtol=1e-4, atol=1e-5, err_msg=name)
+            np.testing.assert_allclose(moms[name], ref_moms[name],
+                                       rtol=1e-4, atol=1e-5, err_msg=name)

@@ -50,7 +50,7 @@ def _mlp(feature=6, hidden=16, classes=3):
 
 
 def _toy_fit(num_epoch=1, kvstore=None, batch=8, n=24, feature=6,
-             monitor=None, seed=0):
+             monitor=None, seed=0, optimizer="sgd"):
     """3-steps-per-epoch toy fit; returns the fitted Module."""
     np.random.seed(seed)
     mx.random.seed(seed)
@@ -59,7 +59,7 @@ def _toy_fit(num_epoch=1, kvstore=None, batch=8, n=24, feature=6,
     Y = rng.randint(0, 3, (n,)).astype(np.float32)
     it = mx.io.NDArrayIter(X, Y, batch_size=batch)
     mod = mx.mod.Module(_mlp(feature=feature), context=mx.cpu())
-    mod.fit(it, num_epoch=num_epoch,
+    mod.fit(it, num_epoch=num_epoch, optimizer=optimizer,
             optimizer_params={"learning_rate": 0.1},
             kvstore=kvstore if kvstore is not None else "local",
             monitor=monitor)
@@ -188,21 +188,30 @@ def _ring(name=None):
             and (name is None or e["name"] == name)]
 
 
-def test_fit_ring_holds_one_step_event_and_its_phases(monkeypatch):
+# an optimizer without a multi-tensor rule dispatches one update program
+# a parameter; `sgd` has the rule and dispatches one a step (ISSUE 26)
+_UPDATE_PATHS = pytest.mark.parametrize(
+    "optimizer, fused", [("nag", False), ("sgd", True)])
+
+
+@_UPDATE_PATHS
+def test_fit_ring_holds_one_step_event_and_its_phases(monkeypatch, optimizer,
+                                                      fused):
     from mxnet_tpu.telemetry import timeline
     monkeypatch.setenv("MXNET_TELEMETRY_TRACE_SAMPLE", "1")
     timeline.reset()
-    mod = _toy_fit()                    # 3 steps
+    mod = _toy_fit(optimizer=optimizer)  # 3 steps
     steps = _ring("fit.step")
     assert [e["args"]["step"] for e in steps] == [1, 2, 3]
     assert steps[0]["args"]["compiles"] >= 1
     assert steps[-1]["args"]["compiles"] == 0
-    # the updates counter: one updater call a parameter with a gradient
+    # the updates counter: the update programs dispatched, one a
+    # parameter with a gradient or one for them all
     with_grad = [n for n in mod._param_names
                  if mod._exec.grad_dict.get(n) is not None]
     assert len(with_grad) == 4
     opt = _ring("fit.optimizer")
-    assert [e["args"]["updates"] for e in opt] == [4, 4, 4]
+    assert [e["args"]["updates"] for e in opt] == [1 if fused else 4] * 3
     for name in ("fit.fwd_bwd", "fit.h2d", "fit.metric"):
         assert len(_ring(name)) == 3, name
     # the ring's budget: a step and its phases, about 8 events a step
@@ -224,7 +233,9 @@ def test_fit_ring_holds_one_step_event_and_its_phases(monkeypatch):
     timeline.reset()
 
 
-def test_fit_with_the_plane_off_appends_and_annotates_nothing(monkeypatch):
+@_UPDATE_PATHS
+def test_fit_with_the_plane_off_appends_and_annotates_nothing(
+        monkeypatch, optimizer, fused):
     """Telemetry on, timeline plane off: the phase histograms still
     fill, the ring never materializes, no profiler annotation is made,
     and the fitted parameters are bitwise those of the plane on."""
@@ -238,7 +249,7 @@ def test_fit_with_the_plane_off_appends_and_annotates_nothing(monkeypatch):
         monkeypatch.setenv("MXNET_TELEMETRY_TIMELINE", plane)
         telemetry.reset()
         timeline.reset()
-        mod = _toy_fit(num_epoch=2)
+        mod = _toy_fit(num_epoch=2, optimizer=optimizer)
         return {k: v.asnumpy() for k, v in mod.get_params()[0].items()}
 
     off = run("0")
@@ -249,7 +260,11 @@ def test_fit_with_the_plane_off_appends_and_annotates_nothing(monkeypatch):
             doc["mxnet_train_step_phase_seconds"]["series"]} >= {
                 "fwd_bwd", "optimizer", "metric"}
     on = run("1")
-    assert "mx:fit.step" in marks and "mx:update/fc1_weight" in marks
+    assert "mx:fit.step" in marks
+    updates = [m for m in marks if m.startswith("mx:update/")]
+    assert updates == (["mx:update/multi_tensor"] * 6 if fused else [
+        "mx:update/" + n for n in ("fc1_weight", "fc1_bias", "fc2_weight",
+                                   "fc2_bias")] * 6)
     assert len(_ring("fit.step")) == 6
     for k in off:
         assert np.array_equal(off[k], on[k]), k

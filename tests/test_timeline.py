@@ -538,7 +538,12 @@ def test_rehabilitated_replica_times_with_the_engines_gate(monkeypatch,
                                   and e["lane"] != "trace"]
 
 
-def test_profiler_trace_holds_the_programs_spans(tmp_path):
+@pytest.mark.parametrize("optimizer, update_marks", [
+    ("nag", ["mx:update/fc1_weight", "mx:update/fc1_bias",
+             "mx:update/fc2_weight", "mx:update/fc2_bias"]),
+    ("sgd", ["mx:update/multi_tensor"])])
+def test_profiler_trace_holds_the_programs_spans(tmp_path, optimizer,
+                                                 update_marks):
     """A ``jax.profiler`` trace around a few decode steps and a short
     ``fit`` holds the program's spans on a ``/host:`` plane — the
     device's clock — children inside their parents."""
@@ -555,7 +560,8 @@ def test_profiler_trace_holds_the_programs_spans(tmp_path):
     jax.profiler.start_trace(str(tmp_path))
     try:
         de.submit([1, 2, 3], max_new_tokens=3).result(timeout=120)
-        mod.fit(it, num_epoch=1, optimizer_params={"learning_rate": 0.1})
+        mod.fit(it, num_epoch=1, optimizer=optimizer,
+                optimizer_params={"learning_rate": 0.1})
     finally:
         jax.profiler.stop_trace()
         de.close()
@@ -564,8 +570,7 @@ def test_profiler_trace_holds_the_programs_spans(tmp_path):
     assert {"mx:decode.step", "mx:decode.step.scan",
             "mx:decode.step.dispatch", "mx:decode.step.read",
             "mx:decode.step.deliver", "mx:fit.step", "mx:fit.fwd_bwd",
-            "mx:executor.forward_backward", "mx:fit.optimizer",
-            "mx:update/fc1_weight", "mx:update/fc2_bias"} <= names
+            "mx:executor.forward_backward", "mx:fit.optimizer"} <= names
 
     def inside(child, parent):
         """Every ``child`` event lies inside a ``parent`` event of its
@@ -582,12 +587,14 @@ def test_profiler_trace_holds_the_programs_spans(tmp_path):
     inside("mx:decode.step.dispatch", "mx:decode.step")
     inside("mx:decode.step.read", "mx:decode.step")
     inside("mx:fit.optimizer", "mx:fit.step")
-    inside("mx:update/fc1_weight", "mx:fit.optimizer")
+    for name in update_marks:
+        inside(name, "mx:fit.optimizer")
     inside("mx:executor.forward_backward", "mx:fit.fwd_bwd")
-    # two steps of two batches: one updater call a parameter a step
+    # two steps of two batches: one mark an update program, which is one
+    # a parameter a step, or one a step where the optimizer takes them all
     upd = [n for evs in lines for n, _s, _e in evs
            if n.startswith("mx:update/")]
-    assert len(upd) == 2 * 4
+    assert sorted(upd) == sorted(2 * update_marks)
 
 
 # ---------------------------------------------------------------------------
