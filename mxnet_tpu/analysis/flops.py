@@ -189,6 +189,8 @@ class FlopsPass(AnalysisPass):
             except Exception:
                 attrs = dict(n.attrs)
             rule = _RULES.get(n.op.name)
+            if rule is None and n.op.flops is not None:
+                rule = (n.op.flops, 2.0)    # declared at registration
             try:
                 if n.op.name in _ZERO_FLOP_OPS:
                     # modeled as exactly zero arithmetic (copies/layout/

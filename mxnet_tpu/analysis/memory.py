@@ -81,6 +81,40 @@ def _itemsize(dt):
         return _F32.itemsize
 
 
+def _is_view(node, dtypes):
+    """Whether the node's output is its first input's buffer seen
+    again: the view ops, and a ``Cast`` to the dtype its input already
+    has (the decode engine pins a low-precision pool's next-state
+    outputs so; XLA drops such a convert)."""
+    if node.op is None or not node.inputs:
+        return False
+    if node.op.name in _ALIAS_OPS:
+        return True
+    if node.op.name != "Cast":
+        return False
+    src, ix = node.inputs[0]
+    have = dtypes.get((id(src), ix))
+    return have is not None and have == dtypes.get((id(node), 0))
+
+
+def _temp_bytes(node, shapes, dtypes):
+    """Bytes of the node's own temporaries, where its op declares
+    them at registration (``OpDef.temp_bytes``): live only while the
+    node runs, on top of its inputs and outputs."""
+    rule = node.op.temp_bytes
+    if rule is None:
+        return 0
+    keys = [(id(i), ix) for (i, ix) in node.inputs]
+    ins = [shapes.get(k) for k in keys]
+    if any(s is None for s in ins):
+        return 0
+    try:
+        attrs = node.op.normalize(node.attrs)
+        return int(rule(attrs, ins, [dtypes.get(k, _F32) for k in keys]))
+    except Exception:
+        return 0
+
+
 def _axspec_divisor(shape, axspec, axes):
     """Product of mesh-axis sizes an axis-spec partitions ``shape`` by,
     with the plan's divisibility-drop: a named axis whose size does not
@@ -300,9 +334,7 @@ class MemoryPass(AnalysisPass):
             head_entries.add((id(h), hix))
             last_use[(id(h), hix)] = INF
         for n in reversed(topo):
-            if n.op is None or n.op.name not in _ALIAS_OPS:
-                continue
-            if not n.inputs:
+            if not _is_view(n, dtypes):
                 continue
             src, ix = n.inputs[0]
             mine = last_use.get((id(n), 0), -1)
@@ -318,9 +350,11 @@ class MemoryPass(AnalysisPass):
             ctx.memory_donation = donation
             for name, info in donation.per_input.items():
                 if info["sound"]:
-                    alias_credit.add(
-                        (id(view.heads[info["output"]][0]),
-                         view.heads[info["output"]][1]))
+                    # the buffer the output is, through any views of it
+                    node, ix = view.heads[info["output"]]
+                    while _is_view(node, dtypes):
+                        node, ix = node.inputs[0]
+                    alias_credit.add((id(node), ix))
             for node, reason in failures:
                 report.add(Diagnostic(
                     Severity.WARNING, self.name,
@@ -345,7 +379,7 @@ class MemoryPass(AnalysisPass):
             if n.op is None:
                 continue
             i = index[id(n)]
-            alias = n.op.name in _ALIAS_OPS
+            alias = _is_view(n, dtypes)
             out_total = 0
             try:
                 nout = n.num_outputs()
@@ -365,8 +399,7 @@ class MemoryPass(AnalysisPass):
                 if key in head_entries:
                     output_bytes += b
             live += out_total
-            if live > peak:
-                peak = live
+            peak = max(peak, live + _temp_bytes(n, shapes, dtypes))
             if out_total:
                 per_node.append((out_total, n.name, n.op.name,
                                  param_bytes + live))

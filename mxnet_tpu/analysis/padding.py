@@ -438,6 +438,8 @@ class PaddingSoundnessPass(AnalysisPass):
         handler = getattr(self, "_op_" + _HANDLERS.get(name, ""), None)
         if handler is not None:
             return handler(h)
+        if h.node.op.row_local is not None:
+            return self._declared_row_local(h)
 
         h.emit("no padding-soundness rule for op %r with a padded "
                "input — conservatively cross-position (add a transfer "
@@ -903,6 +905,27 @@ class PaddingSoundnessPass(AnalysisPass):
             axes.add(0)
         return [_Pad(axes, False,
                      cache.diffuse or row.diffuse or pos.diffuse)]
+
+    def _declared_row_local(self, h):
+        """An op that declared its independent axes at registration
+        (``OpDef.row_local``): ``"leading"`` computes along the last
+        axis only, so every other axis is a batch of positions;
+        ``"axis0"`` mixes every axis but the first.  Output position i
+        along an independent axis reads position i of every operand,
+        so padding there is carried through (no zero credit: a norm or
+        a softmax makes pad rows nonzero); padding on a mixed axis is
+        cross-position."""
+        kind = h.node.op.row_local
+        out_rank = len(h.out_shapes[0]) if h.out_shapes[0] else 1
+        free = {0} if kind == "axis0" else set(range(max(out_rank - 1, 1)))
+        axes = set()
+        for s in h.ins:
+            axes |= s.axes
+        if axes - free:
+            h.emit("%s mixes positions along axis %s, which carries "
+                   "padding (independent axes: %s)"
+                   % (h.node.op.name, sorted(axes - free), sorted(free)))
+        return [_Pad(axes & free, False)] * self._nout(h.node)
 
     def _op_cache_write_rows(self, h):
         """``_cache_write_rows(cache, rows, pos, count)``: output row i

@@ -23,3 +23,4 @@ from . import contrib_deform  # noqa: F401
 from . import sparse_ops    # noqa: F401
 from . import fused_unit    # noqa: F401
 from . import cache         # noqa: F401
+from . import transformer   # noqa: F401

@@ -42,7 +42,8 @@ class OpDef:
                  mode_dependent=False, mutate_aux=None, fill_shapes=None,
                  num_visible_outputs=None, key_var_num_args=None,
                  aux_inputs=(), sparse_aware=False, sparse_grad=None,
-                 host_sync=False, doc=""):
+                 host_sync=False, row_local=None, flops=None,
+                 temp_bytes=None, doc=""):
         self.name = name
         self.impl = impl
         self.params = params or {}
@@ -80,6 +81,19 @@ class OpDef:
         # host-sync detector (analysis/retrace.py) trusts this flag and
         # only falls back to impl-source scanning when it is unset
         self.host_sync = host_sync
+        # what the analysis passes need of an op, declared where the op
+        # is written instead of once in each pass (ROADMAP D13):
+        #   row_local  "leading": every axis but the last is a batch of
+        #              independent positions; "axis0": only axis 0 is.
+        #              analysis/padding.py then needs no rule of its own
+        #   flops      fn(attrs, in_shapes, out_shape) -> forward FLOPs
+        #              (analysis/flops.py, where _RULES has no entry)
+        #   temp_bytes fn(attrs, in_shapes, in_dtypes) -> bytes of the
+        #              impl's own temporaries, live while the node runs
+        #              (analysis/memory.py prices outputs only)
+        self.row_local = row_local
+        self.flops = flops
+        self.temp_bytes = temp_bytes
         self.doc = doc or (impl.__doc__ or "")
         self._jit_cache = {}
 

@@ -1,0 +1,409 @@
+"""Decoder-block ops: RMSNorm, rotary embedding, a float32-accumulating
+linear map, grouped-query attention against a per-layer cache state
+(one query row a slot) and over a whole prompt (causal, banded), and a
+sparse expert layer that is told which experts it holds.
+
+Every op declares what the analysis passes need of it where it is
+written (``row_local``, ``flops``, ``temp_bytes``: ROADMAP D13); the
+shape rule is the implementation under ``jax.eval_shape`` plus
+``fill_shapes`` for the parameters.
+
+Precision, whatever the storage dtype: a norm's statistics, the rotation,
+attention scores and softmaxes, the router's softmax and every product's
+accumulator are float32; results are rounded once to the dtype of the
+activations they join.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .registry import register, P
+
+_MASKED = -1e30        # finite: a fully masked row softmaxes to uniform
+
+
+def _acc(*arrays):
+    """The dtype statistics and accumulators are kept in: float32, or
+    wider where the operands are (the gradient sweep runs in float64)."""
+    return jnp.result_type(jnp.float32, *[a.dtype for a in arrays])
+
+
+def _prod(shape):
+    out = 1
+    for d in shape:
+        out *= int(d)
+    return out
+
+
+def _itemsize(dt):
+    return jnp.dtype(dt).itemsize
+
+
+# ---------------------------------------------------------------- RMSNorm
+def _gamma_fill(attrs, in_shapes):
+    out = list(in_shapes)
+    if out[0] is not None and len(out) > 1 and out[1] is None:
+        out[1] = (out[0][-1],)
+    return out
+
+
+@register("RMSNorm", nin=2, input_names=["data", "gamma"],
+          params={"eps": P(float, 1e-6)}, fill_shapes=_gamma_fill,
+          row_local="leading",
+          flops=lambda a, i, o: 4.0 * _prod(o))
+def rms_norm(attrs, data, gamma):
+    """``gamma * data / sqrt(mean(data**2, -1) + eps)``; the mean and
+    the division in float32, rounded to ``data``'s dtype before the
+    gain is applied."""
+    x = data.astype(_acc(data))
+    inv = lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + attrs["eps"])
+    return (x * inv).astype(data.dtype) * gamma.astype(data.dtype)
+
+
+# ----------------------------------------------------------------- linear
+def _dense_fill(attrs, in_shapes):
+    out = list(in_shapes)
+    if out[0] is not None and len(out) > 1 and out[1] is None:
+        out[1] = (attrs["num_hidden"], out[0][-1])
+    return out
+
+
+@register("_dense", nin=2, input_names=["data", "weight"],
+          params={"num_hidden": P(int), "out_dtype": P(str, "float32")},
+          fill_shapes=_dense_fill, row_local="leading",
+          flops=lambda a, i, o: 2.0 * _prod(o) * i[0][-1])
+def dense(attrs, data, weight):
+    """``data @ weight.T`` over the last axis, accumulated and returned
+    in ``out_dtype``: ``FullyConnected`` returns its input's dtype, and a
+    router's logits and a head's logits are wanted in float32 from
+    bfloat16 operands without a float32 copy of the weight."""
+    return lax.dot_general(
+        data, weight, (((data.ndim - 1,), (1,)), ((), ())),
+        preferred_element_type=jnp.promote_types(attrs["out_dtype"],
+                                                 _acc(data)))
+
+
+# ----------------------------------------------------------------- rotary
+@register("_rotary", nin=2, input_names=["data", "pos"],
+          params={"head_dim": P(int), "theta": P(float, 10000.0)},
+          row_local="leading",
+          flops=lambda a, i, o: 6.0 * _prod(o))
+def rotary(attrs, data, pos):
+    """Rotary position embedding by position, half-rotation layout:
+    ``data`` is ``(..., heads * head_dim)``, ``pos`` broadcasts against
+    its leading axes.  Within a head, element ``i`` of the first half
+    pairs with element ``i`` of the second and the pair turns by
+    ``pos * theta ** (-2 i / head_dim)``."""
+    d = attrs["head_dim"]
+    half = d // 2
+    acc = _acc(data)
+    inv = jnp.asarray(attrs["theta"], acc) ** (
+        -jnp.arange(half, dtype=acc) * 2.0 / d)
+    ang = pos.astype(acc)[..., None] * inv
+    cos = jnp.cos(ang)[..., None, :]
+    sin = jnp.sin(ang)[..., None, :]
+    x = data.astype(acc).reshape(data.shape[:-1] + (-1, d))
+    x1, x2 = x[..., :half], x[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out.reshape(data.shape).astype(data.dtype)
+
+
+# ---------------------------------------------------- attention, one step
+def _decode_attn_flops(attrs, ins, out):
+    n, rows, c = ins[1]
+    return 4.0 * n * attrs["num_heads"] * rows * c
+
+
+def _decode_attn_temp(attrs, ins, dts):
+    n, rows, _c = ins[1]
+    return 2 * 4 * n * attrs["num_heads"] * rows
+
+
+@register("_gqa_decode", nin=4,
+          input_names=["query", "k_cache", "v_cache", "pos"],
+          params={"num_heads": P(int), "num_kv_heads": P(int),
+                  "window": P(int, 0)},
+          row_local="axis0", flops=_decode_attn_flops,
+          temp_bytes=_decode_attn_temp)
+def gqa_decode(attrs, q, k_cache, v_cache, pos):
+    """One query row a slot against that slot's cache state.
+
+    ``q`` is ``(slots, heads * d)``; the caches are ``(slots, rows,
+    kv_heads * d)`` and already hold the row of position ``pos``.  Row
+    ``j`` holds the latest position ``p <= pos`` with ``p mod rows ==
+    j``: a cache of ``max_len`` rows holds position ``j`` there, a ring
+    of ``window`` rows written at ``pos mod window`` holds the last
+    ``window`` positions.  A row is read when that position exists
+    (``p >= 0``) and, under a window, ``pos - p < window``.
+
+    The cache keeps its ``(rows, kv_heads * d)`` layout: the queries of
+    a group are laid block-diagonally over the ``kv_heads * d`` columns,
+    so scores and output are two plain batched products over the whole
+    row with no relayout of the cache (four times the score FLOPs of a
+    per-group product; the step is bound by the cache read)."""
+    h, kv, w = attrs["num_heads"], attrs["num_kv_heads"], attrs["window"]
+    n, rows, c = k_cache.shape
+    d, g = c // kv, h // kv
+    acc = _acc(q)
+    eye = jnp.eye(kv, dtype=q.dtype)
+    qbd = (q.reshape(n, kv, g, 1, d) * eye[None, :, None, :, None]) \
+        .reshape(n, h, c)
+    s = jnp.einsum("nhc,nrc->nhr", qbd, k_cache,
+                   preferred_element_type=acc) * (d ** -0.5)
+    p = pos.astype(jnp.int32)[:, None]
+    held = p - jnp.mod(p - jnp.arange(rows, dtype=jnp.int32)[None, :], rows)
+    ok = held >= 0
+    if w > 0:
+        ok = jnp.logical_and(ok, p - held < w)
+    a = jax.nn.softmax(jnp.where(ok[:, None, :], s, _MASKED), axis=-1)
+    o = jnp.einsum("nhr,nrc->nhc", a.astype(v_cache.dtype), v_cache,
+                   preferred_element_type=acc)
+    o = (o.reshape(n, kv, g, kv, d)
+         * eye.astype(acc)[None, :, None, :, None]).sum(3)
+    return o.reshape(n, h * d).astype(q.dtype)
+
+
+# ------------------------------------------------ attention, whole prompt
+def _prefill_blocks(t, block, window):
+    """(query start, query end, first key) of each query block: keys
+    run to the block's end (causal) and, under a window, start at the
+    first position its first query still sees."""
+    out = []
+    for qs in range(0, t, block):
+        ks = 0 if window <= 0 else max(0, qs - window + 1)
+        out.append((qs, min(qs + block, t), ks))
+    return out
+
+
+def _prefill_attn_flops(attrs, ins, out):
+    b, t, hd = ins[0]
+    blocks = _prefill_blocks(t, min(attrs["block"], t), attrs["window"])
+    return sum(4.0 * b * hd * (qe - qs) * (qe - ks)
+               for qs, qe, ks in blocks)
+
+
+def _prefill_attn_temp(attrs, ins, dts):
+    b, t, _hd = ins[0]
+    blocks = _prefill_blocks(t, min(attrs["block"], t), attrs["window"])
+    return max(2 * 4 * b * attrs["num_heads"] * (qe - qs) * (qe - ks)
+               for qs, qe, ks in blocks)
+
+
+@register("_gqa_prefill", nin=3, input_names=["query", "key", "value"],
+          params={"num_heads": P(int), "num_kv_heads": P(int),
+                  "window": P(int, 0), "block": P(int, 512)},
+          row_local="axis0", flops=_prefill_attn_flops,
+          temp_bytes=_prefill_attn_temp)
+def gqa_prefill(attrs, q, k, v):
+    """Causal grouped-query attention over ``(batch, T, heads * d)``
+    queries and ``(batch, T, kv_heads * d)`` keys and values, position
+    ``i`` seeing ``j <= i`` and, under a window, ``i - j < window``.
+    Computed a block of queries at a time against the keys that block
+    can see, so the largest score tensor is ``block x T`` a head and no
+    ``T x T`` one exists; a block wholly outside the band is never
+    multiplied."""
+    h, kv, w = attrs["num_heads"], attrs["num_kv_heads"], attrs["window"]
+    b, t, _ = q.shape
+    d, g = k.shape[-1] // kv, h // kv
+    acc = _acc(q)
+    outs = []
+    for qs, qe, ks in _prefill_blocks(t, min(attrs["block"], t), w):
+        qb = q[:, qs:qe].reshape(b, qe - qs, kv, g, d)
+        kb = k[:, ks:qe].reshape(b, qe - ks, kv, d)
+        vb = v[:, ks:qe].reshape(b, qe - ks, kv, d)
+        s = jnp.einsum("bqkgd,blkd->bkgql", qb, kb,
+                       preferred_element_type=acc) * (d ** -0.5)
+        qi = jnp.arange(qs, qe, dtype=jnp.int32)[:, None]
+        kj = jnp.arange(ks, qe, dtype=jnp.int32)[None, :]
+        ok = kj <= qi
+        if w > 0:
+            ok = jnp.logical_and(ok, qi - kj < w)
+        # the weights leave here unnormalised and the row sums divide
+        # the product: one pass over the block's scores fewer than a
+        # softmax that is normalised before it is multiplied
+        s = jnp.where(ok, s, _MASKED)
+        a = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        o = jnp.einsum("bkgql,blkd->bqkgd", a.astype(v.dtype), vb,
+                       preferred_element_type=acc)
+        o = o / jnp.moveaxis(jnp.sum(a, axis=-1), 3, 1)[..., None]
+        outs.append(o.reshape(b, qe - qs, h * d).astype(q.dtype))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
+# ------------------------------------------------------------ expert layer
+def _held(attrs, n_experts):
+    first = attrs["first_expert"]
+    held = attrs["num_held"] if attrs["num_held"] > 0 else n_experts - first
+    return first, held
+
+
+def _moe_rows(ins):
+    return _prod(ins[0][:-1])
+
+
+def _moe_padded_rows(attrs, rows, held):
+    """Rows the sorted path multiplies at most: the pairs in whole
+    blocks, and a block of padding an expert."""
+    blk = attrs["block"]
+    return (-(-rows * attrs["top_k"] // blk) + held) * blk
+
+
+def _moe_dense(attrs, rows, held):
+    """Every held expert over every row, where that is no more rows
+    multiplied than the sorted path may pad to."""
+    return rows * held <= _moe_padded_rows(attrs, rows, held)
+
+
+def _moe_flops(attrs, ins, out):
+    rows, held, f, d = (_moe_rows(ins),) + tuple(ins[2])
+    if _moe_dense(attrs, rows, held):
+        return 6.0 * rows * held * f * d
+    return 6.0 * rows * attrs["top_k"] * f * d
+
+
+def _moe_temp(attrs, ins, dts):
+    rows, held, f, d = (_moe_rows(ins),) + tuple(ins[2])
+    item = _itemsize(dts[0])
+    if _moe_dense(attrs, rows, held):
+        return rows * held * f * (3 * 4 + item)
+    padded = _moe_padded_rows(attrs, rows, held)
+    return padded * d * 2 * item + rows * attrs["top_k"] * d * 4
+
+
+def _moe_fill(attrs, in_shapes):
+    out = list(in_shapes)
+    if out[0] is not None and out[1] is not None:
+        _first, held = _held(attrs, out[1][-1])
+        for i in (2, 3, 4):
+            if len(out) > i and out[i] is None:
+                out[i] = (held, attrs["expert_width"], out[0][-1])
+    return out
+
+
+@register("_moe_experts", nin=5, nout=2,
+          input_names=["data", "router", "gate_weight", "up_weight",
+                       "down_weight"],
+          params={"top_k": P(int), "first_expert": P(int, 0),
+                  "num_held": P(int, 0), "expert_width": P(int, 0),
+                  "block": P(int, 256)},
+          fill_shapes=_moe_fill, row_local="leading", flops=_moe_flops,
+          temp_bytes=_moe_temp)
+def moe_experts(attrs, data, router, wg, wu, wd):
+    """Sparse gated-linear experts, dropless, over the experts held here.
+
+    ``router`` holds a row's logits over *all* the experts (the
+    published router width); each row takes its ``top_k`` largest and
+    weights them by the softmax over those ``top_k`` logits.  The
+    weights ``(num_held, width, hidden)`` are those of experts
+    ``first_expert .. first_expert + num_held - 1`` (``num_held`` 0: all
+    from ``first_expert`` on); expert ``e`` computes ``down_e.T @
+    (relu(gate_e @ x) * (up_e @ x))``.  Returns the held experts' part
+    of the weighted sum (shares over a partition of the experts add up
+    to the whole layer) and the ``(..., experts)`` routing weights, zero
+    where an expert was not chosen.
+
+    Two formulations of the one sum, and in both an expert's gated
+    activation takes its routing weight in float32 before it is rounded
+    for the down projection.  Where every held expert over every row is
+    no more rows multiplied than the sorted path may pad to (up to 284
+    rows for 64 experts, 6 a row, blocks of 256: a decode step), three
+    plain products against the parameters as stored: at a few dozen rows
+    nearly every expert's weights are read anyway.  Past that (a
+    prefill), the (row, expert) pairs are sorted by expert, each
+    expert's run padded to a multiple of ``block``, and a loop
+    multiplies one block by one expert's weights: no pair is dropped,
+    and the padding is at most ``block`` rows an expert.  On a v5e, a
+    layer at the published widths: 32 rows 1.16 ms plain against 3.14
+    sorted (sorting, gathering and 64 short loop turns); 512 rows 2.48
+    against 3.44, so the rule leaves the plain products early; 8,192
+    rows 16 ms sorted, where the plain products would be 64/6 of the
+    work (40 ms at the peak) and 4.8 GB of activations."""
+    k, blk = attrs["top_k"], attrs["block"]
+    lead, d = data.shape[:-1], data.shape[-1]
+    n_exp = router.shape[-1]
+    first, held = _held(attrs, n_exp)
+    f = wg.shape[1]
+    x = data.reshape(-1, d)
+    acc = _acc(data)
+    r = router.reshape(-1, n_exp).astype(acc)
+    m = x.shape[0]
+    top_v, top_i = lax.top_k(r, k)
+    w = jax.nn.softmax(top_v, axis=-1)
+    route = jnp.sum(jax.nn.one_hot(top_i, n_exp, dtype=acc)
+                    * w[..., None], axis=1)
+    nt = (((1,), (1,)), ((), ()))
+    if _moe_dense(attrs, m, held):
+        local = route[:, first:first + held]
+        gate = lax.dot_general(x, wg.reshape(held * f, d), nt,
+                               preferred_element_type=acc)
+        up = lax.dot_general(x, wu.reshape(held * f, d), nt,
+                             preferred_element_type=acc)
+        act = (jax.nn.relu(gate) * up).reshape(m, held, f) \
+            * local[:, :, None]
+        y = jnp.dot(act.reshape(m, held * f).astype(x.dtype),
+                    wd.reshape(held * f, d), preferred_element_type=acc)
+    else:
+        y = _moe_sorted(x, top_i, w, wg, wu, wd, first, held, blk)
+    return (y.reshape(lead + (d,)).astype(data.dtype),
+            route.reshape(lead + (n_exp,)))
+
+
+def _moe_sorted(x, top_i, w, wg, wu, wd, first, held, blk):
+    """The grouped product: pairs sorted by expert into runs padded to
+    whole blocks, one block against one expert's weights a loop turn
+    (the pair's routing weight on the activation), then each row's
+    ``top_k`` results summed in float32.
+    Pairs are numbered choice-major (all rows' first choice, then their
+    second, ...), so that the results come back as ``(top_k, rows,
+    hidden)`` and the sum runs over the leading axis."""
+    m, d = x.shape
+    k = top_i.shape[1]
+    pairs = m * k
+    acc = _acc(x)
+    nt = (((1,), (1,)), ((), ()))
+    e = top_i.T.reshape(-1) - first
+    mine = jnp.logical_and(e >= 0, e < held)
+    e = jnp.where(mine, e, held)                 # the others sort last
+    order = jnp.argsort(e, stable=True)
+    rank = jnp.argsort(order)                    # pair -> place when sorted
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[e].add(1)[:held]
+    start = jnp.cumsum(sizes) - sizes
+    padded = -(-sizes // blk) * blk
+    pend = jnp.cumsum(padded)
+    pstart = pend - padded
+    n_rows = (-(-pairs // blk) + held) * blk
+    p = jnp.arange(n_rows, dtype=jnp.int32)
+    grp = jnp.minimum(jnp.searchsorted(pend, p, side="right"), held - 1)
+    off = p - pstart[grp]
+    real = jnp.logical_and(off < sizes[grp], p < pend[-1])
+    # a padding row reads the zero row appended to ``x``
+    src = jnp.where(real,
+                    order[jnp.clip(start[grp] + off, 0, pairs - 1)] % m, m)
+    xs = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[src]
+    # a pair's weight, in sorted order; padding and the others' pairs 0
+    wk = w.T.reshape(-1) * mine.astype(acc)
+    ws = jnp.where(real, wk[order[jnp.clip(start[grp] + off, 0, pairs - 1)]],
+                   0.0)
+    block_e = grp[::blk]
+
+    def one_block(b, ys):
+        at = b * blk
+        xb = lax.dynamic_slice_in_dim(xs, at, blk, axis=0)
+        ex = block_e[b]
+        gate = lax.dot_general(xb, wg[ex], nt, preferred_element_type=acc)
+        up = lax.dot_general(xb, wu[ex], nt, preferred_element_type=acc)
+        wb = lax.dynamic_slice_in_dim(ws, at, blk, axis=0)
+        act = (jax.nn.relu(gate) * up * wb[:, None]).astype(x.dtype)
+        yb = jnp.dot(act, wd[ex], preferred_element_type=acc)
+        return lax.dynamic_update_slice_in_dim(ys, yb.astype(x.dtype), at,
+                                               axis=0)
+
+    ys = lax.fori_loop(0, pend[-1] // blk, one_block,
+                       jnp.zeros((n_rows, d), x.dtype))
+    ec = jnp.minimum(e, held - 1)
+    dest = jnp.clip(pstart[ec] + rank - start[ec], 0, n_rows - 1)
+    return jnp.sum(jnp.where(mine.reshape(k, m, 1),
+                             ys[dest].reshape(k, m, d).astype(acc), 0.0),
+                   axis=0)

@@ -345,6 +345,13 @@ class ProgramCache(object):
         rng key (replica probation: two caches' probe dispatches must
         draw identically even for stochastic graphs, whose per-cache
         key streams would otherwise never agree bitwise)."""
+        return [np.asarray(o)
+                for o in self.dispatch(feeds, _record, _fixed_key)]
+
+    def dispatch(self, feeds, _record=True, _fixed_key=None):
+        """:meth:`run` without the read: the outputs stay device
+        arrays (a decode prefill's state rows go from here into the
+        slot pool and never visit the host)."""
         shape_key = tuple(sorted((k, v.shape) for k, v in feeds.items()))
         plan = self._plans.get(shape_key)
         if plan is None:
@@ -370,8 +377,7 @@ class ProgramCache(object):
         else:
             for n, pos in data_pos:
                 flat[pos] = feeds[n]    # jit commits host arrays itself
-        outs = kernel(key, *flat)
-        return [np.asarray(o) for o in outs[:self._n_out]]
+        return list(kernel(key, *flat)[:self._n_out])
 
     def run_pad_probe(self, feeds, live_masks, sentinel=7.5):
         """Runtime padding-soundness assert (MXNET_SERVE_PAD_CHECK) —

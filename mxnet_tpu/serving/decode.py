@@ -280,6 +280,57 @@ class DecodeRequest(Request):
         self.uflops = 0
 
 
+def _pin_state_dtypes(step_sym, state_info, dtype):
+    """The step graph with every next-state output cast to the dtype
+    its pool buffer has.  A graph that mixes a low-precision state with
+    float32 host vectors (a one-hot blend of ``pos`` into a cache)
+    promotes the state, and the pool would come back float32 from the
+    first step: twice the bytes, no donation, one more compile.  A
+    float32 pool over a float32 graph is left as it is, and so is an
+    output that already ends in the cast (``StepProgram`` pins whatever
+    graph it is given; the engine pins first, for its analyses)."""
+    from .. import symbol as sym
+    want = [np.dtype(info.get("dtype") or dtype) for info in state_info]
+    if all(dt == np.dtype(np.float32) for dt in want):
+        return step_sym
+    outs = [step_sym[i] for i in range(len(step_sym))]
+    for i, dt in enumerate(want):
+        node = outs[1 + i]._outputs[0][0]
+        if node.op is not None and node.op.name == "Cast" \
+                and np.dtype(node.attrs.get("dtype")) == dt:
+            continue
+        outs[1 + i] = sym.Cast(outs[1 + i], dtype=dt.name)
+    return sym.Group(outs)
+
+
+def _lay_rows(buf, rows, info, slots, lens):
+    """``StepProgram.commit_prefill`` for one state, traced."""
+    import jax.numpy as jnp
+    from jax import lax
+    held = buf.shape[1:]
+    seq = rows.shape[1:] != held
+    if seq and not (info.get("cache") and rows.ndim == buf.ndim
+                    and rows.shape[2:] == held[1:]):
+        raise MXNetError(
+            "prefill rows %s fit neither state %r's row %s nor, as keys "
+            "or values a position, a cache state's"
+            % (rows.shape[1:], info["name"], held))
+    for i in reversed(range(rows.shape[0])):
+        one = rows[i]
+        if seq and one.shape[0] > held[0]:
+            if info.get("window"):
+                last = lens[i] - 1
+                at = last - jnp.mod(
+                    last - jnp.arange(held[0], dtype=jnp.int32), held[0])
+                one = one[jnp.clip(at, 0, one.shape[0] - 1)]
+            else:
+                one = one[:held[0]]
+        buf = lax.dynamic_update_slice(
+            buf, one[None].astype(buf.dtype),
+            (slots[i],) + (0,) * (buf.ndim - 1))
+    return buf
+
+
 class StepProgram(object):
     """The persistent compiled decode step over a fixed slot pool.
 
@@ -330,11 +381,25 @@ class StepProgram(object):
         self.state_info = [dict(s) for s in state_info]
         self.state_names = [s["name"] for s in self.state_info]
         self.token_name = token_name
-        if len(step_sym) != 1 + len(self.state_names):
+        n_states = len(self.state_names)
+        if len(step_sym) < 1 + n_states \
+                or (self._spec is not None
+                    and len(step_sym) != 1 + n_states):
             raise MXNetError(
                 "decode step graph has %d outputs; expected 1 (logits) "
-                "+ %d next-state outputs (state_info order)"
-                % (len(step_sym), len(self.state_names)))
+                "+ %d next-state outputs (state_info order)%s"
+                % (len(step_sym), n_states,
+                   "" if self._spec is None else
+                   "; a speculative step takes no further outputs"))
+        # outputs past the states ride along as counters: small arrays
+        # the host reads with the sampled ids each step (an expert
+        # layer's load) and the scheduler hangs on its ``decode.step``
+        # event as ``<name>_max`` / ``<name>_mean``
+        self.extra_names = [
+            n[:-len("_output")] if n.endswith("_output") else n
+            for n in step_sym.list_outputs()[1 + n_states:]]
+        self.last_extras = {}
+        step_sym = _pin_state_dtypes(step_sym, self.state_info, self._dtype)
         if self._spec is not None:
             # the spec program needs per-position RAW logits (the
             # greedy head becomes a jnp.argmax with identical
@@ -394,6 +459,14 @@ class StepProgram(object):
         na = len(arg_names)
         n_t = len(order)
         state_pos = tuple(order.index(n) for n in self.state_names)
+        # a ``cache`` state is not zeroed at a join: it is read under a
+        # mask by position, so every row a request reads is one that
+        # request wrote.  Zeroing is a select over the whole buffer in
+        # front of the step, which for the recurrent rows it was written
+        # for is nothing and for a cache of gigabytes is a copy of the
+        # pool every step
+        reset_pos = tuple(order.index(i["name"]) for i in self.state_info
+                          if not i.get("cache"))
         _sampler = self.sampler
         # -------------------------------------------------- draft half
         # the draft model is a full second graph riding the same flat
@@ -639,7 +712,7 @@ class StepProgram(object):
             # jnp.where, not multiply: stale rows may hold non-finite
             # values and 0*inf would leak NaN into the fresh state.
             flat = list(flat)
-            for i in state_pos:
+            for i in reset_pos:
                 s = flat[i]
                 r = reset.reshape((-1,) + (1,) * (s.ndim - 1))
                 flat[i] = jnp.where(r > 0, jnp.zeros((), s.dtype), s)
@@ -729,9 +802,30 @@ class StepProgram(object):
         # warmup()'s row-write traces must also pin to zero on a warm
         # restart, or the "0 compiles for previously-served buckets"
         # contract would leak through the scatter path.
-        self._set_row_jit = jax.jit(set_row)
+        # off-CPU the buffer is donated, like the pool to the step: a
+        # row write patches the pool in place and never holds a second
+        # copy of a state (a caller rebinds the dict ``write_row``
+        # returns; the one it passed holds consumed buffers)
+        self._set_row_jit = jax.jit(
+            set_row, donate_argnums=(0,) if donate else ())
         self._row_kernels = {}
         self._jnp = jnp
+
+        n_s = len(self.state_info)
+
+        def commit(slots, lens, *flat):
+            self._trace_count += 1
+            _count_xla_trace()
+            return [_lay_rows(b, r, info, slots, lens) for b, r, info
+                    in zip(flat[:n_s], flat[n_s:], self.state_info)]
+
+        # a prefill's state rows laid into the pool on the device, one
+        # program a (batch, prompt bucket) shape (``commit_prefill``),
+        # resolved through the AOT cache like the row kernels
+        self._commit_donate = tuple(range(2, 2 + n_s)) if donate else ()
+        self._commit_jit = jax.jit(commit,
+                                   donate_argnums=self._commit_donate)
+        self._commit_kernels = {}
 
     @property
     def trace_count(self):
@@ -840,6 +934,41 @@ class StepProgram(object):
                 out[name], idx, row)
         return out
 
+    def commit_prefill(self, states, rows, slots, lens):
+        """Lay one prefill dispatch's state rows (device arrays,
+        ``(batch,) + row shape`` in ``state_info`` order) into slots
+        ``slots`` of the pool, in one dispatch, and return the state
+        dict.  A row of a state's own shape replaces the slot's row.  A
+        ``cache`` state also takes the keys or values of every prompt
+        position, ``(batch, T) + tail``: position ``p`` goes to row
+        ``p``, and where the state is a ring of ``window`` rows shorter
+        than ``T``, row ``j`` takes the last position ``p < lens[i]``
+        with ``p mod window == j``, which is where the step, writing at
+        ``pos mod window``, will look for it.  Batch rows are written
+        last to first, so a dead row of a padded batch is given the
+        slot and length of row 0 and is overwritten by it."""
+        jnp = self._jnp
+        args = [jnp.asarray(slots, jnp.int32), jnp.asarray(lens, jnp.int32)] \
+            + [states[n] for n in self.state_names] + list(rows)
+        kernel = self._commit_jit
+        if self._aot is not None:
+            sig = tuple((tuple(a.shape), str(a.dtype)) for a in args)
+            kernel = self._commit_kernels.get(sig)
+            if kernel is None:
+                from .aot_cache import resolve_kernel
+                # what ``_lay_rows`` reads of a state beside its shape
+                tag = "lay_rows_v1|" + ",".join(
+                    "%d:%d" % (bool(i.get("cache")), i.get("window") or 0)
+                    for i in self.state_info)
+                kernel, _src = resolve_kernel(
+                    self._aot, self._commit_jit, "decode_commit_prefill",
+                    tag, args, donate_argnums=self._commit_donate,
+                    universal=True)
+                self._commit_kernels[sig] = kernel
+        out = dict(states)
+        out.update(zip(self.state_names, kernel(*args)))
+        return out
+
     def zero_row(self, states, slot, which="all"):
         """Zero one slot's rows in every state buffer (a joining
         request must never inherit the previous occupant's state).
@@ -867,11 +996,12 @@ class StepProgram(object):
             raise MXNetError("this StepProgram compiled a speculative "
                              "draft-k-verify step: dispatch through "
                              "step_spec()")
-        (sampled,), outs = self._run(tokens, pos, valid, states, reset,
-                                     None, 1)
+        host, outs = self._run(tokens, pos, valid, states, reset, None, 1)
         new_states = {name: outs[1 + i]
                       for i, name in enumerate(self.state_names)}
-        return sampled, new_states
+        if self.extra_names:
+            self.last_extras = dict(zip(self.extra_names, host[1:]))
+        return host[0], new_states
 
     def _dispatch(self, tokens, pos, valid, states, reset, spec):
         """Build the flat argument vector and enqueue the step kernel;
@@ -886,19 +1016,25 @@ class StepProgram(object):
 
     def _run(self, tokens, pos, valid, states, reset, spec, n_read):
         """One dispatch and the blocking read of its first ``n_read``
-        outputs (the only device->host traffic of a step); returns
+        outputs and of the counters past the states (the only
+        device->host traffic of a step); returns
         ``(host arrays, device outputs)``.  ``decode.step.read`` holds
         the device's own step time while the host waits for it."""
         tl = self._tl
+        n_extra = len(self.extra_names)
+
+        def read(outs):
+            return [np.asarray(o) for o in list(outs[:n_read])
+                    + list(outs[len(outs) - n_extra:])]
         if tl is None:
             outs = self._dispatch(tokens, pos, valid, states, reset, spec)
-            return [np.asarray(o) for o in outs[:n_read]], outs
+            return read(outs), outs
         t0 = time.perf_counter()
         with tl.annotate("decode.step.dispatch"):
             outs = self._dispatch(tokens, pos, valid, states, reset, spec)
         t1 = time.perf_counter()
         with tl.annotate("decode.step.read"):
-            host = [np.asarray(o) for o in outs[:n_read]]
+            host = read(outs)
         self.last_split = (t1 - t0, time.perf_counter() - t1)
         return host, outs
 
@@ -1323,6 +1459,14 @@ class DecodeEngine(object):
         state buffers, in the order the step graph returns their next
         values (``BaseRNNCell.state_info`` shapes with the batch dim
         dropped; see ``begin_state_arrays`` for the cell-side analog).
+        Each state has its own shape: ``"cache": True`` marks a
+        positional cache whose leading axis is rows, written at ``pos``
+        and read under a mask by position (so a join does not zero it,
+        and a prefill may hand it the keys or values of every prompt
+        position), ``"window": n`` a ring of ``n`` rows written at
+        ``pos mod n``.
+        Outputs of the step graph past the states are counters read
+        with the sampled ids (``StepProgram.extra_names``).
     num_slots, max_len : slot-pool geometry (defaults from
         ``MXNET_DECODE_SLOTS`` / ``MXNET_DECODE_MAX_LEN``).
     eos_id : sampling this id ends a request with reason "eos".
@@ -1337,6 +1481,10 @@ class DecodeEngine(object):
         bucket); its state rows are scattered into the free slot.
         Without it, prompts are teacher-forced token-by-token through
         the running step batch (no extra programs).
+    prefill_buckets : the padded prompt lengths to compile (default:
+        every power of two up to ``max_len``'s).  A deployment that
+        knows its prompts names the few it needs; a prompt longer than
+        the largest is fed through the step.
     sampler : :class:`Sampler` hook for the token-selection head
         (default :class:`GreedySampler` — bitwise-pinned argmax).
         :class:`TemperatureSampler` runs temperature/top-k categorical
@@ -1360,7 +1508,7 @@ class DecodeEngine(object):
                  token_name="token", pos_name="pos", valid_name="valid",
                  num_slots=None, max_len=None, eos_id=None,
                  prefill_sym=None, prefill_data_name="prompt",
-                 prefill_len_name="plen",
+                 prefill_len_name="plen", prefill_buckets=None,
                  max_queue=None, default_deadline_ms=None,
                  overload_policy=None, ctx=None, dtype=np.float32,
                  start=True, sampler=None, replicas=None, sharding=None,
@@ -1469,6 +1617,13 @@ class DecodeEngine(object):
                                         draft_state_info or [],
                                         token_name, pos_name,
                                         valid_name, what="draft")
+        # what every replica's StepProgram will run (it pins what it is
+        # given; a graph already pinned comes back as it is), so that
+        # the analyses below price the pool in the dtype it has
+        step_sym = _pin_state_dtypes(step_sym, state_info, dtype)
+        if self._spec_k:
+            draft_sym = _pin_state_dtypes(draft_sym, draft_state_info or [],
+                                          dtype)
         # the spec bundle every replica's StepProgram shares: draft
         # graph/params plus the ONE verdict-gated commit graph (built
         # here, not per replica — the selection decision is engine
@@ -1525,14 +1680,17 @@ class DecodeEngine(object):
         # shape keys are the buckets) or — the BucketingModule idiom,
         # since an unrolled graph bakes its length in — a callable
         # ``T -> Symbol`` invoked once per bucket.
-        prefill_buckets = ()
-        if prefill_sym is not None:
+        if prefill_sym is None:
+            prefill_buckets = ()
+        elif prefill_buckets is None:
             buckets, b = [], 1
             top = _next_pow2(self.max_len)
             while b <= top:
                 buckets.append(b)
                 b <<= 1
             prefill_buckets = tuple(buckets)
+        else:
+            prefill_buckets = tuple(sorted(int(b) for b in prefill_buckets))
         # coalesced prefill dispatches at pow2 BATCH buckets too (a
         # group of joiners pads up to the next one); serial mode only
         # ever dispatches batch 1 — warmup warms exactly this grid, so
@@ -1551,6 +1709,12 @@ class DecodeEngine(object):
         # budget BEFORE any compile.  Purely diagnostic: the engine
         # serves bitwise-identically with the planner off.
         self.memory_plan = None
+        # the positions one prefill dispatch may hold, which the memory
+        # preflight derives from what the device has free beside the
+        # weights and the pool (None: no budget known, every batch of
+        # every bucket).  The warm set is the (batch, bucket) shapes
+        # within it, and coalescing forms no group past it
+        self._prefill_token_budget = None
         if config.get("MXNET_MEMORY_PLAN") \
                 and config.get("MXNET_ANALYSIS_ON"):
             self._memory_preflight(
@@ -1559,6 +1723,13 @@ class DecodeEngine(object):
                 prefill_buckets, draft_sym, draft_state_info,
                 draft_arg_params, draft_aux_params,
                 config.get("MXNET_ANALYSIS_STRICT"))
+        budget = self._prefill_token_budget
+        self._prefill_grid = {
+            b: tuple(bb for bb in self._prefill_batches
+                     if budget is None or bb * b <= budget)
+            for b in prefill_buckets}
+        prefill_buckets = tuple(b for b in prefill_buckets
+                                if self._prefill_grid[b])
         # persistent AOT program cache (serving/aot_cache.py,
         # MXNET_AOT_CACHE_DIR): one per engine, shared by every
         # replica's step program, prefill buckets, and row-scatter
@@ -1907,7 +2078,12 @@ class DecodeEngine(object):
                 for extra in (pos_name, valid_name):
                     if extra in arg_names:
                         shapes[extra] = (n,)
-                dtypes = {k: self._dtype for k in shapes}
+                # the host vectors are float32 whatever the pool is;
+                # a state has the dtype its buffer has
+                dtypes = {k: np.dtype(np.float32) for k in shapes}
+                for info in infos:
+                    dtypes[info["name"]] = np.dtype(info.get("dtype")
+                                                    or self._dtype)
                 for src in (a_params or {}), (x_params or {}):
                     for k, v in src.items():
                         dt = getattr(v, "dtype", None)
@@ -1932,7 +2108,8 @@ class DecodeEngine(object):
             pool = 0
             for info in state_info:
                 shp = (n,) + tuple(info["shape"])
-                nbytes = int(np.prod(shp)) * self._dtype.itemsize
+                nbytes = int(np.prod(shp)) * np.dtype(
+                    info.get("dtype") or self._dtype).itemsize
                 pool += nbytes // shard_divisor(spec, info["name"],
                                                 shp, kind="state")
             per_slot = pool // n
@@ -1955,9 +2132,15 @@ class DecodeEngine(object):
                 need += dplan["peak_bytes"]
                 offender = "step+draft"
                 donation["draft"] = dplan["donation"]
+            budget = device_memory_budget()
             if prefill_sym is not None and prefill_buckets:
+                # one row of the largest bucket prices a position; what
+                # the device has free beside the step's peak (weights,
+                # pool, the step's own temporaries) then says how many
+                # positions a dispatch may hold, and the largest warm
+                # shape within that is the row that is reported
                 b_top = max(prefill_buckets)
-                bb = max(self._prefill_batches)
+                bb = 1
                 psym = prefill_sym
                 if not isinstance(psym, _Symbol) and callable(psym):
                     psym = psym(b_top)
@@ -1977,9 +2160,21 @@ class DecodeEngine(object):
                                           dtypes=pdtypes,
                                           sharding=spec)
                 if pplan:
-                    label = "prefill[b%dxT%d]" % (bb, b_top)
+                    per_token = max(
+                        1, pplan["transient_peak_bytes"] // b_top)
+                    if budget is not None:
+                        self._prefill_token_budget = max(
+                            0, int((budget - need) // per_token))
+                    cap = self._prefill_token_budget
+                    warm = [bt * b for b in prefill_buckets
+                            for bt in self._prefill_batches
+                            if cap is None or bt * b <= cap]
+                    tokens = max(warm) if warm else 0
+                    label = "prefill[%d positions]" % tokens
                     r = row(label, pplan)
-                    r["peak_bytes"] = pplan["peak_bytes"] + pool
+                    r["transient_peak_bytes"] = per_token * tokens
+                    r["peak_bytes"] = (pplan["param_bytes"] + pool
+                                       + per_token * tokens)
                     programs.append(r)
                     if r["peak_bytes"] > need:
                         need = r["peak_bytes"]
@@ -2001,7 +2196,7 @@ class DecodeEngine(object):
             mem["digest"] = plan_digest(
                 {k: mem[k] for k in ("programs", "predicted_peak_bytes",
                                      "sharded", "donation")})
-            budget = device_memory_budget()
+            mem["prefill_token_budget"] = self._prefill_token_budget
             mem["budget_bytes"] = budget
             mem["budget_ok"] = (None if budget is None
                                 else need <= budget)
@@ -2786,16 +2981,24 @@ class DecodeEngine(object):
         seated = [req for req in reqs if self._seat_slot(rep, req)]
         if not seated:
             return
+        stepped = seated
         if rep.prefill_caches:
             # serial mode is the degenerate grouping — one singleton
             # group per joiner dispatches the identical (1, bucket)
             # program the pre-coalescing engine did, through the SAME
-            # code path (no serial/coalesced divergence to maintain)
-            groups = []                 # [(bucket, [reqs])], seat order
+            # code path (no serial/coalesced divergence to maintain).
+            # A group never outgrows its bucket's largest warm batch
+            # (the token budget a dispatch); a prompt past the largest
+            # bucket is fed through the step like any token
+            groups, stepped = [], []    # [(bucket, [reqs])], seat order
             for req in seated:
-                b = next(bk for bk in rep.prefill_buckets
-                         if bk >= len(req.prompt))
-                g = next((g for g in groups if g[0] == b),
+                b = next((bk for bk in rep.prefill_buckets
+                          if bk >= len(req.prompt)), None)
+                if b is None:
+                    stepped.append(req)
+                    continue
+                g = next((g for g in groups if g[0] == b
+                          and len(g[1]) < self._prefill_grid[b][-1]),
                          None) if self._coalesce else None
                 if g is None:
                     groups.append((b, [req]))
@@ -2803,20 +3006,19 @@ class DecodeEngine(object):
                     g[1].append(req)
             for b, grp in groups:
                 self._prefill_group(rep, b, grp)
-        else:
-            for req in seated:
-                # the previous occupant's state rows are cleared IN
-                # the next step dispatch (StepProgram reset mask) — a
-                # join costs zero device traffic of its own
-                slot = req.slot
-                rep.reset_np[slot] = 1.0
-                rep.tokens_np[slot] = req.prompt[0]
-                rep.pos_np[slot] = 0.0
-                req.prompt_i = 1
-                # spec eligibility starts with the FIRST sampling step
-                # — the one that consumes the last prompt token
-                rep.spec_np[slot] = (1.0 if req.prompt_i
-                                     >= len(req.prompt) else 0.0)
+        for req in stepped:
+            # the previous occupant's state rows are cleared IN
+            # the next step dispatch (StepProgram reset mask) — a
+            # join costs zero device traffic of its own
+            slot = req.slot
+            rep.reset_np[slot] = 1.0
+            rep.tokens_np[slot] = req.prompt[0]
+            rep.pos_np[slot] = 0.0
+            req.prompt_i = 1
+            # spec eligibility starts with the FIRST sampling step
+            # — the one that consumes the last prompt token
+            rep.spec_np[slot] = (1.0 if req.prompt_i
+                                 >= len(req.prompt) else 0.0)
         for req in seated:
             if req.slot is not None and rep.slots[req.slot] is req:
                 self._check_finish(rep, req.slot)
@@ -2893,7 +3095,8 @@ class DecodeEngine(object):
             live.append(req)
         if not live:
             return
-        bb = next(b for b in self._prefill_batches if b >= len(live))
+        bb = next(b for b in self._prefill_grid.get(
+            bucket, self._prefill_batches) if b >= len(live))
         arr = np.zeros((bb, bucket), np.float32)
         lens = np.zeros((bb,), np.float32)
         for r_i, req in enumerate(live):
@@ -2901,17 +3104,31 @@ class DecodeEngine(object):
             arr[r_i, :plen] = req.prompt
             lens[r_i] = plen
         t_pf0 = time.perf_counter()
+        ann = (self._tl.annotate("decode.prefill") if self._tl is not None
+               else _telemetry.timeline.NO_SPAN)
         try:
-            outs = rep.prefill_caches[bucket].run({
-                self._prefill_data_name: arr,
-                self._prefill_len_name: lens})
-            with self._lock:
-                self._prefill_dispatches += 1
-            if self._sampler.greedy:
-                first = np.asarray(outs[0])
-            else:
-                first = rep.program.sample_tokens(outs[0])
-            rows_all = [np.asarray(o) for o in outs[1:]]
+            with ann:
+                outs = rep.prefill_caches[bucket].dispatch({
+                    self._prefill_data_name: arr,
+                    self._prefill_len_name: lens})
+                with self._lock:
+                    self._prefill_dispatches += 1
+                on_device = self._rows_stay_on_device(rep)
+                if on_device:
+                    # dead rows of the padded batch take row 0's slot
+                    # and length, and are overwritten by it
+                    slots = [live[0].slot] * bb
+                    plens = [len(live[0].prompt)] * bb
+                    for r_i, req in enumerate(live):
+                        slots[r_i], plens[r_i] = req.slot, len(req.prompt)
+                    rep.states = rep.program.commit_prefill(
+                        rep.states, outs[1:], slots, plens)
+                if self._sampler.greedy:
+                    first = np.asarray(outs[0])
+                else:
+                    first = rep.program.sample_tokens(outs[0])
+                rows_all = None if on_device \
+                    else [np.asarray(o) for o in outs[1:]]
         except Exception as e:
             for req in live:
                 self._fail_seated(rep, req, e)
@@ -2929,7 +3146,9 @@ class DecodeEngine(object):
             self._tl.complete("decode.prefill", "decode",
                               "decode:%s" % rep.label, t_pf0,
                               time.perf_counter(),
-                              args={"bucket": bucket, "group": len(live)})
+                              args={"bucket": bucket, "group": len(live),
+                                    "tokens": live_elems,
+                                    "padded": padded_elems})
         if self._eff is not None:
             shape_key = tuple(sorted(
                 (k, v.shape)
@@ -2944,16 +3163,32 @@ class DecodeEngine(object):
                         req.uflops += (useful * len(req.prompt)
                                        // live_elems)
         for r_i, req in enumerate(live):
-            rows = {name: rows_all[i][r_i]
-                    for i, name in enumerate(rep.program.state_names)}
+            rows = None if rows_all is None else {
+                name: rows_all[i][r_i]
+                for i, name in enumerate(rep.program.state_names)}
             self._commit_prefill(rep, req, rows, first[r_i])
 
+    def _rows_stay_on_device(self, rep):
+        """Whether a prefill's state rows are laid into the pool by
+        ``StepProgram.commit_prefill``: on the device, one dispatch a
+        group, nothing read back.  Two pools keep the row write a
+        request and a state, each for a reason: a sharded one, because
+        the commit program's outputs would take the shardings the
+        compiler infers and the next step would meet a pool laid out
+        otherwise than it was warmed on (the row kernels are resolved a
+        sharding); a speculative one, because its join also writes the
+        draft's rows, which the prefill graph does not produce."""
+        return rep.plan is None and not self._spec_k
+
     def _commit_prefill(self, rep, req, rows, first):
-        """Scatter one request's prefill output rows into its slot and
-        deliver the first generated token (row scatter stays one
-        traced-index kernel per state shape — never a new compile)."""
+        """Deliver one request's first generated token, after its
+        prefill rows went into its slot: here, by a row write a state
+        (one traced-index kernel per state shape — never a new
+        compile), unless the group's rows were laid in on the device
+        already (``rows`` None)."""
         slot = req.slot
-        rep.states = rep.program.write_row(rep.states, slot, rows)
+        if rows is not None:
+            rep.states = rep.program.write_row(rep.states, slot, rows)
         if self._spec_k:
             # the prefill graph produced TARGET rows only; the draft
             # never saw this prompt, and the previous occupant's draft
@@ -3077,6 +3312,9 @@ class DecodeEngine(object):
             sp.args = {"live": done[0], "tokens": done[1],
                        "dispatch_ms": disp_s * 1e3,
                        "read_ms": read_s * 1e3}
+            for name, arr in rep.program.last_extras.items():
+                sp.args[name + "_max"] = float(arr.max())
+                sp.args[name + "_mean"] = float(arr.mean())
 
     def _booked(self, rep, live, new_tokens, t0):
         """Book one scheduler iteration begun at ``t0`` (``stats()``
@@ -3382,6 +3620,8 @@ class DecodeEngine(object):
         (with a prefill graph) one program per pow2 prompt bucket.
         After this, joins/leaves/steps never trace — tests pin
         ``compile_count`` across churn.  Returns the compile count.
+        Call it before traffic: an empty pool is warmed in place (a
+        second pool beside it need not fit the device).
 
         The step runs TWICE on purpose: jax's executable cache keys on
         argument sharding, and the kernel's own state outputs (every
@@ -3402,14 +3642,29 @@ class DecodeEngine(object):
         fresh one."""
         z = np.zeros((self.num_slots,), np.float32)
         prog = rep.program
-        states = prog.init_states()
-        states = prog.zero_row(states, 0)
-        if self._spec_k:
-            _t, _c, states = prog.step_spec(z, z, z, z, states)
-            _t, _c, states = prog.step_spec(z, z, z, z, states)
-        else:
-            _, states = prog.step(z, z, z, states)
-            _, states = prog.step(z, z, z, states)
+        # an empty pool is warmed in place and keeps the stepped,
+        # committed buffers (dead slots hold whatever a warm step left,
+        # as they hold a finished request's rows: a join resets or
+        # overwrites them); a second pool beside the first need not fit.
+        # With a request seated, a scratch pool takes the warm steps
+        idle = rep.occupied_count() == 0
+
+        def keep(states):
+            # step by step: the pool's buffers are donated to each warm
+            # program, and a warm-up that raises midway must leave the
+            # replica holding live ones
+            if idle:
+                rep.states = states
+            return states
+
+        states = rep.states if idle else prog.init_states()
+        states = keep(prog.zero_row(states, 0))
+        for _ in range(2):
+            if self._spec_k:
+                _t, _c, states = prog.step_spec(z, z, z, z, states)
+            else:
+                _, states = prog.step(z, z, z, states)
+            keep(states)
         rows = {}
         # ALL states — the prefill path also scatters draft rows
         # (zero_row which="draft") into STEPPED buffers, and their
@@ -3417,17 +3672,23 @@ class DecodeEngine(object):
         for key, info in prog._state_infos():
             dt = np.dtype(info.get("dtype") or prog._dtype)
             rows[key] = np.zeros(tuple(info["shape"]), dt)
-        prog.write_row(states, 0, rows)
+        states = keep(prog.write_row(states, 0, rows))
         for b in rep.prefill_buckets:
             # the full (batch, prompt) bucket grid: coalesced prefill
             # dispatches at pow2 BATCH extents too, and every shape
             # live traffic can meet must be warm or the zero-warm-
             # retrace contract would leak through the coalesced path
-            for bb in self._prefill_batches:
-                rep.prefill_caches[b].run({
+            # within the token budget a dispatch (``_prefill_grid``)
+            for bb in self._prefill_grid.get(b, self._prefill_batches):
+                outs = rep.prefill_caches[b].dispatch({
                     self._prefill_data_name: np.zeros((bb, b),
                                                       np.float32),
                     self._prefill_len_name: np.zeros((bb,), np.float32)})
+                if self._rows_stay_on_device(rep):
+                    # against stepped buffers, as live traffic meets it
+                    states = keep(prog.commit_prefill(
+                        states, outs[1:], [0] * bb, [0] * bb))
+                np.asarray(outs[0])
 
     @property
     def compile_count(self):
@@ -3513,6 +3774,13 @@ class DecodeEngine(object):
                 "prefill_buckets": list(self._prefill_buckets),
                 "prefill_coalesced": bool(self._coalesce),
                 "prefill_batch_buckets": list(self._prefill_batches),
+                "prefill_token_budget": self._prefill_token_budget,
+                "prefill_programs": sum(
+                    len(self._prefill_grid[b])
+                    for b in self._prefill_buckets),
+                "state_rows": {info["name"]: int(info["shape"][0])
+                               for info in self._program.state_info
+                               if info.get("cache")},
                 "prefill_dispatches": self._prefill_dispatches,
                 "optimizer": {
                     "accepted": (bool(self.opt_plan.accepted)

@@ -251,3 +251,99 @@ def test_planner_peak_within_25pct_of_v5e(one_chip, name):
     assert chip > 0
     assert abs(plan["peak_bytes"] - chip) / chip < 0.25, \
         "planner %d vs v5e compiler %d" % (plan["peak_bytes"], chip)
+
+
+# ---------------------------------------------------------------------------
+# the decoder with experts and window caches, at the benchmark's sizes
+# ---------------------------------------------------------------------------
+
+def _smallthinker_cfg():
+    import json
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "smallthinker-21ba3b-8l-bf16.json")) as f:
+        return json.load(f)
+
+
+def test_smallthinker_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """``smallthinker-decode-longdoc``'s step at its own size: 32 slots,
+    eight layers at the published widths in bfloat16, rings of 4,096
+    rows and caches of 12,288, the pool donated, the cache writes in the
+    kernel 'auto' picks on the chip.  7.93 GB of weights and 3.22 GB of
+    pool; what the step adds to them has to stay small."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.executor import build_graph_fn
+    from mxnet_tpu.models import smallthinker
+    cfg = _smallthinker_cfg()
+    slots, bf = 32, jnp.bfloat16
+    step, info = smallthinker.decode_step(cfg, 12288)
+    pool = jax.ShapeDtypeStruct((slots,) + tuple(info[0]["shape"]), bf)
+    monkeypatch.setenv("MXNET_CACHE_SCATTER_IMPL",
+                       _impl_auto_picks_on_tpu(monkeypatch, pool))
+    head = mx.sym.argmax(step[0], axis=1)
+    serve = mx.sym.Group([head] + [step[i] for i in range(1, len(step))])
+    names = serve.list_arguments()
+    fn = build_graph_fn(serve, names, [])
+    shapes = smallthinker.param_shapes(cfg)
+    states = {i["name"]: (slots,) + tuple(i["shape"]) for i in info}
+    args = [jax.ShapeDtypeStruct(shapes[n], bf) if n in shapes
+            else jax.ShapeDtypeStruct(states[n], bf) if n in states
+            else jax.ShapeDtypeStruct((slots,), jnp.float32)
+            for n in names]
+    jitted = jax.jit(
+        lambda *flat: fn(list(flat), [], jax.random.PRNGKey(0), False)[0],
+        donate_argnums=tuple(names.index(n) for n in states))
+    compiled = jitted.lower(*_described(args, one_chip)).compile()
+    ma = compiled.memory_analysis()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert ma.alias_size_in_bytes == 32 * (12 * 4096 + 4 * 12288) * 512 * 2
+    assert ma.temp_size_in_bytes < 0.5e9
+    assert _total_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("case", ["experts_8192_rows", "attention_global",
+                                  "attention_window", "commit_rings"])
+def test_smallthinker_prefill_parts_compile_for_v5e(one_chip, case):
+    """The parts of the 8,192-position prefill that are new to the chip's
+    compiler, each alone (the whole program takes it a minute): the
+    grouped expert product over 49,152 sorted pairs, blockwise attention
+    (no 8,192 x 8,192 score tensor: temporaries under 1.5 GB), and the
+    prefill's keys and values laid into rings and whole caches in
+    place."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.models import smallthinker
+    from mxnet_tpu.ops.registry import get_op
+    from mxnet_tpu.serving.decode import _lay_rows
+    bf = jnp.bfloat16
+
+    def sds(*shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    donate = ()
+    if case == "experts_8192_rows":
+        op = get_op("_moe_experts")
+        fn = op.bound(op.normalize({"top_k": 6}))
+        args = (sds(8192, 2560), sds(8192, 64, dtype=jnp.float32),
+                sds(64, 768, 2560), sds(64, 768, 2560), sds(64, 768, 2560))
+        limit = 1.5e9
+    elif case.startswith("attention"):
+        op = get_op("_gqa_prefill")
+        fn = op.bound(op.normalize({
+            "num_heads": 28, "num_kv_heads": 4,
+            "window": 4096 if case.endswith("window") else 0}))
+        args = (sds(1, 8192, 3584), sds(1, 8192, 512), sds(1, 8192, 512))
+        limit = 1.5e9       # 8,192 x 8,192 x 28 float32 would be 7.5 GB
+    else:
+        info = smallthinker.state_info(_smallthinker_cfg(), 12288)
+
+        def fn(bufs, rows, slots, lens):
+            return [_lay_rows(b, r, i, slots, lens)
+                    for b, r, i in zip(bufs, rows, info)]
+        args = ([sds(32, *i["shape"]) for i in info],
+                [sds(1, 8192, 512) for _ in info],
+                sds(1, dtype=jnp.int32), sds(1, dtype=jnp.int32))
+        donate, limit = (0,), 0.2e9
+    compiled = jax.jit(fn, donate_argnums=donate).lower(
+        *_described(args, one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < limit

@@ -582,7 +582,7 @@ def test_prefill_failure_isolated_to_joining_request():
     class _Boom(object):
         compile_count = 0
 
-        def run(self, feeds):
+        def dispatch(self, feeds):
             raise RuntimeError("prefill boom")
 
     eng._prefill_buckets = (64,)

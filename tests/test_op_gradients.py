@@ -184,6 +184,24 @@ SPECS["_cache_write_row"] = S(
 SPECS["_cache_write_rows"] = S(
     lambda: [_u(3, 5, 2), _u(3, 2, 2), np.array([0., 3., 2.]),
              np.array([0., 2., 1.])], wrt=[0, 1])
+# decoder-block ops (ops/transformer.py): plain jax, so jax.vjp is exact;
+# positions are data, not differentiated
+SPECS["RMSNorm"] = S(lambda: [_u(2, 3, 6), _pos(6)], {"eps": 1e-6})
+SPECS["_dense"] = S(lambda: [_u(2, 3, 5), _u(4, 5)], {"num_hidden": 4})
+SPECS["_rotary"] = S(lambda: [_u(2, 3, 8), np.array([[0., 5., 11.]])],
+                     {"head_dim": 4, "theta": 100.0}, wrt=[0])
+SPECS["_gqa_decode"] = S(
+    lambda: [_u(3, 8), _u(3, 4, 4), _u(3, 4, 4), np.array([0., 3., 6.])],
+    {"num_heads": 4, "num_kv_heads": 2, "window": 4}, wrt=[0, 1, 2])
+SPECS["_gqa_prefill"] = S(
+    lambda: [_u(2, 5, 8), _u(2, 5, 4), _u(2, 5, 4)],
+    {"num_heads": 4, "num_kv_heads": 2, "window": 3, "block": 2})
+# at a few rows every held expert multiplies every row (the sorted
+# path's loop has a data-dependent trip count and no reverse rule);
+# router logits spread apart so no choice sits on a tie
+SPECS["_moe_experts"] = S(
+    lambda: [_u(3, 4), _distinct(3, 5) * 3.0, _u(5, 3, 4), _u(5, 3, 4),
+             _u(5, 3, 4)], {"top_k": 2})
 SPECS["Embedding"] = S(lambda: [np.array([0., 2., 1.]), _u(4, 3)],
                        {"input_dim": 4, "output_dim": 3}, wrt=[1])
 
