@@ -464,7 +464,8 @@ def decode_step_program(prog):
     over a fresh pool (see train_step_program)."""
     z = np.zeros((prog.num_slots,), np.float32)
     flat = prog._build_flat(z, z, z, prog.init_states())
-    return prog._jit_kernel, (prog._key, np.int32(0), z) + tuple(flat)
+    return prog._jit_kernel, (prog._key, np.int32(0), z,
+                              prog._ids_like) + tuple(flat)
 
 
 def phase_kv(sizes, seed, platform, cache):

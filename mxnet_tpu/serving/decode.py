@@ -21,9 +21,24 @@ workload):
   Caching" (arxiv 2603.09555): a ``(slots, max_len, d)`` buffer
   written at one position per step, never grown, never re-laid-out.
   State stays in HBM across steps (buffers are donated to the step
-  dispatch off-CPU); the host ships only the per-step new-token id
-  vector and the slot-occupancy/valid vector, and receives only the
-  sampled token ids back;
+  dispatch off-CPU); the host ships four small vectors a step (tokens,
+  positions, the slot-occupancy/valid vector, the join-time reset) and
+  receives only the sampled token ids back;
+- **one step in flight**: the plain loop dispatches step N+1 BEFORE it
+  reads step N's ids (``DecodeEngine._step_body``).  A slot that
+  generates is fed the id step N sampled for it on the device, from
+  that step's output buffer (``StepFeed`` / ``FROM_PREVIOUS``: a
+  select inside the one step program), a slot fed its prompt takes the
+  host's token, and positions advance at the dispatch.  The device
+  runs N+1 while the host reads N, walks its slots, admits and builds
+  N+2.  Delivery goes by who sat where when the step was dispatched; a
+  finish by length is known before the read and its slot is not stepped
+  again, so only an eos id, a deadline or a raising callback costs one
+  slot-step whose id is thrown away (``stats()["decode"]``:
+  ``steps_ahead``, ``slot_steps_discarded``).  Whatever leaves the
+  steady state (an empty pool, a close, a failure) first reads the step
+  in flight.  A speculative step commits a count of positions only its
+  read tells, so it is read where it is dispatched;
 - **masked dead slots**: free slots ride along in every dispatch
   holding whatever a finished request left behind.  That is sound
   exactly when the step graph is row-local along the slot axis —
@@ -81,7 +96,8 @@ Step-graph contract: ``step_sym`` outputs ``[logits] + next_states``
 (slot vector of last token ids), the state names from ``state_info``
 (each ``(slots,) + per_slot_shape``), and optionally ``pos`` (per-slot
 write position) and ``valid`` (1/0 occupancy).  The engine appends a
-greedy ``argmax`` head so only token ids cross the host boundary.
+greedy ``argmax`` head so only token ids cross the host boundary, and
+they cross it one step late.
 """
 from __future__ import annotations
 
@@ -243,7 +259,7 @@ class DecodeRequest(Request):
     scheduler mutates as the request moves queue -> slot -> done."""
     __slots__ = ("prompt", "max_new", "tokens", "prompt_i", "slot",
                  "t_join", "n_steps", "t_first_tok", "t_last_tok",
-                 "on_token", "sse_id", "uflops")
+                 "on_token", "sse_id", "uflops", "n_ahead")
 
     def __init__(self, prompt, max_new, future, deadline=None,
                  trace=None, on_token=None, sse_id=None):
@@ -267,6 +283,10 @@ class DecodeRequest(Request):
         self.on_token = on_token
         self.tokens = []            # generated ids (host mirror)
         self.prompt_i = 0           # next prompt token to teacher-force
+        # generated tokens dispatched for and not delivered yet (the
+        # plain loop reads a step after it dispatched the next): with
+        # ``tokens`` it tells, before the read, which step is the last
+        self.n_ahead = 0
         self.slot = None
         self.t_join = None
         self.n_steps = 0
@@ -329,6 +349,66 @@ def _lay_rows(buf, rows, info, slots, lens):
             buf, one[None].astype(buf.dtype),
             (slots[i],) + (0,) * (buf.ndim - 1))
     return buf
+
+
+#: In a :class:`StepFeed`'s token vector: the slot is fed the id that
+#: the step before sampled for it.  Token ids are never negative.
+FROM_PREVIOUS = -1.0
+
+
+class StepFeed(object):
+    """What a caller that keeps one step in flight hands
+    :meth:`StepProgram.step` in place of the token vector: the host's
+    ``tokens``, ``FROM_PREVIOUS`` where a slot takes the id that
+    ``after`` sampled for it, and ``after``, the :class:`PendingStep`
+    dispatched before this one (None where no slot asks).  The ids go
+    from one step's output buffer into the next step's input on the
+    device; the host need not have read them."""
+    __slots__ = ("tokens", "after")
+
+    def __init__(self, tokens, after=None):
+        self.tokens = tokens
+        self.after = after
+
+
+class PendingStep(object):
+    """A dispatched step whose ids the host has not read: what
+    :meth:`StepProgram.step` returns for a :class:`StepFeed`.  The copy
+    to the host starts at the dispatch; :meth:`read` waits for it.
+    ``ids`` stays a device array, for the :class:`StepFeed` of the step
+    after.  ``dispatch_s`` and, once read, ``read_s`` and ``extras``
+    (the step's counters past its states) are what ``last_split`` and
+    ``last_extras`` hold for a step that is read at once."""
+    __slots__ = ("ids", "extras", "dispatch_s", "read_s", "_extra_outs",
+                 "_names", "_tl")
+
+    def __init__(self, ids, extra_outs, names, tl):
+        self.ids = ids
+        self._extra_outs = list(extra_outs)
+        self._names = names
+        self._tl = tl
+        self.extras = {}
+        self.dispatch_s = self.read_s = 0.0
+        for o in [ids] + self._extra_outs:
+            o.copy_to_host_async()
+
+    def read(self):
+        """The sampled ids as a host vector; blocks until the device
+        has finished the step."""
+        t0 = time.perf_counter()
+        with (self._tl.annotate("decode.step.read")
+              if self._tl is not None else _telemetry.timeline.NO_SPAN):
+            ids = np.asarray(self.ids)
+            self.extras = dict(zip(
+                self._names, [np.asarray(o) for o in self._extra_outs]))
+        self._extra_outs = ()
+        self.read_s = time.perf_counter() - t0
+        return ids
+
+    def __array__(self, dtype=None, copy=None):
+        # ``np.array(step)``: what a wrapper around ``StepProgram.step``
+        # that looks at the ids gets (``StepProgram.pending``)
+        return np.array(self.read(), dtype=dtype)
 
 
 class StepProgram(object):
@@ -702,7 +782,9 @@ class StepProgram(object):
                         + [committed[kk]
                            for kk in self.draft_state_keys])
 
-        def call(key, tick, reset, *flat):
+        token_pos = order.index(token_name)
+
+        def call(key, tick, reset, prev_ids, *flat):
             self._trace_count += 1      # runs once per XLA trace
             _count_xla_trace()
             # a joining slot's state is zeroed HERE, fused into the
@@ -716,14 +798,28 @@ class StepProgram(object):
                 s = flat[i]
                 r = reset.reshape((-1,) + (1,) * (s.ndim - 1))
                 flat[i] = jnp.where(r > 0, jnp.zeros((), s.dtype), s)
+            # a slot whose token is ``FROM_PREVIOUS`` takes the id the
+            # step before sampled for it, here, from that step's output
+            # buffer: a caller that keeps a step in flight dispatches
+            # this one before it has read that one (``StepFeed``).  The
+            # select is part of the one program, whoever calls it: a
+            # host-fed step hands in ids that no slot asks for
+            tok = flat[token_pos]
+            flat[token_pos] = jnp.where(tok < 0, prev_ids, tok)
             outs, _ = gf(flat[:na], flat[na:], key, False)
+            outs = list(outs)
             if not _sampler.greedy:
                 # fold the per-step tick into the (formerly dead) key
                 # INSIDE the jit: tick is a traced scalar, so churning
                 # values never retrace, and the sampler's draws are a
                 # pure function of (base key, tick, logits)
                 k = jax.random.fold_in(key, tick)
-                outs = [_sampler.sample(k, outs[0])] + list(outs[1:])
+                outs[0] = _sampler.sample(k, outs[0])
+            # the ids leave in the dtype the token feed has, so that
+            # they can be the next step's ``prev_ids`` under the one
+            # signature (the head's own dtype is the logits': the same
+            # values in a bfloat16 graph, and no cast in a float32 one)
+            outs[0] = outs[0].astype(tok.dtype)
             return outs
 
         if self._spec is not None:
@@ -733,8 +829,9 @@ class StepProgram(object):
             # in-place HBM update of the slot pool: the old state
             # buffers are donated to the dispatch (CPU jax cannot
             # honor donation and would warn per compile).  Offsets
-            # skip the (key, tick, reset[, spec]) leading args.
-            lead = 4 if self._spec is not None else 3
+            # skip the (key, tick, reset, spec or prev_ids) leading
+            # args; the previous ids are read, never donated.
+            lead = 4
             donate = tuple(lead + order.index(n)
                            for n in self.state_names)
             if self._spec is not None:
@@ -784,6 +881,15 @@ class StepProgram(object):
         # byte-for-byte
         self._tl = None
         self.last_split = None  # (dispatch seconds, read seconds)
+        # what a step is handed as its previous ids where no slot asks
+        # for one: the newest ids this program sampled, zeros before
+        # the first.  Only its shape, dtype and placement matter (the
+        # select takes none of its values), and they are those of every
+        # fed-back step, so the host-fed and the fed-back form are one
+        # compiled program
+        self._ids_like = jax.device_put(
+            np.zeros((self.num_slots,), np.float32),
+            None if self._plan is not None else self._ctx.jax_device())
         seed = getattr(self.sampler, "seed", None)
         if seed is not None:
             self._key = jax.random.PRNGKey(int(seed))
@@ -900,24 +1006,24 @@ class StepProgram(object):
             self._row_kernels[sig] = kernel
         return kernel
 
-    def _ensure_kernel(self, reset, flat, spec_m=None):
+    def _ensure_kernel(self, reset, fourth, flat):
         """Resolve the persistent step kernel at the first dispatch
-        (the argument avals are only concrete here): AOT-cache hit
-        loads the serialized program with zero traces; a miss compiles
-        once through jax.export and persists it.  Double-checked under
-        a lock: the scheduler's first step and a rehab probe may race
-        here, and exactly one resolution must win."""
+        (the argument avals are only concrete here; ``fourth`` is the
+        speculative mask, or the plain step's previous ids): AOT-cache
+        hit loads the serialized program with zero traces; a miss
+        compiles once through jax.export and persists it.
+        Double-checked under a lock: the scheduler's first step and a
+        rehab probe may race here, and exactly one resolution must
+        win."""
         if self._kernel is None:
             with self._kernel_lock:
                 if self._kernel is None:
                     from .aot_cache import resolve_kernel
-                    lead = [self._key, np.int32(0), reset]
-                    if spec_m is not None:
-                        lead.append(spec_m)
                     kernel, _src = resolve_kernel(
                         self._aot, self._jit_kernel, "decode_step",
                         self._graph_digest,
-                        lead + list(flat),
+                        [self._key, np.int32(0), reset, fourth]
+                        + list(flat),
                         donate_argnums=self._donate)
                     self._kernel = kernel
         return self._kernel
@@ -991,11 +1097,16 @@ class StepProgram(object):
         this step — how a join clears the previous occupant's rows
         without a single extra device dispatch.  Returns (sampled ids
         as a host float vector, new state dict) — the only
-        device->host traffic is the id vector."""
+        device->host traffic is the id vector.  Handed a
+        :class:`StepFeed` as ``tokens``, it reads nothing and returns
+        (:class:`PendingStep`, new state dict): the caller reads the
+        ids when it has dispatched the step after."""
         if self._spec is not None:
             raise MXNetError("this StepProgram compiled a speculative "
                              "draft-k-verify step: dispatch through "
                              "step_spec()")
+        if isinstance(tokens, StepFeed):
+            return self._step_ahead(tokens, pos, valid, states, reset)
         host, outs = self._run(tokens, pos, valid, states, reset, None, 1)
         new_states = {name: outs[1 + i]
                       for i, name in enumerate(self.state_names)}
@@ -1003,16 +1114,59 @@ class StepProgram(object):
             self.last_extras = dict(zip(self.extra_names, host[1:]))
         return host[0], new_states
 
-    def _dispatch(self, tokens, pos, valid, states, reset, spec):
+    def _step_ahead(self, feed, pos, valid, states, reset):
+        """:meth:`step` for a :class:`StepFeed`: the same one dispatch,
+        returned as ``(PendingStep, new state dict)`` with nothing
+        read.  The caller goes on writing its vectors while the step
+        runs, so the dispatch takes copies of them."""
+        tl = self._tl
+        after = feed.after
+        t0 = time.perf_counter()
+        with (tl.annotate("decode.step.dispatch") if tl is not None
+              else _telemetry.timeline.NO_SPAN):
+            outs = self._dispatch(
+                feed.tokens.copy(), pos.copy(), valid.copy(), states,
+                None if reset is None else reset.copy(), None,
+                prev=None if after is None else after.ids)
+            pending = PendingStep(
+                outs[0], outs[len(outs) - len(self.extra_names):],
+                self.extra_names, tl)
+        pending.dispatch_s = time.perf_counter() - t0
+        return pending, {name: outs[1 + i]
+                         for i, name in enumerate(self.state_names)}
+
+    def pending(self, ids):
+        """``ids`` as the :class:`PendingStep` of the step that sampled
+        them: what a caller that keeps a step in flight makes of a host
+        vector it is handed where it expected the step unread, because
+        a wrapper around :meth:`step` read the ids itself, and may have
+        changed them.  They are the step's ids from here on, for the
+        requests and for the step after."""
+        import jax
+        return PendingStep(
+            jax.device_put(np.asarray(ids, np.float32),
+                           self._ids_like.sharding), (), (), self._tl)
+
+    def _dispatch(self, tokens, pos, valid, states, reset, spec,
+                  prev=None):
         """Build the flat argument vector and enqueue the step kernel;
-        returns its device outputs without waiting for them."""
+        returns its device outputs without waiting for them.  ``prev``
+        is the plain step's previous ids (``call``), the newest this
+        program sampled where the caller names none."""
         if reset is None:
             reset = np.zeros((self.num_slots,), np.float32)
         flat = self._build_flat(tokens, pos, valid, states)
-        kernel = self._ensure_kernel(reset, flat, spec_m=spec)
+        if spec is None:
+            fourth = self._ids_like if prev is None else prev
+        else:
+            fourth = spec
+        kernel = self._ensure_kernel(reset, fourth, flat)
         self._tick = (self._tick + 1) & 0x7fffffff
-        lead = (reset,) if spec is None else (reset, spec)
-        return kernel(self._key, np.int32(self._tick), *lead, *flat)
+        outs = kernel(self._key, np.int32(self._tick), reset, fourth,
+                      *flat)
+        if spec is None:
+            self._ids_like = outs[0]
+        return outs
 
     def _run(self, tokens, pos, valid, states, reset, spec, n_read):
         """One dispatch and the blocking read of its first ``n_read``
@@ -1092,13 +1246,11 @@ class StepProgram(object):
         z = np.zeros((self.num_slots,), np.float32)
         states = self.init_states()
         flat = self._build_flat(z, z, z, states)
-        if self._spec is not None:
-            kernel = self._ensure_kernel(z, flat, spec_m=z)
-            outs = kernel(jax.random.PRNGKey(0), np.int32(0), z, z,
-                          *flat)
-        else:
-            kernel = self._ensure_kernel(z, flat)
-            outs = kernel(jax.random.PRNGKey(0), np.int32(0), z, *flat)
+        # the speculative mask, or previous ids that no slot asks for
+        fourth = z if self._spec is not None else self._ids_like
+        kernel = self._ensure_kernel(z, fourth, flat)
+        outs = kernel(jax.random.PRNGKey(0), np.int32(0), z, fourth,
+                      *flat)
         return [np.asarray(o) for o in outs]
 
     def sample_tokens(self, logits):
@@ -1257,9 +1409,9 @@ class _DecodeTelemetry(object):
             "and expired=True, and the slot frees for queued work")
         self.step_ms = reg.histogram(
             "mxnet_serve_decode_step_ms",
-            "wall time of one decode iteration (deadline sweep + step "
-            "dispatch + host bookkeeping), per engine and device "
-            "replica",
+            "wall time of one decode iteration (deadline sweep + "
+            "dispatch of the next step + read and delivery of the step "
+            "in flight), per engine and device replica",
             labelnames=("engine", "replica"),
             buckets=_telemetry.LATENCY_MS_BUCKETS)
         # per-request tail latency the tokens/s counter cannot see
@@ -1849,6 +2001,10 @@ class DecodeEngine(object):
         self._step_ms = collections.deque(maxlen=4096)
         self._lat_ms = collections.deque(maxlen=4096)
         self._steps = 0
+        self._steps_ahead = 0       # dispatched before the step before
+        #                             them was read
+        self._discarded = 0         # slot-steps whose result was thrown
+        #                             away: their request had left
         self._joins = 0
         self._steals = 0
         self._leaves = 0
@@ -2592,12 +2748,13 @@ class DecodeEngine(object):
             self._hb_busy = False
             try:
                 if self._abort:
+                    self._settle(rep)
                     for i in rep.occupied():
                         self._finish_slot(rep, i, "closed")
                     return
                 occ = rep.occupied()
                 free = self.num_slots - len(occ)
-                if not occ:
+                if not occ and rep.flight is None:
                     batch = self._adm.take(free, 0.0)
                     if batch is None:
                         return          # closed and drained
@@ -2626,7 +2783,9 @@ class DecodeEngine(object):
                 # a failed step dispatch may have consumed the DONATED
                 # state buffers (non-CPU backends): rep.states would
                 # point at deleted arrays and wedge every later step —
-                # the pool is empty now, so fresh zeros lose nothing
+                # the pool is empty now, so fresh zeros lose nothing.
+                # The step in flight goes unread: its requests failed
+                rep.flight = None
                 rep.states = rep.program.init_states()
                 rep.tokens_np.fill(0.0)
                 rep.pos_np.fill(0.0)
@@ -2736,6 +2895,7 @@ class DecodeEngine(object):
                         _fail_future(req.future, e)
                         if req.trace is not None:
                             req.trace.abort(type(e).__name__)
+                self._settle(rep)
                 for i in rep.occupied():
                     self._finish_slot(rep, i, "closed")
                 return
@@ -2784,7 +2944,7 @@ class DecodeEngine(object):
                     live.append(req)
             if live:
                 self._join_many(rep, live)
-            if not rep.occupied_count():
+            if not rep.occupied_count() and rep.flight is None:
                 with self._dr_cond:
                     if rep.pending:
                         continue
@@ -2850,6 +3010,7 @@ class DecodeEngine(object):
             % (rep.index, rep.ctx if rep.ctx is not None else "cpu(0)",
                exc, rep.occupied_count(),
                sum(1 for x in self._replicas if x.healthy)))
+        self._settle(rep)
         for i in rep.occupied():
             self._finish_slot(rep, i, "error")
         if rep.tm_failures is not None:
@@ -2941,6 +3102,7 @@ class DecodeEngine(object):
             rep.reset_np = fresh.reset_np
             rep.spec_np = fresh.spec_np
             rep.states = fresh.states
+            rep.flight = None
             rep.pending.clear()
             rep.in_step = False
             rep.healthy = True
@@ -3304,34 +3466,32 @@ class DecodeEngine(object):
         with _telemetry.timeline.span(
                 "decode.step", "decode", "decode:%s" % rep.label,
                 tl=tl) as sp:
-            done = self._step_body(rep, sp, sp.t0)
-            if done is None:
-                sp.drop()       # empty pool: not a step
-                return
-            disp_s, read_s = rep.program.last_split
-            sp.args = {"live": done[0], "tokens": done[1],
-                       "dispatch_ms": disp_s * 1e3,
-                       "read_ms": read_s * 1e3}
-            for name, arr in rep.program.last_extras.items():
-                sp.args[name + "_max"] = float(arr.max())
-                sp.args[name + "_mean"] = float(arr.mean())
+            sp.args = self._step_body(rep, sp, sp.t0)
+            if sp.args is None:
+                sp.drop()       # no step read: its event comes with it
 
-    def _booked(self, rep, live, new_tokens, t0):
+    def _booked(self, rep, live, new_tokens, t0, ahead=0, discarded=0):
         """Book one scheduler iteration begun at ``t0`` (``stats()``
-        and the scraped series) and return ``(live, new_tokens)``.
-        Called by the step's body while its host arrays are alive:
-        dropping the view of the sampled ids frees a device buffer,
-        which lets a caller woken by its last token run, and it may
-        read ``stats()`` straight away."""
+        and the scraped series): the step it dispatched, over ``live``
+        slots (none: it only read the step in flight), and what the
+        step it read delivered.  Called by the step's body while its
+        host arrays are alive: dropping the view of the sampled ids
+        frees a device buffer, which lets a caller woken by its last
+        token run, and it may read ``stats()`` straight away."""
         dt_ms = (time.perf_counter() - t0) * 1e3
         with self._lock:
-            self._steps += 1
             self._tokens_out += new_tokens
-            self._step_ms.append(dt_ms)
+            self._discarded += discarded
+            if live:
+                self._steps += 1
+                self._steps_ahead += ahead
+                self._step_ms.append(dt_ms)
         if self._tm is not None:
-            self._tm.steps.inc()
             if new_tokens:
                 self._tm.tokens.inc(new_tokens)
+            if not live:
+                return
+            self._tm.steps.inc()
             rep.tm_step_ms.observe(dt_ms)
             # slot-occupancy split of this dispatch (ISSUE 18
             # satellite): the persistent step computed num_slots rows
@@ -3340,34 +3500,66 @@ class DecodeEngine(object):
             dead = self.num_slots - live
             if dead:
                 self._tm.slot_steps_dead.inc(dead)
-        return live, new_tokens
+
+    def _step_args(self, live, tokens, dispatch_s, read_s, ahead=0,
+                   discarded=0, extras=()):
+        """The arguments of one step's ``decode.step`` event (nothing
+        with the plane off): the step's counters go in as
+        ``<name>_max`` / ``<name>_mean``."""
+        if self._tl is None:
+            return None
+        args = {"live": live, "tokens": tokens,
+                "dispatch_ms": dispatch_s * 1e3, "read_ms": read_s * 1e3,
+                "ahead": ahead, "discarded": discarded}
+        for name, arr in dict(extras).items():
+            args[name + "_max"] = float(arr.max())
+            args[name + "_mean"] = float(arr.mean())
+        return args
 
     def _step_body(self, rep, sp, t0):
         """One scheduler iteration, begun at ``t0``: deadline scan, the
-        step program, delivery of the sampled tokens, the step's
-        booking.  Returns ``(live slots, new tokens)``, or None when
-        no slot was occupied.  ``sp`` is the open ``decode.step`` span
-        (its inert stand-in with the plane off): the scan and the
+        dispatch of the next step, the read and delivery of the step in
+        flight, the booking.  Returns the arguments of the
+        ``decode.step`` event of the step it read, all of them that
+        step's own (one event a step, written by the iteration that
+        reads it, whose interval also holds the dispatch of the step
+        after), or None when it read none: an empty pool, or a step
+        dispatched onto an idle one.  ``sp`` is the open ``decode.step``
+        span (its inert stand-in with the plane off): the scan and the
         delivery are marked inside it in the profiler's trace
         (``mx:decode.step.scan`` / ``.deliver``); the step program
-        marks its own dispatch and read."""
+        marks its own dispatch and read.
+
+        The plain loop keeps ONE step in flight.  Step N+1 goes out
+        before step N's ids are read: a slot that generates takes its
+        token from step N's output buffer on the device
+        (``FROM_PREVIOUS``), a slot fed its prompt takes the host's,
+        and every stepped slot's position is advanced here, at the
+        dispatch.  The device runs N+1 while the host reads N, walks
+        its slots, admits, and builds N+2.  A speculative step commits
+        a count of positions that only its read tells, so it is read
+        where it is dispatched."""
         now = time.monotonic()
         # per-iteration deadline check folded into ONE slot scan: an
         # expired slot-resident request completes with its partial
         # tokens and frees the slot for queued work — mid-generation
-        # eviction, not failure
+        # eviction, not failure.  A seated slot that is not valid has
+        # its last token in the step in flight: it is not stepped
+        # again, and not evicted either (its answer is whole, and is
+        # delivered further down this iteration)
         with sp.child("decode.step.scan"):
             occ = []
             for i, req in enumerate(rep.slots):
-                if req is None:
+                if req is None or not rep.valid_np[i]:
                     continue
                 if req.deadline is not None and now >= req.deadline:
                     self._finish_slot(rep, i, "deadline")
                 else:
                     occ.append(i)
-        if not occ:
+        read = rep.flight
+        if not occ and read is None:
             return None
-        if _faults.ACTIVE:
+        if occ and _faults.ACTIVE:
             # chaos seam: a raise retires this replica through the
             # real step-failure path (partial-output eviction +
             # re-route); a hang wedges the pool for the watchdog
@@ -3395,50 +3587,135 @@ class DecodeEngine(object):
             with sp.child("decode.step.deliver"):
                 new_tokens = self._advance_spec(rep, occ, toks_mat,
                                                 counts)
-            return self._booked(rep, len(occ), new_tokens, t0)
-        sampled, rep.states = rep.program.step(
-            rep.tokens_np, rep.pos_np, rep.valid_np, rep.states,
-            reset=rep.reset_np)
-        rep.reset_np.fill(0.0)      # consumed: rows are zeroed now
-        if self._eff is not None:
-            self._ledger_step(
-                rep, occ,
-                self._eff.record_step(
-                    rep.label,
-                    _goodput.price_step_program(rep.program),
-                    len(occ), self.num_slots))
+            self._booked(rep, len(occ), new_tokens, t0)
+            return self._step_args(len(occ), new_tokens,
+                                   *(rep.program.last_split or (0, 0)))
+        if occ:
+            # a slot that holds ``FROM_PREVIOUS`` was stepped by the
+            # step in flight: there is one whenever there is such a slot
+            step, rep.states = rep.program.step(
+                StepFeed(rep.tokens_np, read and read[0]), rep.pos_np,
+                rep.valid_np, rep.states, reset=rep.reset_np)
+            rep.reset_np.fill(0.0)      # consumed: rows are zeroed now
+            if not isinstance(step, PendingStep):
+                step = rep.program.pending(step)
+            rep.flight = (step, self._seats_ahead(rep, occ),
+                          int(read is not None))
+            if self._eff is not None:
+                self._ledger_step(
+                    rep, occ,
+                    self._eff.record_step(
+                        rep.label,
+                        _goodput.price_step_program(rep.program),
+                        len(occ), self.num_slots))
+        else:
+            rep.flight = None
+        if read is None:
+            self._booked(rep, len(occ), 0, t0)
+            return None
+        before, seats, ahead = read
+        ids = before.read()
         with sp.child("decode.step.deliver"):
-            # one C-level conversion instead of num_slots
-            # ndarray-scalar __getitem__ calls: the slot loop below is
-            # the scheduler's per-step GIL cost, and with replica
-            # routing two of these loops interleave on the host —
-            # every microsecond here is paid per step per replica
-            sampled_l = sampled.tolist()
-            new_tokens = 0
-            t_tok = time.perf_counter()  # one stamp serves every slot
-            for i in occ:
-                req = rep.slots[i]
-                req.n_steps += 1
-                rep.pos_np[i] += 1.0
-                if req.prompt_i < len(req.prompt):
-                    # teacher forcing: the sample is discarded, the
-                    # next prompt token rides the next step
-                    rep.tokens_np[i] = req.prompt[req.prompt_i]
-                    req.prompt_i += 1
-                else:
-                    tok = sampled_l[i]
-                    req.tokens.append(int(tok))
-                    rep.tokens_np[i] = tok
-                    new_tokens += 1
-                    if req.t_first_tok is None:
-                        self._first_token(req, t_tok)
-                    req.t_last_tok = t_tok
-                    self._emit_token(req, tok)
-                    if req.on_token is not None \
-                            and not self._fire_on_token(rep, req, tok):
-                        continue    # evicted by its own callback
-                self._check_finish(rep, i)
-        return self._booked(rep, len(occ), new_tokens, t0)
+            new_tokens, discarded = self._deliver(rep, seats, ids)
+        self._booked(rep, len(occ), new_tokens, t0, int(bool(occ)),
+                     discarded)
+        return self._step_args(len(seats), new_tokens, before.dispatch_s,
+                               before.read_s, ahead, discarded,
+                               before.extras)
+
+    def _seats_ahead(self, rep, occ):
+        """The host's half of a dispatch, made before the step's ids
+        exist: every stepped slot moves on one position and is given
+        its next token, the host's while it is fed its prompt and the
+        step's own (``FROM_PREVIOUS``) once it generates.  A request
+        whose count of tokens or of positions is full with the token
+        now in flight is not stepped again: its slot goes dead here and
+        is freed when that token is delivered, so that a finish by
+        length costs no slot-step.  Only a finish the host cannot
+        foresee (the eos id, a deadline, a callback that raises) leaves
+        one slot-step in flight, whose id is thrown away.  Returns who
+        sat where: ``(slot, request, kind)`` a stepped slot, kind 0 fed
+        its prompt, 1 generating, 2 generating its last token."""
+        seats = []
+        for i in occ:
+            req = rep.slots[i]
+            rep.pos_np[i] += 1.0
+            if req.prompt_i < len(req.prompt):
+                # teacher forcing: the sample is discarded, the next
+                # prompt token rides the next step
+                rep.tokens_np[i] = req.prompt[req.prompt_i]
+                req.prompt_i += 1
+                seats.append((i, req, 0))
+                continue
+            req.n_ahead += 1
+            if len(req.tokens) + req.n_ahead >= req.max_new \
+                    or rep.pos_np[i] >= self.max_len:
+                # a dead slot rides along at position 0, like any other
+                rep.valid_np[i] = 0.0
+                rep.tokens_np[i] = 0.0
+                rep.pos_np[i] = 0.0
+                seats.append((i, req, 2))
+            else:
+                rep.tokens_np[i] = FROM_PREVIOUS
+                seats.append((i, req, 1))
+        return seats
+
+    def _deliver(self, rep, seats, ids):
+        """Hand a read step's ids to the requests that sat in its slots
+        when it was dispatched (``seats``), not to whoever sits there
+        now: a request that has left since (deadline, eos one step
+        back, a raising callback, a failure), its slot free or seated
+        anew, gets nothing, and the slot-step counts as discarded.
+        Returns ``(new tokens, discarded slot-steps)``."""
+        # one C-level conversion instead of num_slots ndarray-scalar
+        # __getitem__ calls: the slot loop below is the scheduler's
+        # per-step GIL cost, and with replica routing two of these
+        # loops interleave on the host
+        ids_l = ids.tolist()
+        eos = self.eos_id
+        new_tokens = discarded = 0
+        t_tok = time.perf_counter()     # one stamp serves every slot
+        for i, req, kind in seats:
+            if rep.slots[i] is not req:
+                discarded += 1
+                continue
+            req.n_steps += 1
+            if not kind:
+                continue
+            req.n_ahead -= 1
+            tok = int(ids_l[i])
+            req.tokens.append(tok)
+            new_tokens += 1
+            if req.t_first_tok is None:
+                self._first_token(req, t_tok)
+            req.t_last_tok = t_tok
+            self._emit_token(req, tok)
+            if req.on_token is not None \
+                    and not self._fire_on_token(rep, req, tok):
+                continue        # evicted by its own callback
+            if eos is not None and tok == eos:
+                self._finish_slot(rep, i, "eos")
+            elif kind == 2:
+                self._finish_slot(rep, i, "length")
+        return new_tokens, discarded
+
+    def _settle(self, rep):
+        """Read and deliver the step in flight, if there is one: what
+        touches the pool next (a close, a failure's clean-up) finds
+        every sampled id delivered and nothing pending.  A failure may
+        be what brought the loop here: a step that cannot be read is
+        dropped, and its requests keep the tokens they have.  Such a
+        step leaves no ``decode.step`` event; its tokens are booked."""
+        read, rep.flight = rep.flight, None
+        if read is None:
+            return
+        try:
+            ids = read[0].read()
+        except Exception:
+            return
+        new_tokens, discarded = self._deliver(rep, read[1], ids)
+        self._booked(rep, 0, new_tokens, time.perf_counter(),
+                     discarded=discarded)
 
     def _ledger_step(self, rep, occ, useful):
         """Spread one step dispatch's useful FLOPs over the live slots
@@ -3628,7 +3905,9 @@ class DecodeEngine(object):
         live iteration's inputs) carry committed shardings that fresh
         ``init_states`` buffers don't — one warm step would leave the
         first live iteration paying a silent ~100ms recompile that the
-        trace counter cannot even see.  The row-write kernel likewise
+        trace counter cannot even see.  The second step is also the
+        first to be handed a step's own ids as its previous ids, as
+        every fed-back step of live traffic is.  The row-write kernel likewise
         warms against both a fresh buffer and a stepped one (the two
         shardings a prefill scatter can meet)."""
         for rep in self._replicas:
@@ -3753,6 +4032,11 @@ class DecodeEngine(object):
                 "slots_occupied": self._occupied_count(),
                 "max_len": self.max_len,
                 "steps": self._steps,
+                # of those, the ones dispatched before the step before
+                # them was read; and the slot-steps whose result was
+                # thrown away because their request had left
+                "steps_ahead": self._steps_ahead,
+                "slot_steps_discarded": self._discarded,
                 "tokens_generated": self._tokens_out,
                 "joins": self._joins,
                 "steals": self._steals,

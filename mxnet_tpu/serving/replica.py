@@ -305,8 +305,8 @@ class DecodeReplica(object):
     __slots__ = ("index", "label", "ctx", "plan", "program",
                  "prefill_caches",
                  "prefill_buckets", "slots", "tokens_np", "pos_np",
-                 "valid_np", "reset_np", "spec_np", "states", "pending",
-                 "healthy",
+                 "valid_np", "reset_np", "spec_np", "states", "flight",
+                 "pending", "healthy",
                  "accepting", "in_step", "probations", "hb_t", "thread",
                  "tm_step_ms", "tm_failures")
 
@@ -336,6 +336,12 @@ class DecodeReplica(object):
         # float per slot); non-spec programs never read it.
         self.spec_np = np.zeros((n,), np.float32)
         self.states = program.init_states()
+        # the plain loop keeps one step in flight (decode.py
+        # ``_step_body``): the dispatched step whose ids are unread, as
+        # ``(PendingStep, who sat where at its dispatch, whether it went
+        # out ahead of a read)``, or None.  Its output buffer feeds the
+        # slots that generate
+        self.flight = None
         self.pending = collections.deque()      # routed DecodeRequests
         self.healthy = True
         self.in_step = False

@@ -437,9 +437,22 @@ def test_decode_ring_splits_the_step_and_the_first_token():
     assert len(by["decode.step"]) == steps
     for e in by["decode.step"]:
         a = e["args"]
-        assert {"live", "tokens", "dispatch_ms", "read_ms"} <= set(a)
+        assert {"live", "tokens", "dispatch_ms", "read_ms", "ahead",
+                "discarded"} <= set(a)
         assert a["dispatch_ms"] > 0 and a["read_ms"] > 0
-        assert a["dispatch_ms"] + a["read_ms"] <= e["dur"] * 1e3
+        assert a["read_ms"] <= e["dur"] * 1e3
+    # one event a step, written by the iteration that reads it; that
+    # iteration also dispatched the step after, if that one went out
+    # ahead: an event's interval then holds its own read and the next
+    # event's dispatch
+    for e, after in zip(by["decode.step"], by["decode.step"][1:]):
+        if after["args"]["ahead"]:
+            assert e["args"]["read_ms"] + after["args"]["dispatch_ms"] \
+                <= e["dur"] * 1e3
+    # ahead of a read, but for the first step of a burst (one burst,
+    # unless this thread was held up between its three submits)
+    ahead = [e["args"]["ahead"] for e in by["decode.step"]]
+    assert ahead[0] == 0 and sum(ahead) >= steps - 3
     # the ring's budget: one event a step, three a request
     assert len(by["decode.first_token"]) == 3
     assert len(by["decode.join"]) == len(by["decode.leave"]) == 3
