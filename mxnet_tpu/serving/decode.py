@@ -122,6 +122,7 @@ from .engine import (_ENGINE_SEQ, _percentile, aot_metric_families,
                      _supervisor_state, memory_metric_families,
                      _memory_stats_block, refresh_memory_gauges)
 from .replica import DecodeReplica, resolve_replica_placements
+from .slot_state import SlotLayout
 
 __all__ = ["DecodeEngine", "DecodeResult", "StepProgram", "greedy_decode",
            "Sampler", "GreedySampler", "TemperatureSampler"]
@@ -300,9 +301,10 @@ class DecodeRequest(Request):
         self.uflops = 0
 
 
-def _pin_state_dtypes(step_sym, state_info, dtype):
+def _pin_state_dtypes(step_sym, states):
     """The step graph with every next-state output cast to the dtype
-    its pool buffer has.  A graph that mixes a low-precision state with
+    its pool buffer has (``states``: the model's, of a
+    :class:`SlotLayout`).  A graph that mixes a low-precision state with
     float32 host vectors (a one-hot blend of ``pos`` into a cache)
     promotes the state, and the pool would come back float32 from the
     first step: twice the bytes, no donation, one more compile.  A
@@ -310,7 +312,7 @@ def _pin_state_dtypes(step_sym, state_info, dtype):
     output that already ends in the cast (``StepProgram`` pins whatever
     graph it is given; the engine pins first, for its analyses)."""
     from .. import symbol as sym
-    want = [np.dtype(info.get("dtype") or dtype) for info in state_info]
+    want = [s.dtype for s in states]
     if all(dt == np.dtype(np.float32) for dt in want):
         return step_sym
     outs = [step_sym[i] for i in range(len(step_sym))]
@@ -321,34 +323,6 @@ def _pin_state_dtypes(step_sym, state_info, dtype):
             continue
         outs[1 + i] = sym.Cast(outs[1 + i], dtype=dt.name)
     return sym.Group(outs)
-
-
-def _lay_rows(buf, rows, info, slots, lens):
-    """``StepProgram.commit_prefill`` for one state, traced."""
-    import jax.numpy as jnp
-    from jax import lax
-    held = buf.shape[1:]
-    seq = rows.shape[1:] != held
-    if seq and not (info.get("cache") and rows.ndim == buf.ndim
-                    and rows.shape[2:] == held[1:]):
-        raise MXNetError(
-            "prefill rows %s fit neither state %r's row %s nor, as keys "
-            "or values a position, a cache state's"
-            % (rows.shape[1:], info["name"], held))
-    for i in reversed(range(rows.shape[0])):
-        one = rows[i]
-        if seq and one.shape[0] > held[0]:
-            if info.get("window"):
-                last = lens[i] - 1
-                at = last - jnp.mod(
-                    last - jnp.arange(held[0], dtype=jnp.int32), held[0])
-                one = one[jnp.clip(at, 0, one.shape[0] - 1)]
-            else:
-                one = one[:held[0]]
-        buf = lax.dynamic_update_slice(
-            buf, one[None].astype(buf.dtype),
-            (slots[i],) + (0,) * (buf.ndim - 1))
-    return buf
 
 
 #: In a :class:`StepFeed`'s token vector: the slot is fed the id that
@@ -458,8 +432,12 @@ class StepProgram(object):
         self.num_slots = int(num_slots)
         self._dtype = np.dtype(dtype)
         self.sampler = sampler if sampler is not None else GreedySampler()
-        self.state_info = [dict(s) for s in state_info]
-        self.state_names = [s["name"] for s in self.state_info]
+        # what a slot holds, for both models of a speculative program
+        # (slot_state.py reads ``state_info``; nothing here does)
+        self.layout = SlotLayout(
+            state_info, self.num_slots, self._dtype,
+            None if spec is None else spec.draft_state_info)
+        self.state_names = [s.name for s in self.layout.target]
         self.token_name = token_name
         n_states = len(self.state_names)
         if len(step_sym) < 1 + n_states \
@@ -479,7 +457,7 @@ class StepProgram(object):
             n[:-len("_output")] if n.endswith("_output") else n
             for n in step_sym.list_outputs()[1 + n_states:]]
         self.last_extras = {}
-        step_sym = _pin_state_dtypes(step_sym, self.state_info, self._dtype)
+        step_sym = _pin_state_dtypes(step_sym, self.layout.target)
         if self._spec is not None:
             # the spec program needs per-position RAW logits (the
             # greedy head becomes a jnp.argmax with identical
@@ -539,14 +517,8 @@ class StepProgram(object):
         na = len(arg_names)
         n_t = len(order)
         state_pos = tuple(order.index(n) for n in self.state_names)
-        # a ``cache`` state is not zeroed at a join: it is read under a
-        # mask by position, so every row a request reads is one that
-        # request wrote.  Zeroing is a select over the whole buffer in
-        # front of the step, which for the recurrent rows it was written
-        # for is nothing and for a cache of gigabytes is a copy of the
-        # pool every step
-        reset_pos = tuple(order.index(i["name"]) for i in self.state_info
-                          if not i.get("cache"))
+        reset_pos = tuple(order.index(n)
+                          for n in self.layout.reset_names())
         _sampler = self.sampler
         # -------------------------------------------------- draft half
         # the draft model is a full second graph riding the same flat
@@ -564,7 +536,7 @@ class StepProgram(object):
             # once before any replica constructs; a directly-built
             # StepProgram(spec=...) gets the same build here instead
             # of a KeyError inside its first traced dispatch
-            dspec.build(self.num_slots, self.state_info, self._dtype)
+            dspec.build(self.layout)
             dsym = sym.Group(list(dspec.draft_sym))
             d_args = dsym.list_arguments()
             d_auxs = dsym.list_auxiliary_states()
@@ -572,7 +544,7 @@ class StepProgram(object):
                 raise MXNetError("draft graph has no %r input; "
                                  "arguments: %s"
                                  % (dspec.token_name, d_args))
-            d_states = dspec.draft_state_names()
+            d_states = [s.name for s in self.layout.draft]
             missing = [n for n in d_states if n not in d_args]
             if missing:
                 raise MXNetError("draft graph is missing state "
@@ -612,12 +584,11 @@ class StepProgram(object):
                         src[n].as_in_context(self._ctx)._data
             # absolute feed positions in the merged flat vector,
             # keyed by the engine-side draft state keys
-            from .spec import _draft_key
-            self._d_feed_pos = {}
-            for n in d_feeds:
-                key_n = _draft_key(n) if n in d_states else n
-                self._d_feed_pos[key_n] = n_t + d_order.index(n)
-            self.draft_state_keys = dspec.draft_keys()
+            self._d_feed_pos = {n: n_t + d_order.index(n)
+                                for n in d_feeds if n not in d_states}
+            self._d_feed_pos.update((s.key, n_t + d_order.index(s.name))
+                                    for s in self.layout.draft)
+            self.draft_state_keys = [s.key for s in self.layout.draft]
             gf_d = build_graph_fn(dsym, d_args, d_auxs)
             if gf_d.stochastic:
                 raise MXNetError("draft graph contains stochastic "
@@ -630,26 +601,10 @@ class StepProgram(object):
             # rows through the (possibly _cache_write_rows-selected)
             # commit graph; everything else selects the chain state
             # at the accepted count
-            for info in self.state_info:
-                if info.get("cache"):
-                    if self.pos_name is None:
-                        raise MXNetError(
-                            "state %r is cache-declared but the step "
-                            "graph has no %r input — a positional "
-                            "cache commit needs the write position"
-                            % (info["name"], pos_name))
-                    self._spec_cache_t.append(
-                        (info["name"], int(info["shape"][0])))
-            for info in dspec.draft_state_info:
-                if info.get("cache"):
-                    if self._d_pos is None:
-                        raise MXNetError(
-                            "draft state %r is cache-declared but the "
-                            "draft graph has no %r input"
-                            % (info["name"], dspec.pos_name))
-                    self._spec_cache_d.append(
-                        (_draft_key(info["name"]),
-                         int(info["shape"][0])))
+            self._spec_cache_t = self.layout.cache_rows(
+                "target", pos_name, self.pos_name is not None)
+            self._spec_cache_d = self.layout.cache_rows(
+                "draft", dspec.pos_name, self._d_pos is not None)
             gf_commit = commit_args = None
             if dspec.commit_sym is not None:
                 commit_args = dspec.commit_sym.list_arguments()
@@ -917,13 +872,13 @@ class StepProgram(object):
         self._row_kernels = {}
         self._jnp = jnp
 
-        n_s = len(self.state_info)
+        n_s = len(self.state_names)
 
         def commit(slots, lens, *flat):
             self._trace_count += 1
             _count_xla_trace()
-            return [_lay_rows(b, r, info, slots, lens) for b, r, info
-                    in zip(flat[:n_s], flat[n_s:], self.state_info)]
+            return self.layout.lay_prefill(flat[:n_s], flat[n_s:], slots,
+                                           lens)
 
         # a prefill's state rows laid into the pool on the device, one
         # program a (batch, prompt bucket) shape (``commit_prefill``),
@@ -945,37 +900,16 @@ class StepProgram(object):
         device computation)."""
         import jax
         dev = None if self._plan is not None else self._ctx.jax_device()
-        out = {}
-        infos = list(self._state_infos())
-        for key, info in infos:
-            dt = np.dtype(info.get("dtype") or self._dtype)
-            shape = (self.num_slots,) + tuple(info["shape"])
-            if self._plan is not None:
-                # sharded slot-pool layout: the plan's state_rules
-                # decide which per-slot axes partition over the group.
-                # Built from HOST zeros — a pool sized to fit only
-                # when sharded must never be staged whole on one
-                # device (device_put ships each shard's slice)
-                out[key] = self._plan.put_state(
-                    info["name"], np.zeros(shape, dtype=dt))
-            else:
-                out[key] = jax.device_put(
-                    self._jnp.zeros(shape, dtype=dt), dev)
-        return out
-
-    def _state_infos(self, which="all"):
-        """(engine state key, info) pairs over the requested model
-        half: ``"all"`` (the slot pool's full state set), ``"target"``
-        or ``"draft"``.  Draft states ride the merged dict under
-        prefixed keys so a draft h-state never collides with a target
-        one."""
-        if which in ("all", "target"):
-            for info in self.state_info:
-                yield info["name"], info
-        if self._spec is not None and which in ("all", "draft"):
-            from .spec import _draft_key
-            for info in self._spec.draft_state_info:
-                yield _draft_key(info["name"]), info
+        if self._plan is not None:
+            # sharded slot-pool layout: the plan's state_rules decide
+            # which per-slot axes partition over the group.  Built from
+            # HOST zeros — a pool sized to fit only when sharded must
+            # never be staged whole on one device (device_put ships
+            # each shard's slice)
+            return {s.key: self._plan.put_state(s.name, z)
+                    for s, z in self.layout.zeros(pool=True)}
+        return {s.key: jax.device_put(z, dev)
+                for s, z in self.layout.zeros(pool=True, xp=self._jnp)}
 
     def _row_kernel(self, buf, idx, row):
         """The row-scatter kernel for one (buffer, row) signature,
@@ -1062,14 +996,10 @@ class StepProgram(object):
             kernel = self._commit_kernels.get(sig)
             if kernel is None:
                 from .aot_cache import resolve_kernel
-                # what ``_lay_rows`` reads of a state beside its shape
-                tag = "lay_rows_v1|" + ",".join(
-                    "%d:%d" % (bool(i.get("cache")), i.get("window") or 0)
-                    for i in self.state_info)
                 kernel, _src = resolve_kernel(
                     self._aot, self._commit_jit, "decode_commit_prefill",
-                    tag, args, donate_argnums=self._commit_donate,
-                    universal=True)
+                    self.layout.commit_tag(), args,
+                    donate_argnums=self._commit_donate, universal=True)
                 self._commit_kernels[sig] = kernel
         out = dict(states)
         out.update(zip(self.state_names, kernel(*args)))
@@ -1082,11 +1012,8 @@ class StepProgram(object):
         prefill commit path writes REAL target rows but the draft
         (which never saw the prompt) must start the generation cold,
         not from a dead request's leftovers."""
-        rows = {}
-        for key, info in self._state_infos(which):
-            dt = np.dtype(info.get("dtype") or self._dtype)
-            rows[key] = np.zeros(tuple(info["shape"]), dtype=dt)
-        return self.write_row(states, slot, rows)
+        return self.write_row(
+            states, slot, {s.key: z for s, z in self.layout.zeros(which)})
 
     def step(self, tokens, pos, valid, states, reset=None):
         """One decode iteration over the whole pool.  ``tokens``/
@@ -1607,16 +1534,13 @@ class DecodeEngine(object):
     ----------
     step_sym : Symbol with outputs ``[logits] + next_states``.
     arg_params, aux_params : trained weights (checkpoint artifacts).
-    state_info : list of ``{"name", "shape"[, "dtype"]}`` — per-slot
-        state buffers, in the order the step graph returns their next
-        values (``BaseRNNCell.state_info`` shapes with the batch dim
-        dropped; see ``begin_state_arrays`` for the cell-side analog).
-        Each state has its own shape: ``"cache": True`` marks a
-        positional cache whose leading axis is rows, written at ``pos``
-        and read under a mask by position (so a join does not zero it,
-        and a prefill may hand it the keys or values of every prompt
-        position), ``"window": n`` a ring of ``n`` rows written at
-        ``pos mod n``.
+    state_info : list of ``{"name", "shape"[, "dtype"][, "cache"]
+        [, "window"]}`` — per-slot state buffers, in the order the step
+        graph returns their next values (``BaseRNNCell.state_info``
+        shapes with the batch dim dropped; see ``begin_state_arrays``
+        for the cell-side analog).  ``serving/slot_state.py`` defines
+        the format and is its one reader: what ``cache`` and ``window``
+        mean, the pool's shapes, dtypes and bytes.
         Outputs of the step graph past the states are counters read
         with the sampled ids (``StepProgram.extra_names``).
     num_slots, max_len : slot-pool geometry (defaults from
@@ -1718,12 +1642,17 @@ class DecodeEngine(object):
         self._dtype = np.dtype(dtype)
         self._default_deadline_s = float(default_deadline_ms) / 1e3
         self._sampler = sampler if sampler is not None else GreedySampler()
+        # the pool's layout, for the analyses that run before a program
+        # exists (each replica's StepProgram builds its own)
+        self._layout = SlotLayout(
+            state_info, self.num_slots, self._dtype,
+            draft_state_info if self._spec_k else None)
         self.analysis_report = None
         self.step_verdict = None
         self.draft_verdict = None
         if config.get("MXNET_ANALYSIS_ON"):
             self.step_verdict, self.analysis_report = self._preflight(
-                step_sym, state_info, token_name, pos_name,
+                step_sym, "target", token_name, pos_name,
                 valid_name, config.get("MXNET_ANALYSIS_STRICT"),
                 what="step")
             if self._spec_k:
@@ -1734,7 +1663,7 @@ class DecodeEngine(object):
                 # content stays exact, but the soundness bar is the
                 # same as the target's
                 self.draft_verdict, _ = self._preflight(
-                    draft_sym, draft_state_info or [], token_name,
+                    draft_sym, "draft", token_name,
                     pos_name, valid_name,
                     config.get("MXNET_ANALYSIS_STRICT"), what="draft")
         if self._spec_k:
@@ -1743,9 +1672,8 @@ class DecodeEngine(object):
             # garbage tokens silently (take_along_axis clamps under
             # jit) — so it refuses construction even with
             # MXNET_ANALYSIS_ON=0
-            self._check_draft_heads(step_sym, draft_sym, state_info,
-                                    draft_state_info or [],
-                                    token_name, pos_name, valid_name)
+            self._check_draft_heads(step_sym, draft_sym, token_name,
+                                    pos_name, valid_name)
         # fused-op selection (ISSUE 13): run the optimizer's kernel-
         # selection pipeline over the step graph BEFORE any program is
         # built, so StepProgram serves the optimized graph — the
@@ -1761,21 +1689,18 @@ class DecodeEngine(object):
                 and config.get("MXNET_ANALYSIS_ON") \
                 and config.get("MXNET_OPT_SELECT_KERNELS"):
             step_sym, self.opt_plan, self.selection = \
-                self._optimize_step(step_sym, state_info, token_name,
+                self._optimize_step(step_sym, "target", token_name,
                                     pos_name, valid_name, what="step")
             if self._spec_k:
                 draft_sym, self.draft_opt_plan, _dsel = \
-                    self._optimize_step(draft_sym,
-                                        draft_state_info or [],
-                                        token_name, pos_name,
-                                        valid_name, what="draft")
+                    self._optimize_step(draft_sym, "draft", token_name,
+                                        pos_name, valid_name, what="draft")
         # what every replica's StepProgram will run (it pins what it is
         # given; a graph already pinned comes back as it is), so that
         # the analyses below price the pool in the dtype it has
-        step_sym = _pin_state_dtypes(step_sym, state_info, dtype)
+        step_sym = _pin_state_dtypes(step_sym, self._layout.target)
         if self._spec_k:
-            draft_sym = _pin_state_dtypes(draft_sym, draft_state_info or [],
-                                          dtype)
+            draft_sym = _pin_state_dtypes(draft_sym, self._layout.draft)
         # the spec bundle every replica's StepProgram shares: draft
         # graph/params plus the ONE verdict-gated commit graph (built
         # here, not per replica — the selection decision is engine
@@ -1790,7 +1715,7 @@ class DecodeEngine(object):
                 draft_state_info=draft_state_info,
                 token_name=token_name, pos_name=pos_name,
                 valid_name=valid_name)
-            self._spec_cfg.build(self.num_slots, state_info, dtype)
+            self._spec_cfg.build(self._layout)
         # model-parallel decode (ROADMAP item 1): the plan spec is
         # verdict-gated on the step graph's slot-axis row-locality —
         # a plan partitioning the slot axis of a cross-position (or
@@ -1870,9 +1795,9 @@ class DecodeEngine(object):
         if config.get("MXNET_MEMORY_PLAN") \
                 and config.get("MXNET_ANALYSIS_ON"):
             self._memory_preflight(
-                step_sym, state_info, arg_params, aux_params,
+                step_sym, arg_params, aux_params,
                 token_name, pos_name, valid_name, prefill_sym,
-                prefill_buckets, draft_sym, draft_state_info,
+                prefill_buckets, draft_sym,
                 draft_arg_params, draft_aux_params,
                 config.get("MXNET_ANALYSIS_STRICT"))
         budget = self._prefill_token_budget
@@ -2148,7 +2073,7 @@ class DecodeEngine(object):
             plan=plan)
 
     # ---------------------------------------------------------- preflight
-    def _preflight(self, step_sym, state_info, token_name, pos_name,
+    def _preflight(self, step_sym, which, token_name, pos_name,
                    valid_name, strict, what="step"):
         """Construction-time soundness lint: the masked step must be
         row-local along the SLOT axis with state seeded pad-dirty
@@ -2158,19 +2083,11 @@ class DecodeEngine(object):
         (speculative engines) the draft graph — both ride the same
         slot pool.  Returns (verdict, report)."""
         from ..analysis import check_decode_step, AnalysisError
-        n = self.num_slots
-        arg_names = set(step_sym.list_arguments())
-        shapes = {token_name: (n,)}
-        state_names = []
-        for info in state_info:
-            shapes[info["name"]] = (n,) + tuple(info["shape"])
-            state_names.append(info["name"])
-        for extra in (pos_name, valid_name):
-            if extra in arg_names:
-                shapes[extra] = (n,)
+        grid = self._layout.grid(step_sym, token_name, pos_name,
+                                 valid_name, which)
         verdict, report = check_decode_step(
-            step_sym, shapes, state_names=state_names,
-            valid_name=valid_name if valid_name in arg_names else None)
+            step_sym, grid.shapes, state_names=grid.state_names,
+            valid_name=valid_name if valid_name in grid.shapes else None)
         if report.errors:
             if strict:
                 report.raise_if_errors()
@@ -2192,10 +2109,9 @@ class DecodeEngine(object):
                           "WILL differ from single-request decode")
         return verdict, report
 
-    def _memory_preflight(self, step_sym, state_info, arg_params,
-                          aux_params, token_name, pos_name, valid_name,
-                          prefill_sym, prefill_buckets, draft_sym,
-                          draft_state_info, draft_arg_params,
+    def _memory_preflight(self, step_sym, arg_params, aux_params,
+                          token_name, pos_name, valid_name, prefill_sym,
+                          prefill_buckets, draft_sym, draft_arg_params,
                           draft_aux_params, strict):
         """OOM preflight + donation gate (analysis/memory.py).
 
@@ -2216,59 +2132,36 @@ class DecodeEngine(object):
         either way the verdict lands before any compile."""
         from ..analysis import AnalysisError
         from ..analysis.memory import (plan_memory, plan_digest,
-                                       device_memory_budget,
-                                       format_bytes, shard_divisor)
+                                       device_memory_budget, format_bytes)
         from ..symbol import Symbol as _Symbol
         try:
-            n = self.num_slots
             spec = self._sharding_spec
 
-            def price_step(sym_, infos, a_params, x_params):
-                arg_names = set(sym_.list_arguments())
-                shapes = {token_name: (n,)}
-                donate, names = {}, []
-                for i, info in enumerate(infos):
-                    shapes[info["name"]] = (n,) + tuple(info["shape"])
-                    names.append(info["name"])
-                    donate[info["name"]] = 1 + i
-                for extra in (pos_name, valid_name):
-                    if extra in arg_names:
-                        shapes[extra] = (n,)
-                # the host vectors are float32 whatever the pool is;
-                # a state has the dtype its buffer has
-                dtypes = {k: np.dtype(np.float32) for k in shapes}
-                for info in infos:
-                    dtypes[info["name"]] = np.dtype(info.get("dtype")
-                                                    or self._dtype)
+            def price_step(sym_, which, a_params, x_params):
+                grid = self._layout.grid(sym_, token_name, pos_name,
+                                         valid_name, which)
+                dtypes = dict(grid.dtypes)
                 for src in (a_params or {}), (x_params or {}):
                     for k, v in src.items():
                         dt = getattr(v, "dtype", None)
                         if dt is not None:
                             dtypes.setdefault(k, np.dtype(dt))
-                plan, _rep = plan_memory(sym_, shapes, dtypes=dtypes,
-                                         sharding=spec, donate=donate,
-                                         state_names=names)
+                plan, _rep = plan_memory(sym_, grid.shapes, dtypes=dtypes,
+                                         sharding=spec, donate=grid.donate,
+                                         state_names=grid.state_names)
                 return plan
 
-            plan = price_step(step_sym, state_info, arg_params,
-                              aux_params)
+            plan = price_step(step_sym, "target", arg_params, aux_params)
             if not plan:
                 return
             dplan = None
             if self._spec_k and draft_sym is not None:
-                dplan = price_step(draft_sym, draft_state_info or [],
-                                   draft_arg_params, draft_aux_params)
-            # the slot pool the step's inputs already include —
-            # (num_slots,) + state shape per state, divided along plan
-            # state rules — stays resident under prefill too
-            pool = 0
-            for info in state_info:
-                shp = (n,) + tuple(info["shape"])
-                nbytes = int(np.prod(shp)) * np.dtype(
-                    info.get("dtype") or self._dtype).itemsize
-                pool += nbytes // shard_divisor(spec, info["name"],
-                                                shp, kind="state")
-            per_slot = pool // n
+                dplan = price_step(draft_sym, "draft", draft_arg_params,
+                                   draft_aux_params)
+            # the target's pool, which the step's inputs already
+            # include, stays resident under prefill too
+            pool = self._layout.pool_bytes(spec)
+            per_slot = self._layout.slot_bytes(spec)
 
             def row(label, p):
                 return {"program": label,
@@ -2385,7 +2278,7 @@ class DecodeEngine(object):
                        "MXNET_MEMORY_BUDGET_BYTES (priced before any "
                        "compile)"
                        % (offender, format_bytes(need),
-                          format_bytes(pool), n,
+                          format_bytes(pool), self.num_slots,
                           format_bytes(plan["param_bytes"]),
                           format_bytes(budget),
                           (" (at most %d slots fit)" % fit
@@ -2401,28 +2294,20 @@ class DecodeEngine(object):
                           "(%r); continuing without a memory plan"
                           % (e,))
 
-    def _check_draft_heads(self, step_sym, draft_sym, state_info,
-                           draft_state_info, token_name, pos_name,
-                           valid_name):
+    def _check_draft_heads(self, step_sym, draft_sym, token_name,
+                           pos_name, valid_name):
         """Draft-compatibility contract: the two heads must score the
         SAME vocabulary — acceptance compares the draft's proposal
         against the target's distribution index-for-index, so a vocab
         (or logits-rank) mismatch produces garbage comparisons, not an
         error, and must be refused at construction."""
-        def logits_shape(sym_, infos):
-            n = self.num_slots
-            arg_names = set(sym_.list_arguments())
-            shapes = {token_name: (n,)}
-            for info in infos:
-                shapes[info["name"]] = (n,) + tuple(info["shape"])
-            for extra in (pos_name, valid_name):
-                if extra in arg_names:
-                    shapes[extra] = (n,)
-            _a, out, _x = sym_.infer_shape(**shapes)
+        def logits_shape(sym_, which):
+            _a, out, _x = sym_.infer_shape(**self._layout.grid(
+                sym_, token_name, pos_name, valid_name, which).shapes)
             return tuple(out[0])
         try:
-            t_shape = logits_shape(step_sym, state_info)
-            d_shape = logits_shape(draft_sym, draft_state_info)
+            t_shape = logits_shape(step_sym, "target")
+            d_shape = logits_shape(draft_sym, "draft")
         except Exception as e:
             warnings.warn("DecodeEngine: cannot infer draft/target "
                           "head shapes (%r); the head-compatibility "
@@ -2435,7 +2320,7 @@ class DecodeEngine(object):
                 "one vocabulary (and logits layout) for acceptance "
                 "to compare them" % (t_shape, d_shape))
 
-    def _optimize_step(self, step_sym, state_info, token_name, pos_name,
+    def _optimize_step(self, step_sym, which, token_name, pos_name,
                        valid_name, what="step"):
         """Run the kernel-selection optimizer pipeline
         (``analysis.SELECT_OPT_PASSES``) over the step graph under the
@@ -2448,26 +2333,14 @@ class DecodeEngine(object):
         rejection or crash)."""
         from ..analysis import optimize_graph, SELECT_OPT_PASSES
         try:
-            n = self.num_slots
-            arg_names = set(step_sym.list_arguments())
-            shapes = {token_name: (n,)}
-            dtypes = {token_name: np.dtype(np.float32)}
-            state_names = []
-            for info in state_info:
-                shapes[info["name"]] = (n,) + tuple(info["shape"])
-                dtypes[info["name"]] = np.dtype(info.get("dtype")
-                                                or self._dtype)
-                state_names.append(info["name"])
-            for extra in (pos_name, valid_name):
-                if extra in arg_names:
-                    shapes[extra] = (n,)
-                    dtypes[extra] = np.dtype(np.float32)
+            grid = self._layout.grid(step_sym, token_name, pos_name,
+                                     valid_name, which)
             plan = optimize_graph(
-                step_sym, data_shapes=shapes, dtypes=dtypes,
-                pad_axes={"slot": {name: 0 for name in shapes}},
+                step_sym, data_shapes=grid.shapes, dtypes=grid.dtypes,
+                pad_axes={"slot": {name: 0 for name in grid.shapes}},
                 valid_lengths=({"slot": valid_name}
-                               if valid_name in arg_names else None),
-                pad_dirty=tuple(state_names),
+                               if valid_name in grid.shapes else None),
+                pad_dirty=tuple(grid.state_names),
                 passes=SELECT_OPT_PASSES)
         except Exception as e:    # optimizer crash must never block
             warnings.warn("DecodeEngine: %s-graph optimization "
@@ -3183,7 +3056,8 @@ class DecodeEngine(object):
                                  >= len(req.prompt) else 0.0)
         for req in seated:
             if req.slot is not None and rep.slots[req.slot] is req:
-                self._check_finish(rep, req.slot)
+                for slot, reason in self._ended(rep, req.slot):
+                    self._finish_slot(rep, slot, reason)
 
     def _seat_slot(self, rep, req):
         """Claim a free slot for one admitted request; False when the
@@ -3470,14 +3344,15 @@ class DecodeEngine(object):
             if sp.args is None:
                 sp.drop()       # no step read: its event comes with it
 
-    def _booked(self, rep, live, new_tokens, t0, ahead=0, discarded=0):
+    def _booked(self, rep, live, new_tokens, t0, ahead=0, discarded=0,
+                leaving=()):
         """Book one scheduler iteration begun at ``t0`` (``stats()``
         and the scraped series): the step it dispatched, over ``live``
         slots (none: it only read the step in flight), and what the
-        step it read delivered.  Called by the step's body while its
-        host arrays are alive: dropping the view of the sampled ids
-        frees a device buffer, which lets a caller woken by its last
-        token run, and it may read ``stats()`` straight away."""
+        step it read delivered.  Only then do the requests that ended
+        with that step leave (``leaving``: ``(slot, reason)``): a
+        caller woken by its last token may read ``stats()`` straight
+        away, and finds the token counted."""
         dt_ms = (time.perf_counter() - t0) * 1e3
         with self._lock:
             self._tokens_out += new_tokens
@@ -3489,17 +3364,18 @@ class DecodeEngine(object):
         if self._tm is not None:
             if new_tokens:
                 self._tm.tokens.inc(new_tokens)
-            if not live:
-                return
-            self._tm.steps.inc()
-            rep.tm_step_ms.observe(dt_ms)
-            # slot-occupancy split of this dispatch (ISSUE 18
-            # satellite): the persistent step computed num_slots rows
-            # whatever the occupancy — scraped, not inferred
-            self._tm.slot_steps_live.inc(live)
-            dead = self.num_slots - live
-            if dead:
-                self._tm.slot_steps_dead.inc(dead)
+            if live:
+                self._tm.steps.inc()
+                rep.tm_step_ms.observe(dt_ms)
+                # slot-occupancy split of this dispatch (ISSUE 18
+                # satellite): the persistent step computed num_slots
+                # rows whatever the occupancy — scraped, not inferred
+                self._tm.slot_steps_live.inc(live)
+                dead = self.num_slots - live
+                if dead:
+                    self._tm.slot_steps_dead.inc(dead)
+        for slot, reason in leaving:
+            self._finish_slot(rep, slot, reason)
 
     def _step_args(self, live, tokens, dispatch_s, read_s, ahead=0,
                    discarded=0, extras=()):
@@ -3585,9 +3461,10 @@ class DecodeEngine(object):
                         len(occ), self.num_slots, committed,
                         self._spec_k + 1))
             with sp.child("decode.step.deliver"):
-                new_tokens = self._advance_spec(rep, occ, toks_mat,
-                                                counts)
-            self._booked(rep, len(occ), new_tokens, t0)
+                new_tokens, leaving = self._advance_spec(
+                    rep, occ, toks_mat, counts)
+                self._booked(rep, len(occ), new_tokens, t0,
+                             leaving=leaving)
             return self._step_args(len(occ), new_tokens,
                                    *(rep.program.last_split or (0, 0)))
         if occ:
@@ -3616,9 +3493,9 @@ class DecodeEngine(object):
         before, seats, ahead = read
         ids = before.read()
         with sp.child("decode.step.deliver"):
-            new_tokens, discarded = self._deliver(rep, seats, ids)
-        self._booked(rep, len(occ), new_tokens, t0, int(bool(occ)),
-                     discarded)
+            new_tokens, discarded, leaving = self._deliver(rep, seats, ids)
+            self._booked(rep, len(occ), new_tokens, t0, int(bool(occ)),
+                         discarded, leaving)
         return self._step_args(len(seats), new_tokens, before.dispatch_s,
                                before.read_s, ahead, discarded,
                                before.extras)
@@ -3666,7 +3543,8 @@ class DecodeEngine(object):
         now: a request that has left since (deadline, eos one step
         back, a raising callback, a failure), its slot free or seated
         anew, gets nothing, and the slot-step counts as discarded.
-        Returns ``(new tokens, discarded slot-steps)``."""
+        Returns ``(new tokens, discarded slot-steps, leaving)``: who
+        ended with this step leaves once it is booked (``_booked``)."""
         # one C-level conversion instead of num_slots ndarray-scalar
         # __getitem__ calls: the slot loop below is the scheduler's
         # per-step GIL cost, and with replica routing two of these
@@ -3674,6 +3552,7 @@ class DecodeEngine(object):
         ids_l = ids.tolist()
         eos = self.eos_id
         new_tokens = discarded = 0
+        leaving = []
         t_tok = time.perf_counter()     # one stamp serves every slot
         for i, req, kind in seats:
             if rep.slots[i] is not req:
@@ -3694,10 +3573,10 @@ class DecodeEngine(object):
                     and not self._fire_on_token(rep, req, tok):
                 continue        # evicted by its own callback
             if eos is not None and tok == eos:
-                self._finish_slot(rep, i, "eos")
+                leaving.append((i, "eos"))
             elif kind == 2:
-                self._finish_slot(rep, i, "length")
-        return new_tokens, discarded
+                leaving.append((i, "length"))
+        return new_tokens, discarded, leaving
 
     def _settle(self, rep):
         """Read and deliver the step in flight, if there is one: what
@@ -3713,9 +3592,9 @@ class DecodeEngine(object):
             ids = read[0].read()
         except Exception:
             return
-        new_tokens, discarded = self._deliver(rep, read[1], ids)
+        new_tokens, discarded, leaving = self._deliver(rep, read[1], ids)
         self._booked(rep, 0, new_tokens, time.perf_counter(),
-                     discarded=discarded)
+                     discarded=discarded, leaving=leaving)
 
     def _ledger_step(self, rep, occ, useful):
         """Spread one step dispatch's useful FLOPs over the live slots
@@ -3740,10 +3619,12 @@ class DecodeEngine(object):
         max_len (a truncated slot always FINISHES, so positions the
         program committed past the truncation point free with the
         slot); ``on_token`` and the SSE stream fire once per accepted
-        token, in order, exactly like the single-token loop."""
+        token, in order, exactly like the single-token loop.  Returns
+        ``(new tokens, leaving)``, as :meth:`_deliver` does."""
         toks_l = toks_mat.tolist()
         counts_l = counts.tolist()
         new_tokens = 0
+        leaving = []
         drafted = accepted = spec_slots = 0
         t_tok = time.perf_counter()
         for i in occ:
@@ -3758,7 +3639,7 @@ class DecodeEngine(object):
                 req.prompt_i += 1
                 if req.prompt_i >= len(req.prompt):
                     rep.spec_np[i] = 1.0
-                self._check_finish(rep, i)
+                leaving += self._ended(rep, i)
                 continue
             c = int(counts_l[i])
             spec_slots += 1
@@ -3788,7 +3669,7 @@ class DecodeEngine(object):
                 continue
             if last is not None:
                 rep.tokens_np[i] = float(last)
-            self._check_finish(rep, i)
+            leaving += self._ended(rep, i)
         if spec_slots:
             with self._lock:
                 self._spec_steps += 1
@@ -3803,20 +3684,22 @@ class DecodeEngine(object):
                 if drafted:
                     self._tm.spec_accept.observe(accepted
                                                  / float(drafted))
-        return new_tokens
+        return new_tokens, leaving
 
-    def _check_finish(self, rep, slot):
+    def _ended(self, rep, slot):
+        """``[(slot, reason)]`` where the slot's request has ended with
+        the tokens it holds, ``[]`` where it goes on."""
         req = rep.slots[slot]
         if req is None or not req.tokens:
-            return
+            return []
         if self.eos_id is not None and req.tokens[-1] == self.eos_id:
-            self._finish_slot(rep, slot, "eos")
-        elif len(req.tokens) >= req.max_new:
-            self._finish_slot(rep, slot, "length")
-        elif rep.pos_np[slot] >= self.max_len:
-            # no position left to consume the staged token at: the
-            # fixed O(1) cache layout is full
-            self._finish_slot(rep, slot, "length")
+            return [(slot, "eos")]
+        # at ``max_len`` no position is left to consume the staged
+        # token at: the fixed O(1) cache layout is full
+        if len(req.tokens) >= req.max_new \
+                or rep.pos_np[slot] >= self.max_len:
+            return [(slot, "length")]
+        return []
 
     def _finish_slot(self, rep, slot, reason):
         """Leave the batch between steps: deliver the result, mark the
@@ -3944,14 +3827,10 @@ class DecodeEngine(object):
             else:
                 _, states = prog.step(z, z, z, states)
             keep(states)
-        rows = {}
         # ALL states — the prefill path also scatters draft rows
         # (zero_row which="draft") into STEPPED buffers, and their
         # per-sharding row kernels must be warm too
-        for key, info in prog._state_infos():
-            dt = np.dtype(info.get("dtype") or prog._dtype)
-            rows[key] = np.zeros(tuple(info["shape"]), dt)
-        states = keep(prog.write_row(states, 0, rows))
+        states = keep(prog.zero_row(states, 0))
         for b in rep.prefill_buckets:
             # the full (batch, prompt) bucket grid: coalesced prefill
             # dispatches at pow2 BATCH extents too, and every shape
@@ -4062,9 +3941,7 @@ class DecodeEngine(object):
                 "prefill_programs": sum(
                     len(self._prefill_grid[b])
                     for b in self._prefill_buckets),
-                "state_rows": {info["name"]: int(info["shape"][0])
-                               for info in self._program.state_info
-                               if info.get("cache")},
+                "state_rows": dict(self._program.layout.cache_rows()),
                 "prefill_dispatches": self._prefill_dispatches,
                 "optimizer": {
                     "accepted": (bool(self.opt_plan.accepted)

@@ -39,17 +39,9 @@ materializes K full candidates, so declare your KV caches.
 """
 from __future__ import annotations
 
-import numpy as np
-
 from ..base import MXNetError
 
 __all__ = ["SpecConfig", "build_commit_sym"]
-
-
-def _draft_key(name):
-    """Engine-side key for a draft state buffer in the merged per-slot
-    state dict (draft and target state names may collide)."""
-    return "draft:" + name
 
 
 def build_commit_sym(cache_specs, K):
@@ -171,23 +163,11 @@ class SpecConfig(object):
         self._built = False
 
     # ------------------------------------------------------------------
-    def draft_state_names(self):
-        return [s["name"] for s in self.draft_state_info]
-
-    def draft_keys(self):
-        return [_draft_key(s["name"]) for s in self.draft_state_info]
-
-    def cache_infos(self, state_info):
-        """(key, info) pairs of the CACHE-declared states across both
-        models: target states under their own names, draft states
-        under their prefixed engine keys."""
-        out = [(s["name"], s) for s in state_info if s.get("cache")]
-        out += [(_draft_key(s["name"]), s)
-                for s in self.draft_state_info if s.get("cache")]
-        return out
-
-    def build(self, num_slots, state_info, dtype):
-        """Build + verdict-gate the commit graph once (idempotent).
+    def build(self, layout):
+        """Build + verdict-gate the commit graph once (idempotent) over
+        the cache states of both models in ``layout`` (a
+        :class:`~.slot_state.SlotLayout`: target states under their own
+        names, draft states under their prefixed pool keys).
 
         The selection outcome (``_cache_write_rows`` adopted or the
         blend chain served with a reason) is recorded on
@@ -199,11 +179,8 @@ class SpecConfig(object):
             return self
         from .aot_cache import graph_digest
         self.draft_digest = graph_digest(self.draft_sym)
-        specs = []
-        for key, info in self.cache_infos(state_info):
-            dt = np.dtype(info.get("dtype") or dtype)
-            shape = (int(num_slots),) + tuple(info["shape"])
-            specs.append((key, shape, dt))
+        specs = [(s.key, layout.pool_shape(s), s.dtype)
+                 for s in layout.caches()]
         if not specs:
             self._built = True
             return self

@@ -109,24 +109,13 @@ def price_graph(symbol, data_shapes, dtypes=None, label_names=None):
         return None
 
 
-def _price_step_sym(symbol, token_name, pos_name, valid_name,
-                    state_info, num_slots, host_dtype):
-    """Price one step-graph execution at slot-pool shapes — the same
-    shape grid the memory preflight and the compiled program use:
-    token/pos/valid are ``(num_slots,)`` host vectors, each state is
-    ``(num_slots,) + state shape``."""
-    arg_names = set(symbol.list_arguments())
-    shapes, dtypes = {}, {}
-    for extra in (token_name, pos_name, valid_name):
-        if extra and extra in arg_names:
-            shapes[extra] = (num_slots,)
-            dtypes[extra] = host_dtype
-    for info in state_info:
-        name = info["name"]
-        if name in arg_names:
-            shapes[name] = (num_slots,) + tuple(info["shape"])
-            dtypes[name] = info.get("dtype", host_dtype)
-    return price_graph(symbol, shapes, dtypes=dtypes)
+def _price_step_sym(symbol, token_name, pos_name, valid_name, layout,
+                    which):
+    """Price one step-graph execution at slot-pool shapes — the grid
+    the memory preflight and the compiled program use
+    (``serving/slot_state.py`` ``SlotLayout.grid``)."""
+    grid = layout.grid(symbol, token_name, pos_name, valid_name, which)
+    return price_graph(symbol, grid.shapes, dtypes=grid.dtypes)
 
 
 def price_step_program(program):
@@ -149,8 +138,7 @@ def price_step_program(program):
     try:
         target = _price_step_sym(
             program._serve_sym, program.token_name, program.pos_name,
-            program.valid_name, program.state_info, program.num_slots,
-            program._dtype)
+            program.valid_name, program.layout, "target")
         spec = getattr(program, "_spec", None)
         if spec is None:
             price = target
@@ -158,8 +146,7 @@ def price_step_program(program):
             from .. import symbol as sym
             draft = _price_step_sym(
                 sym.Group(list(spec.draft_sym)), spec.token_name,
-                spec.pos_name, spec.valid_name, spec.draft_state_info,
-                program.num_slots, program._dtype)
+                spec.pos_name, spec.valid_name, program.layout, "draft")
             if draft is not None:
                 price = spec.K * (target + draft)
     except Exception:

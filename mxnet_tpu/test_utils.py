@@ -211,6 +211,10 @@ def check_op_gradient(op_name, attrs, inputs, wrt=None, eps=1e-5,
         scalar_f = _scalarize(f, proj)
         grads = jax.grad(scalar_f, argnums=tuple(wrt))(
             *[jnp.asarray(x) for x in xs])
+        # the differences below call it twice an element: one compiled
+        # program, not the op's eager dispatches each time (a fused unit
+        # in interpret mode took 490 s of its 212 elements that way)
+        fd_f = jax.jit(scalar_f)
         for gi, i in enumerate(wrt):
             x0 = xs[i]
             num = np.zeros_like(x0, dtype=np.float64)
@@ -220,9 +224,9 @@ def check_op_gradient(op_name, attrs, inputs, wrt=None, eps=1e-5,
                 h = eps * max(1.0, abs(flat[j]))
                 orig = flat[j]
                 flat[j] = orig + h
-                fp = float(scalar_f(*[jnp.asarray(x) for x in xs]))
+                fp = float(fd_f(*[jnp.asarray(x) for x in xs]))
                 flat[j] = orig - h
-                fm = float(scalar_f(*[jnp.asarray(x) for x in xs]))
+                fm = float(fd_f(*[jnp.asarray(x) for x in xs]))
                 flat[j] = orig
                 nflat[j] = (fp - fm) / (2 * h)
             assert_almost_equal(np.asarray(grads[gi], np.float64), num,

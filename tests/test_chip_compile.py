@@ -315,7 +315,7 @@ def test_smallthinker_prefill_parts_compile_for_v5e(one_chip, case):
     import jax.numpy as jnp
     from mxnet_tpu.models import smallthinker
     from mxnet_tpu.ops.registry import get_op
-    from mxnet_tpu.serving.decode import _lay_rows
+    from mxnet_tpu.serving.slot_state import SlotLayout
     bf = jnp.bfloat16
 
     def sds(*shape, dtype=bf):
@@ -337,9 +337,7 @@ def test_smallthinker_prefill_parts_compile_for_v5e(one_chip, case):
     else:
         info = smallthinker.state_info(_smallthinker_cfg(), 12288)
 
-        def fn(bufs, rows, slots, lens):
-            return [_lay_rows(b, r, i, slots, lens)
-                    for b, r, i in zip(bufs, rows, info)]
+        fn = SlotLayout(info, 32, bf).lay_prefill
         args = ([sds(32, *i["shape"]) for i in info],
                 [sds(1, 8192, 512) for _ in info],
                 sds(1, dtype=jnp.int32), sds(1, dtype=jnp.int32))

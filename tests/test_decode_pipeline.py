@@ -202,6 +202,43 @@ def test_mixed_pool_equals_greedy_decode(builder, with_eos):
         assert st["slot_steps_discarded"] == 0
 
 
+def _spec_pair():
+    tstep, tparams, tinfo = _attn_step(seed=0)
+    dstep, dparams, dinfo = _attn_step(seed=1)
+    for i in tinfo + dinfo:
+        i["cache"] = True
+    return (tstep, tparams, tinfo), dict(
+        draft_sym=dstep, draft_arg_params=dparams, draft_state_info=dinfo,
+        spec_k=2)
+
+
+@pytest.mark.parametrize("speculative", [False, True],
+                         ids=["plain", "speculative"])
+def test_a_step_is_booked_before_its_futures_resolve(speculative):
+    """A future's done-callback runs in the scheduler's thread, at the
+    resolve: ``stats()`` read there counts every token delivered so far,
+    the resolving request's last one among them.  (A client woken by its
+    last token reads ``stats()`` at once, on its own thread.)"""
+    if speculative:
+        (step, params, info), kw = _spec_pair()
+        eng, rep = _manual(lambda: (step, params, info), num_slots=3, **kw)
+    else:
+        eng, rep = _manual(num_slots=3)
+    delivered, at_resolve = [], []
+
+    def resolved(fut):
+        at_resolve.append((eng.stats()["decode"]["tokens_generated"],
+                           len(delivered), len(fut.result().tokens)))
+    for p, m in (([1, 2, 3], 4), ([5], 2), ([7, 7], 5), ([9], 3)):
+        eng.submit(p, max_new_tokens=m, on_token=delivered.append) \
+            .add_done_callback(resolved)
+    _run_dry(eng, rep)
+    eng.close()
+    assert sorted(own for _b, _d, own in at_resolve) == [2, 3, 4, 5]
+    for booked, so_far, own in at_resolve:
+        assert booked == so_far >= own
+
+
 def test_clients_on_many_threads_get_greedy_answers():
     """Submits, callbacks and the scheduler's two walks interleave at a
     short switch interval: every request still gets ``greedy_decode``'s
@@ -524,14 +561,9 @@ def _step_events(base):
 def test_ahead_is_zero_on_a_speculative_engine_and_not_on_a_plain_one():
     telemetry.set_enabled(True)
     try:
-        tstep, tparams, tinfo = _attn_step(seed=0)
-        dstep, dparams, dinfo = _attn_step(seed=1)
-        for i in tinfo + dinfo:
-            i["cache"] = True
+        (tstep, tparams, tinfo), draft = _spec_pair()
         spec = DecodeEngine(tstep, tparams, {}, tinfo, num_slots=2,
-                            max_len=MAX_LEN, default_deadline_ms=0,
-                            draft_sym=dstep, draft_arg_params=dparams,
-                            draft_state_info=dinfo, spec_k=2)
+                            max_len=MAX_LEN, default_deadline_ms=0, **draft)
         plain = DecodeEngine(tstep, tparams, {}, tinfo, num_slots=2,
                              max_len=MAX_LEN, default_deadline_ms=0)
         got = {}

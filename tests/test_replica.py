@@ -320,23 +320,23 @@ def test_decode_replica_failover_partial_output(tmp_path, monkeypatch):
                        ctx=[mx.cpu(0), mx.cpu(0)])
     eng.warmup()
     # one slot per replica: the router seats request 1 on replica 0,
-    # request 2 on replica 1 (most-free, index-tied)
-    f1 = eng.submit([1], max_new_tokens=30)
-    f2 = eng.submit([2], max_new_tokens=30)
-    deadline = time.monotonic() + 30
-    while time.monotonic() < deadline:
-        if all(r.occupied_count() == 1 for r in eng._replicas):
-            break
-        time.sleep(0.002)
-    assert all(r.occupied_count() == 1 for r in eng._replicas)
-    victim = eng._replicas[0].slots[0]
-    assert victim is not None
+    # request 2 on replica 1 (most-free, index-tied).  Replica 0's step
+    # fails at its tenth call, so its request has a few tokens by then
+    # whatever the host's load (looking for the moment both replicas
+    # were seated lost to a 30-token request on a busy host)
+    inner = eng._replicas[0].program.step
+    calls = []
 
     def bad_step(tokens, pos, valid, states, reset=None):
-        raise RuntimeError("induced step failure")
+        calls.append(1)
+        if len(calls) >= 10:
+            raise RuntimeError("induced step failure")
+        return inner(tokens, pos, valid, states, reset=reset)
+    eng._replicas[0].program.step = bad_step
     with warnings.catch_warnings():
         warnings.simplefilter("ignore")
-        eng._replicas[0].program.step = bad_step
+        f1 = eng.submit([1], max_new_tokens=30)
+        f2 = eng.submit([2], max_new_tokens=30)
         r1 = f1.result(timeout=120)
         r2 = f2.result(timeout=120)
     # the victim: partial output, eviction reason, not an exception
