@@ -36,7 +36,6 @@ class ShapeDtypePass(AnalysisPass):
     name = "shapes"
 
     def run(self, ctx, report):
-        import jax
         view = ctx.ensure_view()
         f32 = _np.dtype(_np.float32)
         shapes, dtypes = ctx.shapes, ctx.node_dtypes
@@ -75,6 +74,7 @@ class ShapeDtypePass(AnalysisPass):
 
         # -- forward fixed point ------------------------------------------
         failed = set()      # nodes already diagnosed: report each once
+        inferred = {}       # (op, attrs, input shapes, dtypes) -> outputs
         max_passes = max(3, len(view.topo))
         for _ in range(max_passes):
             progressed = False
@@ -107,21 +107,25 @@ class ShapeDtypePass(AnalysisPass):
                     in_shapes = [shapes.get(k) for k in in_keys]
                 if any(s is None for s in in_shapes):
                     continue        # blocked; maybe a later sweep fills it
+                # a graph of repeated layers asks the same question once
+                # a layer (and tracing a Pallas kernel for its shape is
+                # not free): an answer is kept for the rest of this run
+                sig = (n.op.name, n.op._freeze(attrs, ctx.training),
+                       tuple(map(tuple, in_shapes)),
+                       tuple(str(d) for d in in_dtypes))
                 try:
-                    structs = [jax.ShapeDtypeStruct(tuple(s), d)
-                               for s, d in zip(in_shapes, in_dtypes)]
-                    if n.op.stochastic:
-                        key = jax.ShapeDtypeStruct((2,), _np.uint32)
-                        out = jax.eval_shape(
-                            lambda k, *ins: n.op.bound(attrs, ctx.training)(
-                                jax.random.wrap_key_data(k), *ins),
-                            key, *structs)
-                    else:
-                        out = jax.eval_shape(n.op.bound(attrs, ctx.training),
-                                             *structs)
-                except Exception as e:
-                    self._fail(ctx, report, failed, n, in_shapes, e)
-                    continue
+                    out = inferred.get(sig)
+                except TypeError:       # an attribute that does not hash
+                    sig = out = None
+                if out is None:
+                    try:
+                        out = self._eval_shape(ctx, n, attrs, in_shapes,
+                                               in_dtypes)
+                    except Exception as e:
+                        self._fail(ctx, report, failed, n, in_shapes, e)
+                        continue
+                    if sig is not None:
+                        inferred[sig] = out
                 for i, o in enumerate(out):
                     shapes[(id(n), i)] = tuple(o.shape)
                     dtypes[(id(n), i)] = _np.dtype(o.dtype)
@@ -133,6 +137,19 @@ class ShapeDtypePass(AnalysisPass):
         self._report_blocked(ctx, report, view, shapes, failed)
 
     # ------------------------------------------------------------------
+    @staticmethod
+    def _eval_shape(ctx, n, attrs, in_shapes, in_dtypes):
+        import jax
+        structs = [jax.ShapeDtypeStruct(tuple(s), d)
+                   for s, d in zip(in_shapes, in_dtypes)]
+        if n.op.stochastic:
+            key = jax.ShapeDtypeStruct((2,), _np.uint32)
+            return jax.eval_shape(
+                lambda k, *ins: n.op.bound(attrs, ctx.training)(
+                    jax.random.wrap_key_data(k), *ins),
+                key, *structs)
+        return jax.eval_shape(n.op.bound(attrs, ctx.training), *structs)
+
     @staticmethod
     def _nout(n):
         try:

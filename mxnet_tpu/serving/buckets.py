@@ -246,6 +246,30 @@ class ProgramCache(object):
         unpriced, or priced before the efficiency plane was on."""
         return self.flops_by_key.get(shape_key)
 
+    def node_inputs(self, op_name, data_shapes):
+        """``(attrs, input shapes, input dtypes)`` of every ``op_name``
+        node of the program at these data shapes, in graph order: what
+        such a node can observe of its inputs when the program is
+        built (the shapes pass over this cache's graph and its
+        parameters' dtypes; a node whose inputs stayed unresolved is
+        left out)."""
+        from ..analysis import analyze
+        _report, ctx = analyze(
+            self._sym, data_shapes=dict(data_shapes),
+            dtypes={n: np.dtype(a.dtype)
+                    for n, a in self._params.items()},
+            passes=("shapes",))
+        out = []
+        for node in ctx.ensure_view().op_nodes():
+            if node.op.name != op_name:
+                continue
+            keys = [(id(i), ix) for (i, ix) in node.inputs]
+            if all(k in ctx.shapes and k in ctx.node_dtypes for k in keys):
+                out.append((node.op.normalize(node.attrs),
+                            [ctx.shapes[k] for k in keys],
+                            [ctx.node_dtypes[k] for k in keys]))
+        return out
+
     def _plan_for(self, shape_key, data_specs):
         """Prefilled flat-input list + kernel + rng key for one bucket
         signature: everything per-dispatch work can reuse verbatim.

@@ -1742,6 +1742,12 @@ class DecodeEngine(object):
         # direct TTFT lever at concurrency (decode_bench --prefill)
         self._coalesce = bool(config.get("MXNET_DECODE_COALESCE_PREFILL"))
         self._prefill_dispatches = 0
+        # (attention nodes that took the fused kernel, attention
+        # nodes) of each (bucket, batch) prefill program, and their
+        # sums over the prefill dispatches
+        self._prefill_fused = {}
+        self._prefill_fused_attention = 0
+        self._prefill_attention_nodes = 0
         # device replicas (serving/replica.py, ROADMAP 2a): each owns a
         # FULL slot pool — persistent step program + device-resident
         # state + prefill bucket caches, params uploaded once per
@@ -3139,6 +3145,7 @@ class DecodeEngine(object):
             plen = len(req.prompt)
             arr[r_i, :plen] = req.prompt
             lens[r_i] = plen
+        fused, attn_nodes = self._fused_attention(rep, bucket, bb)
         t_pf0 = time.perf_counter()
         ann = (self._tl.annotate("decode.prefill") if self._tl is not None
                else _telemetry.timeline.NO_SPAN)
@@ -3149,6 +3156,8 @@ class DecodeEngine(object):
                     self._prefill_len_name: lens})
                 with self._lock:
                     self._prefill_dispatches += 1
+                    self._prefill_fused_attention += fused
+                    self._prefill_attention_nodes += attn_nodes
                 on_device = self._rows_stay_on_device(rep)
                 if on_device:
                     # dead rows of the padded batch take row 0's slot
@@ -3184,7 +3193,9 @@ class DecodeEngine(object):
                               time.perf_counter(),
                               args={"bucket": bucket, "group": len(live),
                                     "tokens": live_elems,
-                                    "padded": padded_elems})
+                                    "padded": padded_elems,
+                                    "fused_attention": fused,
+                                    "attention_nodes": attn_nodes})
         if self._eff is not None:
             shape_key = tuple(sorted(
                 (k, v.shape)
@@ -3203,6 +3214,28 @@ class DecodeEngine(object):
                 name: rows_all[i][r_i]
                 for i, name in enumerate(rep.program.state_names)}
             self._commit_prefill(rep, req, rows, first[r_i])
+
+    def _fused_attention(self, rep, bucket, bb):
+        """How many ``_gqa_prefill`` nodes of the ``(bb, bucket)``
+        prefill program take the fused kernel, and how many it has:
+        the op's own predicate (``ops/transformer.py``
+        ``prefill_takes_kernel``) over what each node observes of its
+        inputs, asked once a shape (at ``warmup()`` for the warm grid,
+        so a dispatch only looks it up)."""
+        n = self._prefill_fused.get((bucket, bb))
+        if n is None:
+            from ..ops.transformer import prefill_takes_kernel
+            try:
+                nodes = rep.prefill_caches[bucket].node_inputs(
+                    "_gqa_prefill",
+                    {self._prefill_data_name: (bb, bucket),
+                     self._prefill_len_name: (bb,)})
+            except Exception:       # a count must never fail a dispatch
+                nodes = []
+            n = (sum(1 for node in nodes if prefill_takes_kernel(*node)),
+                 len(nodes))
+            self._prefill_fused[(bucket, bb)] = n
+        return n
 
     def _rows_stay_on_device(self, rep):
         """Whether a prefill's state rows are laid into the pool by
@@ -3838,6 +3871,7 @@ class DecodeEngine(object):
             # retrace contract would leak through the coalesced path
             # within the token budget a dispatch (``_prefill_grid``)
             for bb in self._prefill_grid.get(b, self._prefill_batches):
+                self._fused_attention(rep, b, bb)
                 outs = rep.prefill_caches[b].dispatch({
                     self._prefill_data_name: np.zeros((bb, b),
                                                       np.float32),
@@ -3943,6 +3977,10 @@ class DecodeEngine(object):
                     for b in self._prefill_buckets),
                 "state_rows": dict(self._program.layout.cache_rows()),
                 "prefill_dispatches": self._prefill_dispatches,
+                # attention nodes of those dispatches' programs, and
+                # those that took the fused kernel (ops/transformer.py)
+                "prefill_attention_nodes": self._prefill_attention_nodes,
+                "prefill_fused_attention": self._prefill_fused_attention,
                 "optimizer": {
                     "accepted": (bool(self.opt_plan.accepted)
                                  if self.opt_plan is not None else None),

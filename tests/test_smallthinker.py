@@ -150,6 +150,61 @@ def test_prefill_attention_op(ref, window):
     _close(got, want)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("window", [0, 200], ids=["global", "window"])
+def test_fused_prefill_attention_kernel(ref, window, dtype):
+    """The Pallas kernel in interpret mode against the blockwise path
+    and the plain reference: a group of 7 query heads of 128 over each
+    of 2 key/value heads, 512 positions in query blocks of 128 and key
+    blocks of 256 (so a block is skipped, one is cut by the causal edge
+    and, under the window of 200, one by the band's start), batch 2.
+    float32 to ``TOL``; in bfloat16 the three round the softmax weights
+    at different scales, so they agree to an ulp of the largest result."""
+    from mxnet_tpu.ops import transformer as tf
+    rng = np.random.default_rng(11)
+    b, t, h, kv, d = 2, 512, 14, 2, 128
+    q, k, v = (jnp.asarray(rng.standard_normal((b, t, n * d)), dtype)
+               for n in (h, kv, kv))
+    got = tf.gqa_prefill_fused(q, k, v, num_heads=h, num_kv_heads=kv,
+                               window=window, block_q=128, block_k=256,
+                               interpret=True)
+    assert got.dtype == q.dtype and got.shape == q.shape
+    with jax.default_matmul_precision("highest"):
+        blockwise = tf.gqa_prefill_blockwise(
+            q, k, v, num_heads=h, num_kv_heads=kv, window=window, block=128)
+        want = np.stack([np.asarray(ref.attention(
+            q[i], k[i], v[i], h, kv, window, q_block=128), np.float32)
+            for i in range(b)])
+    tol = TOL if dtype == "float32" else 2.0 ** -7      # a bfloat16 ulp
+    for other in (np.asarray(blockwise, np.float32), want):
+        assert np.abs(np.asarray(got, np.float32) - other).max() \
+            <= tol * max(np.abs(other).max(), 1.0)
+
+
+def test_prefill_attention_path_follows_shape_and_trace_mode():
+    """An inference trace at heads of 128 and 1,024 positions holds the
+    kernel (in the TPU branch of a platform switch: the CPU lowers the
+    blockwise branch); head dimension 4, a ragged or short ``T`` and a
+    training trace hold no kernel at all."""
+    from mxnet_tpu.ops.registry import get_op
+    op = get_op("_gqa_prefill")
+    attrs = op.normalize({"num_heads": 2, "num_kv_heads": 1, "window": 300})
+
+    def traced(d, t, training=False, dtype=jnp.bfloat16):
+        sds = [jax.ShapeDtypeStruct((1, t, n * d), dtype) for n in (2, 1, 1)]
+        return str(jax.make_jaxpr(op.bound(attrs, training))(*sds))
+    fused = traced(128, 1024)
+    assert "pallas_call" in fused and "platform_index" in fused
+    for text in (traced(4, 1024), traced(128, 1024, training=True),
+                 traced(128, 512), traced(128, 1024 + 64),
+                 traced(128, 1024, dtype=jnp.float16)):
+        assert "pallas_call" not in text
+    # and the CPU runs it: the switch lowers to the blockwise branch
+    x = jnp.ones((1, 1024, 256), jnp.bfloat16)
+    out, = jax.jit(op.bound(attrs, False))(x, x[..., :128], x[..., :128])
+    assert out.shape == x.shape
+
+
 @pytest.mark.parametrize("window,rows", [(0, 48), (8, 8)],
                          ids=["global", "ring"])
 def test_decode_attention_op(ref, window, rows):
@@ -362,6 +417,38 @@ def test_declared_flops_and_temporaries_reach_the_passes():
     assert plan["transient_peak_bytes"] == io + 2 * 4 * 4 * 16 * 23
 
 
+def test_fused_prefill_attention_declares_no_score_temporaries(monkeypatch):
+    """Where the kernel runs, the node's temporaries are the kernel's
+    (none in HBM) and under the blockwise block of scores; its FLOPs,
+    the band's and causality's count, are whoever computes them."""
+    from mxnet_tpu.analysis.flops import count_flops
+    from mxnet_tpu.analysis.memory import plan_memory
+    from mxnet_tpu.ops import transformer as tf
+    q, k, v = (mx.sym.Variable(n) for n in "qkv")
+    att = mx.sym._gqa_prefill(q, k, v, num_heads=14, num_kv_heads=2,
+                              window=700)
+    shapes = {"q": (2, 2048, 1792), "k": (2, 2048, 256),
+              "v": (2, 2048, 256)}
+    dtypes = {n: np.dtype(jnp.bfloat16) for n in shapes}
+    io = 2 * 2 * 2048 * (2 * 1792 + 2 * 256)
+
+    def read():
+        plan, _ = plan_memory(att, shapes, dtypes=dtypes)
+        return (plan["transient_peak_bytes"],
+                count_flops(att, shapes, dtypes=dtypes)["total"])
+    blockwise, flops = read()
+    assert blockwise == io + 2 * 4 * 2 * 14 * 512 * (700 + 511)
+    monkeypatch.setattr(tf, "_lowers_for_tpu", lambda: True)
+    fused, fused_flops = read()
+    assert fused == io < blockwise
+    assert fused_flops == flops > 0
+    # a head of 4 keeps the blockwise figure on a TPU too
+    small = {"q": (2, 2048, 56), "k": (2, 2048, 8), "v": (2, 2048, 8)}
+    plan, _ = plan_memory(att, small)
+    assert plan["transient_peak_bytes"] \
+        == 4 * 2 * 2048 * (2 * 56 + 2 * 8) + 2 * 4 * 2 * 14 * 512 * 1211
+
+
 # --------------------------------------------------------------- the engine
 def _engine(params, monkeypatch=None, budget=None, **kw):
     step, info = st.decode_step(CFG, MAX_LEN)
@@ -435,6 +522,45 @@ def test_coalesced_prefill_stays_inside_the_token_budget(model, monkeypatch):
         for e in evs:
             assert e["args"]["padded"] <= 64
             assert e["args"]["tokens"] == 20 * e["args"]["group"]
+    finally:
+        eng.close()
+
+
+def test_prefill_event_counts_the_fused_attention_nodes(model, monkeypatch):
+    """``decode.prefill`` says how many attention nodes of the
+    dispatched program took the fused kernel and ``stats()`` totals
+    them: none on the CPU; and with the op's predicate replaced by one
+    that takes the window layers, six of a program's eight."""
+    from mxnet_tpu.ops import transformer as tf
+    from mxnet_tpu.telemetry import timeline
+    params, _tokens, _want = model
+    eng, _s, _i = _engine(params, prefill_buckets=(16, 32))
+    try:
+        eng.warmup()
+        assert eng._prefill_fused == {
+            (b, bb): (0, 8) for b in (16, 32) for bb in (1, 2, 4)}
+        nodes = eng._replicas[0].prefill_caches[16].node_inputs(
+            "_gqa_prefill", {"prompt": (2, 16), "plen": (2,)})
+        assert [(a["window"], s[0], s[1]) for a, s, _d in nodes] == [
+            (w, (2, 16, 32), (2, 16, 16)) for w in [0, 8, 8, 8] * 2]
+        assert {str(d[0]) for _a, _s, d in nodes} == {"float32"}
+        monkeypatch.setattr(
+            tf, "prefill_takes_kernel",
+            lambda attrs, shapes, dtypes: attrs["window"] > 0)
+        eng._prefill_fused.pop((32, 1))
+        rng = np.random.default_rng(12)
+        t0 = time.perf_counter()
+        for n in (20, 9, 30):      # one at a time: buckets 32, 16, 32
+            eng.submit(rng.integers(1, 64, n).tolist(),
+                       max_new_tokens=2).result(timeout=300)
+        evs = [e["args"] for e in timeline.peek().events()
+               if e["name"] == "decode.prefill" and e["mono"] >= t0]
+        assert [(e["bucket"], e["fused_attention"], e["attention_nodes"])
+                for e in evs] == [(32, 6, 8), (16, 0, 8), (32, 6, 8)]
+        stats = eng.stats()["decode"]
+        assert stats["prefill_dispatches"] == 3
+        assert stats["prefill_fused_attention"] == 12
+        assert stats["prefill_attention_nodes"] == 24
     finally:
         eng.close()
 
