@@ -208,3 +208,24 @@ class ShapeDtypePass(AnalysisPass):
                 % unknown, node=n.name, op=n.op.name,
                 provenance=view.provenance(n)))
             return
+
+
+def node_inputs(symbol, op_name, data_shapes, dtypes=None):
+    """``(attrs, input shapes, input dtypes)`` of every ``op_name`` node
+    of ``symbol`` at these data shapes, in graph order: what such a node
+    can observe of its inputs when the program is built (the shapes pass
+    over the graph; a node whose inputs stayed unresolved is left
+    out)."""
+    from .core import analyze
+    _report, ctx = analyze(symbol, data_shapes=dict(data_shapes),
+                           dtypes=dict(dtypes or {}), passes=("shapes",))
+    out = []
+    for node in ctx.ensure_view().op_nodes():
+        if node.op.name != op_name:
+            continue
+        keys = [(id(i), ix) for (i, ix) in node.inputs]
+        if all(k in ctx.shapes and k in ctx.node_dtypes for k in keys):
+            out.append((node.op.normalize(node.attrs),
+                        [ctx.shapes[k] for k in keys],
+                        [ctx.node_dtypes[k] for k in keys]))
+    return out

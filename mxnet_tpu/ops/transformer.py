@@ -2,7 +2,9 @@
 linear map, grouped-query attention against a per-layer cache state
 (one query row a slot) and over a whole prompt (causal, banded: one
 fused Pallas kernel where a TPU can tile it, blockwise XLA elsewhere),
-and a sparse expert layer that is told which experts it holds.
+a gated short convolution against a state of a few rows a slot (one
+step, or a whole padded prompt), and a sparse expert layer that is told
+which experts it holds and by which rule its router's scores choose.
 
 Every op declares what the analysis passes need of it where it is
 written (``row_local``, ``flops``, ``temp_bytes``: ROADMAP D13); the
@@ -10,8 +12,9 @@ shape rule is the implementation under ``jax.eval_shape`` plus
 ``fill_shapes`` for the parameters.
 
 Precision, whatever the storage dtype: a norm's statistics, the rotation,
-attention scores and softmaxes, the router's softmax and every product's
-accumulator are float32; results are rounded once to the dtype of the
+attention scores and softmaxes, the router's scores, choice and weights,
+the short convolution's multiply-adds and every product's accumulator
+are float32; results are rounded once to the dtype of the
 activations they join.
 """
 from __future__ import annotations
@@ -25,6 +28,7 @@ from jax import lax
 from .registry import register, P
 
 _MASKED = -1e30        # finite: a fully masked row softmaxes to uniform
+_ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
 
 
 def _acc(*arrays):
@@ -48,21 +52,27 @@ def _itemsize(dt):
 def _gamma_fill(attrs, in_shapes):
     out = list(in_shapes)
     if out[0] is not None and len(out) > 1 and out[1] is None:
-        out[1] = (out[0][-1],)
+        out[1] = (attrs["head_dim"] or out[0][-1],)
     return out
 
 
 @register("RMSNorm", nin=2, input_names=["data", "gamma"],
-          params={"eps": P(float, 1e-6)}, fill_shapes=_gamma_fill,
-          row_local="leading",
+          params={"eps": P(float, 1e-6), "head_dim": P(int, 0)},
+          fill_shapes=_gamma_fill, row_local="leading",
           flops=lambda a, i, o: 4.0 * _prod(o))
 def rms_norm(attrs, data, gamma):
     """``gamma * data / sqrt(mean(data**2, -1) + eps)``; the mean and
     the division in float32, rounded to ``data``'s dtype before the
-    gain is applied."""
+    gain is applied.  With ``head_dim`` the last axis is heads of that
+    many values laid side by side, each normed over its own values under
+    the one gain of ``head_dim`` (a query/key norm)."""
+    d = attrs["head_dim"]
     x = data.astype(_acc(data))
+    if d:
+        x = x.reshape(data.shape[:-1] + (-1, d))
     inv = lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + attrs["eps"])
-    return (x * inv).astype(data.dtype) * gamma.astype(data.dtype)
+    out = (x * inv).astype(data.dtype) * gamma.astype(data.dtype)
+    return out.reshape(data.shape)
 
 
 # ----------------------------------------------------------------- linear
@@ -458,6 +468,104 @@ def gqa_prefill(attrs, q, k, v):
         default=blockwise)
 
 
+# ------------------------------------------------------- gated activation
+@register("_gated_act", nin=2, input_names=["gate", "up"],
+          params={"activation": P(str, "silu", choices=list(_ACTIVATIONS))},
+          row_local="leading",
+          flops=lambda a, i, o: 6.0 * _prod(o))
+def gated_act(attrs, gate, up):
+    """``act(gate) * up`` (a gated linear unit's middle: ``silu`` makes
+    it SwiGLU, ``relu`` ReGLU), computed in float32 and rounded once."""
+    acc = _acc(gate)
+    return (_ACTIVATIONS[attrs["activation"]](gate.astype(acc))
+            * up.astype(acc)).astype(gate.dtype)
+
+
+# ------------------------------------------------- gated short convolution
+def _conv_split(proj):
+    """The three blocks ``[B, C, x]`` of an input projection."""
+    d = proj.shape[-1] // 3
+    return proj[..., :d], proj[..., d:2 * d], proj[..., 2 * d:]
+
+
+def _conv_flops(attrs, ins, out):
+    # B * x, a multiply-add a tap, C * y
+    return (2.0 * ins[-1][0] + 2.0) * _prod(ins[0]) / 3.0
+
+
+def _conv_fill(attrs, in_shapes):
+    out = list(in_shapes)
+    if out[0] is not None and out[-1] is None:
+        out[-1] = (attrs["taps"], out[0][-1] // 3)
+    return out
+
+
+@register("_short_conv_step", nin=3, nout=2,
+          input_names=["data", "state", "weight"],
+          params={"taps": P(int, 3)}, fill_shapes=_conv_fill,
+          row_local="axis0", flops=_conv_flops)
+def short_conv_step(attrs, proj, state, weight):
+    """One position a slot of a gated short convolution.
+
+    ``proj`` ``(slots, 3 * d)`` is the input projection's ``[B, C, x]``;
+    ``state`` ``(slots, taps - 1, d)`` holds ``u = B * x`` of the
+    ``taps - 1`` positions before this one, oldest first (zeros before
+    the sequence's start); ``weight`` ``(taps, d)`` is a tap a row, the
+    last on the current position.  Returns ``C * y`` with ``y =
+    sum_k weight[k] * u_{t - (taps - 1) + k}`` per channel, and the next
+    state: the old one shifted by a row with ``u_t`` behind it.
+
+    ``u`` is computed in float32 and rounded once to the state's dtype,
+    the value every later position reads of it; the multiply-adds and
+    the gate are float32, the result rounded once."""
+    b, c, x = _conv_split(proj)
+    acc = _acc(proj)
+    u = (b.astype(acc) * x.astype(acc)).astype(state.dtype)
+    held = jnp.concatenate([state, u[:, None]], axis=1)
+    w = weight.astype(acc)
+    y = sum(held[:, k].astype(acc) * w[k] for k in range(w.shape[0]))
+    return (c.astype(acc) * y).astype(proj.dtype), held[:, 1:]
+
+
+def _conv_seq_temp(attrs, ins, dts):
+    # u padded at the front, and the float32 sum over the taps
+    b, t, d3 = ins[0]
+    return b * (t + attrs["taps"]) * (d3 // 3) * (_itemsize(dts[0]) + 4)
+
+
+@register("_short_conv_seq", nin=3, nout=2,
+          input_names=["data", "length", "weight"],
+          params={"taps": P(int, 3)}, fill_shapes=_conv_fill,
+          row_local="axis0", flops=_conv_flops, temp_bytes=_conv_seq_temp)
+def short_conv_seq(attrs, proj, length, weight):
+    """A whole padded prompt of a gated short convolution, in XLA: one
+    shifted multiply-add a tap (``taps`` passes over ``u``; nothing here
+    is bound by them).
+
+    ``proj`` ``(batch, T, 3 * d)``, ``length`` ``(batch,)`` live
+    positions a row, ``weight`` as ``_short_conv_step``.  Returns ``C *
+    y`` at every position (causal, so padding behind a row's length
+    touches no live position) and the state *at each row's own length*:
+    ``u`` of positions ``length - (taps - 1) .. length - 1``, zeros
+    where that is before the start, whatever the padding holds.  The
+    same roundings as the step."""
+    taps = weight.shape[0]
+    b, c, x = _conv_split(proj)
+    acc = _acc(proj)
+    n, t, d = b.shape
+    u = (b.astype(acc) * x.astype(acc)).astype(proj.dtype)
+    front = jnp.concatenate(
+        [jnp.zeros((n, taps - 1, d), u.dtype), u], axis=1)
+    w = weight.astype(acc)
+    y = sum(front[:, k:k + t].astype(acc) * w[k] for k in range(taps))
+    # row j of the state is padded position length + j
+    at = length.astype(jnp.int32)[:, None] \
+        + jnp.arange(taps - 1, dtype=jnp.int32)[None, :]
+    state = jnp.take_along_axis(
+        front, jnp.clip(at, 0, t + taps - 2)[:, :, None], axis=1)
+    return (c.astype(acc) * y).astype(proj.dtype), state
+
+
 # ------------------------------------------------------------ expert layer
 def _held(attrs, n_experts):
     first = attrs["first_expert"]
@@ -480,6 +588,17 @@ def _moe_dense(attrs, rows, held):
     """Every held expert over every row, where that is no more rows
     multiplied than the sorted path may pad to."""
     return rows * held <= _moe_padded_rows(attrs, rows, held)
+
+
+def moe_products(attrs, shapes):
+    """(row, expert) products a ``_moe_experts`` node over inputs of
+    these shapes multiplies: every held expert over every row on the
+    plain path, the rows the sorted path may pad its pairs to past it.
+    The rows that were routed are ``rows * top_k``."""
+    rows, held = _moe_rows(shapes), shapes[2][0]
+    if _moe_dense(attrs, rows, held):
+        return rows * held
+    return _moe_padded_rows(attrs, rows, held)
 
 
 def _moe_flops(attrs, ins, out):
@@ -508,24 +627,55 @@ def _moe_fill(attrs, in_shapes):
     return out
 
 
-@register("_moe_experts", nin=5, nout=2,
+def _moe_route(attrs, r, bias):
+    """``(chosen experts, their weights)`` of each row of float32
+    router logits ``r``, by the layer's rule.  ``softmax``: the
+    ``top_k`` largest logits, weighted by the softmax over them.
+    ``sigmoid``: scores ``sigmoid(r)``; the ``top_k`` largest of score
+    plus ``bias`` (where the layer has one: it steers the choice and
+    nothing else) are chosen, and weighted by their own unbiased scores,
+    over their sum plus 1e-6 under ``norm_topk``, times
+    ``route_scale``."""
+    k = attrs["top_k"]
+    if attrs["routing"] == "softmax":
+        top_v, top_i = lax.top_k(r, k)
+        return top_i, jax.nn.softmax(top_v, axis=-1)
+    s = jax.nn.sigmoid(r)
+    _, top_i = lax.top_k(s if bias is None else s + bias.astype(r.dtype), k)
+    w = jnp.take_along_axis(s, top_i, axis=-1)
+    if attrs["norm_topk"]:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+    return top_i, w * attrs["route_scale"]
+
+
+@register("_moe_experts", nout=2,
+          nin=lambda attrs: 6 if (attrs or {}).get("expert_bias") else 5,
           input_names=["data", "router", "gate_weight", "up_weight",
-                       "down_weight"],
+                       "down_weight", "bias"],
           params={"top_k": P(int), "first_expert": P(int, 0),
                   "num_held": P(int, 0), "expert_width": P(int, 0),
-                  "block": P(int, 256)},
+                  "block": P(int, 256),
+                  "routing": P(str, "softmax",
+                               choices=["softmax", "sigmoid"]),
+                  "activation": P(str, "relu", choices=list(_ACTIVATIONS)),
+                  "expert_bias": P(bool, False),
+                  "norm_topk": P(bool, True),
+                  "route_scale": P(float, 1.0)},
           fill_shapes=_moe_fill, row_local="leading", flops=_moe_flops,
           temp_bytes=_moe_temp)
-def moe_experts(attrs, data, router, wg, wu, wd):
+def moe_experts(attrs, data, router, wg, wu, wd, bias=None):
     """Sparse gated-linear experts, dropless, over the experts held here.
 
     ``router`` holds a row's logits over *all* the experts (the
-    published router width); each row takes its ``top_k`` largest and
-    weights them by the softmax over those ``top_k`` logits.  The
-    weights ``(num_held, width, hidden)`` are those of experts
-    ``first_expert .. first_expert + num_held - 1`` (``num_held`` 0: all
-    from ``first_expert`` on); expert ``e`` computes ``down_e.T @
-    (relu(gate_e @ x) * (up_e @ x))``.  Returns the held experts' part
+    published router width); ``routing`` says how they choose and weigh
+    (``_moe_route``: by default each row takes its ``top_k`` largest and
+    weights them by the softmax over those ``top_k`` logits; ``sigmoid``
+    scores each expert alone and, with ``expert_bias``, chooses under
+    the sixth input, a bias an expert).  The weights ``(num_held, width,
+    hidden)`` are those of experts ``first_expert .. first_expert +
+    num_held - 1`` (``num_held`` 0: all from ``first_expert`` on);
+    expert ``e`` computes ``down_e.T @ (act(gate_e @ x) * (up_e @ x))``
+    with ``activation`` ``relu`` or ``silu``.  Returns the held experts' part
     of the weighted sum (shares over a partition of the experts add up
     to the whole layer) and the ``(..., experts)`` routing weights, zero
     where an expert was not chosen.
@@ -546,7 +696,7 @@ def moe_experts(attrs, data, router, wg, wu, wd):
     against 3.44, so the rule leaves the plain products early; 8,192
     rows 16 ms sorted, where the plain products would be 64/6 of the
     work (40 ms at the peak) and 4.8 GB of activations."""
-    k, blk = attrs["top_k"], attrs["block"]
+    blk = attrs["block"]
     lead, d = data.shape[:-1], data.shape[-1]
     n_exp = router.shape[-1]
     first, held = _held(attrs, n_exp)
@@ -555,8 +705,8 @@ def moe_experts(attrs, data, router, wg, wu, wd):
     acc = _acc(data)
     r = router.reshape(-1, n_exp).astype(acc)
     m = x.shape[0]
-    top_v, top_i = lax.top_k(r, k)
-    w = jax.nn.softmax(top_v, axis=-1)
+    top_i, w = _moe_route(attrs, r, bias)
+    act_fn = _ACTIVATIONS[attrs["activation"]]
     route = jnp.sum(jax.nn.one_hot(top_i, n_exp, dtype=acc)
                     * w[..., None], axis=1)
     nt = (((1,), (1,)), ((), ()))
@@ -566,17 +716,17 @@ def moe_experts(attrs, data, router, wg, wu, wd):
                                preferred_element_type=acc)
         up = lax.dot_general(x, wu.reshape(held * f, d), nt,
                              preferred_element_type=acc)
-        act = (jax.nn.relu(gate) * up).reshape(m, held, f) \
+        act = (act_fn(gate) * up).reshape(m, held, f) \
             * local[:, :, None]
         y = jnp.dot(act.reshape(m, held * f).astype(x.dtype),
                     wd.reshape(held * f, d), preferred_element_type=acc)
     else:
-        y = _moe_sorted(x, top_i, w, wg, wu, wd, first, held, blk)
+        y = _moe_sorted(x, top_i, w, wg, wu, wd, first, held, blk, act_fn)
     return (y.reshape(lead + (d,)).astype(data.dtype),
             route.reshape(lead + (n_exp,)))
 
 
-def _moe_sorted(x, top_i, w, wg, wu, wd, first, held, blk):
+def _moe_sorted(x, top_i, w, wg, wu, wd, first, held, blk, act_fn):
     """The grouped product: pairs sorted by expert into runs padded to
     whole blocks, one block against one expert's weights a loop turn
     (the pair's routing weight on the activation), then each row's
@@ -621,7 +771,7 @@ def _moe_sorted(x, top_i, w, wg, wu, wd, first, held, blk):
         gate = lax.dot_general(xb, wg[ex], nt, preferred_element_type=acc)
         up = lax.dot_general(xb, wu[ex], nt, preferred_element_type=acc)
         wb = lax.dynamic_slice_in_dim(ws, at, blk, axis=0)
-        act = (jax.nn.relu(gate) * up * wb[:, None]).astype(x.dtype)
+        act = (act_fn(gate) * up * wb[:, None]).astype(x.dtype)
         yb = jnp.dot(act, wd[ex], preferred_element_type=acc)
         return lax.dynamic_update_slice_in_dim(ys, yb.astype(x.dtype), at,
                                                axis=0)

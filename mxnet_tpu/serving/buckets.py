@@ -253,22 +253,10 @@ class ProgramCache(object):
         built (the shapes pass over this cache's graph and its
         parameters' dtypes; a node whose inputs stayed unresolved is
         left out)."""
-        from ..analysis import analyze
-        _report, ctx = analyze(
-            self._sym, data_shapes=dict(data_shapes),
-            dtypes={n: np.dtype(a.dtype)
-                    for n, a in self._params.items()},
-            passes=("shapes",))
-        out = []
-        for node in ctx.ensure_view().op_nodes():
-            if node.op.name != op_name:
-                continue
-            keys = [(id(i), ix) for (i, ix) in node.inputs]
-            if all(k in ctx.shapes and k in ctx.node_dtypes for k in keys):
-                out.append((node.op.normalize(node.attrs),
-                            [ctx.shapes[k] for k in keys],
-                            [ctx.node_dtypes[k] for k in keys]))
-        return out
+        from ..analysis.shapes import node_inputs
+        return node_inputs(self._sym, op_name, data_shapes,
+                           {n: np.dtype(a.dtype)
+                            for n, a in self._params.items()})
 
     def _plan_for(self, shape_key, data_specs):
         """Prefilled flat-input list + kernel + rng key for one bucket

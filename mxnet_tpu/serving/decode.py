@@ -1748,6 +1748,11 @@ class DecodeEngine(object):
         self._prefill_fused = {}
         self._prefill_fused_attention = 0
         self._prefill_attention_nodes = 0
+        # (row, expert) products the step's expert nodes multiply at
+        # pool extent, and the experts a live row is routed to, summed
+        # over those nodes (None: the step has no expert layer)
+        self._expert_work = self._step_expert_work(
+            step_sym, arg_params, token_name, pos_name, valid_name)
         # device replicas (serving/replica.py, ROADMAP 2a): each owns a
         # FULL slot pool — persistent step program + device-resident
         # state + prefill bucket caches, params uploaded once per
@@ -3146,6 +3151,9 @@ class DecodeEngine(object):
             arr[r_i, :plen] = req.prompt
             lens[r_i] = plen
         fused, attn_nodes = self._fused_attention(rep, bucket, bb)
+        # the states this dispatch's commit lays, of either kind
+        n_caches = len(rep.program.layout.caches("target"))
+        n_rows = len(rep.program.layout.target) - n_caches
         t_pf0 = time.perf_counter()
         ann = (self._tl.annotate("decode.prefill") if self._tl is not None
                else _telemetry.timeline.NO_SPAN)
@@ -3195,7 +3203,9 @@ class DecodeEngine(object):
                                     "tokens": live_elems,
                                     "padded": padded_elems,
                                     "fused_attention": fused,
-                                    "attention_nodes": attn_nodes})
+                                    "attention_nodes": attn_nodes,
+                                    "row_states": n_rows,
+                                    "cache_states": n_caches})
         if self._eff is not None:
             shape_key = tuple(sorted(
                 (k, v.shape)
@@ -3214,6 +3224,31 @@ class DecodeEngine(object):
                 name: rows_all[i][r_i]
                 for i, name in enumerate(rep.program.state_names)}
             self._commit_prefill(rep, req, rows, first[r_i])
+
+    def _step_expert_work(self, step_sym, arg_params, token_name,
+                          pos_name, valid_name):
+        """``(products, top_k)`` of the step graph's ``_moe_experts``
+        nodes: the (row, expert) products they multiply a step by the
+        op's own rule for the shapes built (``ops/transformer.py``
+        ``moe_products``: every held expert over every slot on the
+        plain path), and the experts a row is routed to, both summed
+        over the nodes.  None where the step has none."""
+        from ..analysis.shapes import node_inputs
+        from ..ops.transformer import moe_products
+        try:
+            grid = self._layout.grid(step_sym, token_name, pos_name,
+                                     valid_name, "target")
+            dtypes = dict(grid.dtypes)
+            dtypes.update((n, np.dtype(a.dtype))
+                          for n, a in (arg_params or {}).items())
+            nodes = node_inputs(step_sym, "_moe_experts", grid.shapes,
+                                dtypes)
+        except Exception:           # a count must never fail an engine
+            nodes = []
+        if not nodes:
+            return None
+        return (sum(moe_products(a, s) for a, s, _d in nodes),
+                sum(a["top_k"] for a, _s, _d in nodes))
 
     def _fused_attention(self, rep, bucket, bb):
         """How many ``_gqa_prefill`` nodes of the ``(bb, bucket)``
@@ -3423,6 +3458,11 @@ class DecodeEngine(object):
         for name, arr in dict(extras).items():
             args[name + "_max"] = float(arr.max())
             args[name + "_mean"] = float(arr.mean())
+        if self._expert_work is not None:
+            # what the expert layers multiplied (every slot rides) and
+            # what the live rows were routed to
+            args["expert_products"] = self._expert_work[0]
+            args["expert_routed"] = self._expert_work[1] * live
         return args
 
     def _step_body(self, rep, sp, t0):
@@ -3976,6 +4016,9 @@ class DecodeEngine(object):
                     len(self._prefill_grid[b])
                     for b in self._prefill_buckets),
                 "state_rows": dict(self._program.layout.cache_rows()),
+                # a slot's plain rows (no cache: zeroed at a join,
+                # replaced by a prefill), in bytes
+                "row_state_bytes": self._program.layout.row_state_bytes(),
                 "prefill_dispatches": self._prefill_dispatches,
                 # attention nodes of those dispatches' programs, and
                 # those that took the fused kernel (ops/transformer.py)

@@ -19,6 +19,15 @@ the engine's preflights, the speculative commit and the goodput pricer
 ask it for pool shapes, dtypes, bytes, the step graph's input grid and
 for what ``cache`` and ``window`` mean.  A new kind of per-slot state is
 a change to this module, to the model's graph and to its op rules.
+
+A state that is no cache is a *plain row*: the step returns it whole,
+a join without prefill zeroes it (``reset_names``) and a prefill output
+of the row's own shape replaces it (``lay_prefill``).  An LSTM's ``h``
+and ``c`` are plain rows, and so is a short convolution's state, the
+last ``taps - 1`` rows of its gated input: what it needs of the pool is
+what a recurrent row needs, so a model may declare caches and plain rows
+side by side (``models/lfm2.py``) and one prefill dispatch and one
+commit lay both.
 """
 from __future__ import annotations
 
@@ -133,6 +142,12 @@ class SlotLayout(object):
 
     def slot_bytes(self, sharding=None, which="target"):
         return self.pool_bytes(sharding, which) // self.num_slots
+
+    def row_state_bytes(self, which="target"):
+        """Bytes one slot's plain rows hold (the states that are no
+        cache): what a join zeroes and a prefill replaces."""
+        return sum(int(np.prod(s.row)) * s.dtype.itemsize
+                   for s in self.states(which) if not s.cache)
 
     # ------------------------------------------------------------ caches
     def cache_rows(self, which="target", pos_name=None, has_pos=True):

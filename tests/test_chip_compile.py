@@ -348,3 +348,103 @@ def test_smallthinker_prefill_parts_compile_for_v5e(one_chip, case):
     assert compiled.memory_analysis().temp_size_in_bytes < limit
     if case.startswith("attention"):
         assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# the decoder with conv rows beside caches, at the benchmark's sizes
+# ---------------------------------------------------------------------------
+
+def _lfm2_cfg():
+    import json
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "lfm2-8b-a1b-14l-bf16.json")) as f:
+        return json.load(f)
+
+
+def test_lfm2_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """``lfm2-decode-chat``'s step at its own size: 256 slots, fourteen
+    layers at the published widths in bfloat16 (the expert bias
+    float32), six caches of 1,280 rows and eleven conv rows a slot, the
+    whole pool donated, the cache writes in the kernel 'auto' picks on
+    the chip.  9.33 GB of weights and 2.04 GB of pool; the plain expert
+    products over 256 rows have to stay small beside them."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.executor import build_graph_fn
+    from mxnet_tpu.models import lfm2
+    cfg = _lfm2_cfg()
+    slots, bf = 256, jnp.bfloat16
+    step, info = lfm2.decode_step(cfg, 1280)
+    pool = jax.ShapeDtypeStruct((slots, 1280, 512), bf)
+    monkeypatch.setenv("MXNET_CACHE_SCATTER_IMPL",
+                       _impl_auto_picks_on_tpu(monkeypatch, pool))
+    head = mx.sym.argmax(step[0], axis=1)
+    serve = mx.sym.Group([head] + [step[i] for i in range(1, len(step))])
+    names = serve.list_arguments()
+    fn = build_graph_fn(serve, names, [])
+    shapes = lfm2.param_shapes(cfg)
+    states = {i["name"]: (slots,) + tuple(i["shape"]) for i in info}
+    args = [jax.ShapeDtypeStruct(
+                shapes[n], jnp.float32 if n.endswith("expert_bias") else bf)
+            if n in shapes
+            else jax.ShapeDtypeStruct(states[n], bf) if n in states
+            else jax.ShapeDtypeStruct((slots,), jnp.float32)
+            for n in names]
+    jitted = jax.jit(
+        lambda *flat: fn(list(flat), [], jax.random.PRNGKey(0), False)[0],
+        donate_argnums=tuple(names.index(n) for n in states))
+    compiled = jitted.lower(*_described(args, one_chip)).compile()
+    ma = compiled.memory_analysis()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert ma.alias_size_in_bytes \
+        == 256 * (6 * 1280 * 512 + 11 * 2 * 2048) * 2
+    assert ma.temp_size_in_bytes < 0.5e9
+    assert _total_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("case", ["conv_32_prompts", "experts_256_rows",
+                                  "commit_rows_and_caches"])
+def test_lfm2_parts_compile_for_v5e(one_chip, case):
+    """What LFM2 brought to the chip's compiler, each part alone: the
+    short convolution over a dispatch of 32 padded prompts of 512 (three
+    shifted multiply-adds and the state at each row's own length), the
+    step's expert layer over 256 rows (sigmoid scores under a bias,
+    SwiGLU, the plain products), and one prefill commit laying keys,
+    values and conv rows of 32 prompts into the pool in place."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.models import lfm2
+    from mxnet_tpu.ops.registry import get_op
+    from mxnet_tpu.serving.slot_state import SlotLayout
+    bf = jnp.bfloat16
+
+    def sds(*shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    donate = ()
+    if case == "conv_32_prompts":
+        op = get_op("_short_conv_seq")
+        fn = op.bound(op.normalize({}))
+        args = (sds(32, 512, 6144), sds(32, dtype=jnp.float32),
+                sds(3, 2048))
+        limit = 0.5e9
+    elif case == "experts_256_rows":
+        op = get_op("_moe_experts")
+        fn = op.bound(op.normalize({
+            "top_k": 4, "routing": "sigmoid", "activation": "silu",
+            "expert_bias": True}))
+        args = (sds(256, 2048), sds(256, 32, dtype=jnp.float32),
+                sds(32, 1792, 2048), sds(32, 1792, 2048),
+                sds(32, 1792, 2048), sds(32, dtype=jnp.float32))
+        limit = 0.3e9
+    else:
+        info = lfm2.state_info(_lfm2_cfg(), 1280)
+        fn = SlotLayout(info, 256, bf).lay_prefill
+        args = ([sds(256, *i["shape"]) for i in info],
+                [sds(32, 512, 512) if i.get("cache") else sds(32, 2, 2048)
+                 for i in info],
+                sds(32, dtype=jnp.int32), sds(32, dtype=jnp.int32))
+        donate, limit = (0,), 0.2e9
+    compiled = jax.jit(fn, donate_argnums=donate).lower(
+        *_described(args, one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < limit

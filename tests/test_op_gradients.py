@@ -202,6 +202,13 @@ SPECS["_gqa_prefill"] = S(
 SPECS["_moe_experts"] = S(
     lambda: [_u(3, 4), _distinct(3, 5) * 3.0, _u(5, 3, 4), _u(5, 3, 4),
              _u(5, 3, 4)], {"top_k": 2})
+SPECS["_gated_act"] = S(lambda: [_u(2, 3, 4), _u(2, 3, 4)])
+# the gated short convolution: projections [B, C, x] of 2 channels, a
+# state of two rows, three taps; a row's length is data
+SPECS["_short_conv_step"] = S(
+    lambda: [_u(3, 6), _u(3, 2, 2), _u(3, 2)])
+SPECS["_short_conv_seq"] = S(
+    lambda: [_u(2, 5, 6), np.array([5., 2.]), _u(3, 2)], wrt=[0, 2])
 SPECS["Embedding"] = S(lambda: [np.array([0., 2., 1.]), _u(4, 3)],
                        {"input_dim": 4, "output_dim": 3}, wrt=[1])
 

@@ -63,6 +63,35 @@ decode) with every observability plane on.  All families live in the
 """
 
 
+#: What the serving path reports outside the registry: the arguments of
+#: the decode engine's timeline events (``telemetry/timeline.py``; one
+#: ``decode.step`` a step, one ``decode.prefill`` a dispatch) and the
+#: keys of ``stats()["decode"]`` the benchmark's readers and drivers
+#: take.  Static text: the ring has no schema to generate it from.
+_EVENTS = """
+## Timeline event arguments and `stats()["decode"]` keys
+
+Not registry families: arguments of the decode engine's events in the
+timeline ring, and keys of `DecodeEngine.stats()["decode"]`.
+
+| where | name | what it holds |
+|---|---|---|
+| `decode.step` | `live`, `tokens` | slots the step stepped; tokens delivered from it |
+| `decode.step` | `dispatch_ms`, `read_ms` | the step's dispatch, an iteration back; the wait for its ids, after the step after it went out |
+| `decode.step` | `ahead`, `discarded` | 1 if dispatched before the step before it was read; slot results whose request had left |
+| `decode.step` | `<name>_max`, `<name>_mean` | a counter output of the step graph past its states (`expert_load`: live rows an expert got) |
+| `decode.step` | `expert_products`, `expert_routed` | (row, expert) products the step's `_moe_experts` nodes multiply at pool extent, by the op's own rule for the shapes built (every held expert over every slot on the plain path); `top_k` x live rows, summed over those nodes. Absent where the step has no expert layer |
+| `decode.prefill` | `bucket`, `group`, `tokens`, `padded` | padded prompt length; requests in the dispatch; live prompt positions; batch x bucket |
+| `decode.prefill` | `fused_attention`, `attention_nodes` | `_gqa_prefill` nodes of the dispatched program that take the fused kernel; how many it has |
+| `decode.prefill` | `row_states`, `cache_states` | states the dispatch's commit laid into the pool: plain rows (replaced whole: recurrent and convolution state) and positional caches (keys or values of every prompt position) |
+| `stats()["decode"]` | `state_rows` | rows of each cache state a slot |
+| `stats()["decode"]` | `row_state_bytes` | bytes of one slot's plain rows (the states that are no cache: zeroed at a join, replaced by a prefill) |
+| `stats()["decode"]` | `prefill_token_budget`, `prefill_programs` | positions a prefill dispatch may hold; (batch, bucket) programs warmed |
+| `stats()["decode"]` | `steps_ahead`, `slot_steps_discarded` | totals of `ahead` and `discarded` |
+| `stats()["decode"]` | `prefill_fused_attention`, `prefill_attention_nodes` | totals over the prefill dispatches |
+"""
+
+
 def populate_registry():
     """Construct both engine kinds with all planes on and exercise the
     ancillary instruments, so the default registry holds every family
@@ -178,6 +207,7 @@ def render(reg):
                      ", ".join("`%s`" % l for l in labels) or "—",
                      help_text))
     buf.write("\n%d families.\n" % len(doc))
+    buf.write(_EVENTS)
     return buf.getvalue()
 
 
