@@ -312,6 +312,18 @@ class AdmissionController(object):
         self._deliver(failures)
         return batch
 
+    def head(self):
+        """``(requests queued, the oldest of them or None)``: what the
+        decode scheduler decides on before it polls.  A request it
+        leaves here stays under the sweep, the shed policies and the
+        queue's bound like any other.  Empty-queue fast path as in
+        :meth:`poll`."""
+        if not self._queue:
+            return 0, None
+        with self._cond:
+            return len(self._queue), (self._queue[0] if self._queue
+                                      else None)
+
     def _pop_group_locked(self, group, max_batch):
         taken, keep = [], collections.deque()
         for r in self._queue:

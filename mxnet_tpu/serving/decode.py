@@ -52,11 +52,24 @@ workload):
   programs), or, with a ``prefill_sym``, in ONE dispatch through the
   existing :class:`~mxnet_tpu.serving.buckets.ProgramCache` at pow2
   seq buckets, its output state scattered into the free slot —
-  and concurrent joiners COALESCE (``MXNET_DECODE_COALESCE_PREFILL``,
-  default on): requests joining in the same scheduler iteration whose
-  prompts pad to the same seq bucket share one dispatch at the next
-  pow2 batch extent instead of prefilling at batch 1 each, the direct
-  TTFT lever at concurrency (``perf/decode_bench.py --prefill``);
+  and joiners COALESCE (``MXNET_DECODE_COALESCE_PREFILL``, default
+  on): requests seated in the same scheduler iteration whose prompts
+  pad to the same seq bucket share one dispatch at the next pow2 batch
+  extent instead of prefilling at batch 1 each
+  (``perf/decode_bench.py --prefill``);
+- **joins that wait for a dispatch worth its cost**: a prefill dispatch
+  stops every decoding slot while it runs, so a free slot is not filled
+  the moment a request waits for it.  The scheduler seats as many of
+  the waiting requests as a dispatch is worth now, from what the engine
+  has observed and nothing else (``join_policy.py``): what a dispatch
+  of each warm batch costs the device, the step's time, the slots
+  decoding, and how many requests become seatable a step.  Where a
+  dispatch has a fixed cost of several steps they are seated several
+  at a time, after a hold of bounded length in the admission queue
+  (deadlines swept, cancels honoured, back-pressure counted); where it
+  costs by its rows, where nothing decodes, and where a join rides the
+  step, at once.  There is no option for it (``stats()["decode"]``:
+  ``prefill_cost_ms``, ``slot_steps_held``);
 - **fused-op selection**: before any program compiles, the optimizer's
   kernel-selection pipeline (``analysis.SELECT_OPT_PASSES``, behind
   ``MXNET_SERVE_OPTIMIZE`` + ``MXNET_OPT_SELECT_KERNELS``) rewrites
@@ -122,6 +135,7 @@ from .engine import (_ENGINE_SEQ, _percentile, aot_metric_families,
                      _supervisor_state, memory_metric_families,
                      _memory_stats_block, refresh_memory_gauges)
 from .replica import DecodeReplica, resolve_replica_placements
+from . import join_policy as _join_policy
 from .slot_state import SlotLayout
 
 __all__ = ["DecodeEngine", "DecodeResult", "StepProgram", "greedy_decode",
@@ -1742,6 +1756,12 @@ class DecodeEngine(object):
         # direct TTFT lever at concurrency (decode_bench --prefill)
         self._coalesce = bool(config.get("MXNET_DECODE_COALESCE_PREFILL"))
         self._prefill_dispatches = 0
+        # what a prefill dispatch costs the device, in seconds, as
+        # {bucket: {batch: s}}: the median of the last readings of the
+        # live dispatches (``_prefill_observed``), which ``_seats_now``
+        # decides on
+        self._prefill_cost = {}
+        self._prefill_readings = {}
         # (attention nodes that took the fused kernel, attention
         # nodes) of each (bucket, batch) prefill program, and their
         # sums over the prefill dispatches
@@ -1941,6 +1961,8 @@ class DecodeEngine(object):
         #                             them was read
         self._discarded = 0         # slot-steps whose result was thrown
         #                             away: their request had left
+        self._slot_steps_held = 0   # seatable requests x steps the join
+        #                             policy left waiting for a batch
         self._joins = 0
         self._steals = 0
         self._leaves = 0
@@ -2639,19 +2661,26 @@ class DecodeEngine(object):
                 occ = rep.occupied()
                 free = self.num_slots - len(occ)
                 if not occ and rep.flight is None:
+                    # nothing decodes: nothing is held
+                    rep.joins.idle()
                     batch = self._adm.take(free, 0.0)
                     if batch is None:
                         return          # closed and drained
                     self._join_many(rep, batch)
                     continue
-                # busy: admit opportunistically (never block a step),
-                # and keep queued deadlines honest even when no slot
-                # is free — expiry must not wait for a drain
-                if free:
-                    polled = self._adm.poll(free)
+                # busy: never block a step.  Of the requests a free
+                # slot waits for, seat as many as a prefill dispatch is
+                # worth stopping the decoding slots for now
+                # (``_seats_now``); the others stay in the admission
+                # queue, whose deadlines are kept honest either way —
+                # expiry must not wait for a drain, or for a batch
+                waiting, head = self._adm.head() if free else (0, None)
+                n = self._seats_now(rep, min(free, waiting), head)
+                if n:
+                    polled = self._adm.poll(n)
                     if polled:
                         self._join_many(rep, polled)
-                else:
+                elif waiting or not free:
                     self._adm.sweep()
                 self._hb_busy = True    # a wedged step must read busy
                 self._step_once(rep)
@@ -2670,6 +2699,7 @@ class DecodeEngine(object):
                 # the pool is empty now, so fresh zeros lose nothing.
                 # The step in flight goes unread: its requests failed
                 rep.flight = None
+                rep.joins.idle()
                 rep.states = rep.program.init_states()
                 rep.tokens_np.fill(0.0)
                 rep.pos_np.fill(0.0)
@@ -2788,9 +2818,16 @@ class DecodeEngine(object):
             stolen = 0
             with self._dr_lock:
                 n_free = rep.free_slots()
-                while rep.pending and len(seats) < n_free:
+                # the same decision as the single pool's, over the
+                # requests routed here: one it leaves stays in
+                # ``rep.pending``, swept above and counted against the
+                # router's promise (``assignable``)
+                w = min(n_free, len(rep.pending))
+                n = self._seats_now(rep, w,
+                                    rep.pending[0] if w else None)
+                while len(seats) < n:
                     seats.append(rep.pending.popleft())
-                if len(seats) < n_free and rep.healthy:
+                if n == w and len(seats) < n_free and rep.healthy:
                     # cross-replica work stealing (ROADMAP a3): a
                     # request routed to a sibling whose pool is FULL
                     # would otherwise wait a whole generation for its
@@ -2987,6 +3024,7 @@ class DecodeEngine(object):
             rep.spec_np = fresh.spec_np
             rep.states = fresh.states
             rep.flight = None
+            rep.joins = fresh.joins
             rep.pending.clear()
             rep.in_step = False
             rep.healthy = True
@@ -3017,13 +3055,16 @@ class DecodeEngine(object):
         the next step dispatch reuses the same compiled program.
 
         With a prefill graph and ``MXNET_DECODE_COALESCE_PREFILL``
-        (default on), joiners landing in the same iteration COALESCE:
-        one dispatch per pow2 (batch, prompt) bucket instead of batch 1
-        per joiner — at concurrency the TTFT cost of the Nth joiner
-        stops being N serial prefill dispatches (ROADMAP 4b; the
-        ``decode_bench --prefill`` sweep measures the win).  Serial
-        mode (knob off) dispatches per request, byte-for-byte the
-        pre-coalescing engine."""
+        (default on), the joiners it is handed COALESCE: one dispatch
+        per pow2 (batch, prompt) bucket instead of batch 1 per joiner
+        (ROADMAP 4b; the ``decode_bench --prefill`` sweep measures the
+        win).  How many it is handed at a time is the scheduler's
+        decision (``_seats_now``): everything seatable while nothing
+        decodes, and otherwise as many as a dispatch is worth stopping
+        the decoding slots for.  Serial mode (knob off) dispatches per
+        request, byte-for-byte the pre-coalescing engine."""
+        # the slots a prefill dispatch of this join stops
+        rep.joins.decoding = int(rep.valid_np.sum())
         seated = [req for req in reqs if self._seat_slot(rep, req)]
         if not seated:
             return
@@ -3182,10 +3223,17 @@ class DecodeEngine(object):
                     first = rep.program.sample_tokens(outs[0])
                 rows_all = None if on_device \
                     else [np.asarray(o) for o in outs[1:]]
+                t_pf1 = time.perf_counter()
         except Exception as e:
             for req in live:
                 self._fail_seated(rep, req, e)
             return
+        # the first ids are read, so the device has run the dispatch and
+        # its commit: less what the step in flight still had to run
+        # when it went out, that is what it cost the decoding slots
+        self._prefill_observed(
+            bucket, bb, t_pf1 - t_pf0 - rep.joins.in_flight_left(t_pf0))
+        rep.joins.stalled = True
         # element split + FLOPs ledger for this one dispatch: the
         # program computed bb*bucket positions; Σ prompt lengths of
         # them carried real tokens, the rest were batch-row padding
@@ -3200,6 +3248,7 @@ class DecodeEngine(object):
                               "decode:%s" % rep.label, t_pf0,
                               time.perf_counter(),
                               args={"bucket": bucket, "group": len(live),
+                                    "live": rep.joins.decoding,
                                     "tokens": live_elems,
                                     "padded": padded_elems,
                                     "fused_attention": fused,
@@ -3224,6 +3273,46 @@ class DecodeEngine(object):
                 name: rows_all[i][r_i]
                 for i, name in enumerate(rep.program.state_names)}
             self._commit_prefill(rep, req, rows, first[r_i])
+
+    def _prefill_observed(self, bucket, batch, seconds):
+        """Fold what one warm ``(batch, bucket)`` prefill dispatch cost
+        the device into the table ``_seats_now`` decides on: the median
+        of its last five readings.  (A mean keeps a share of a reading
+        that held a pause of the host for as long as the policy then
+        avoids that batch, and so never corrects it.)"""
+        with self._lock:
+            last = self._prefill_readings.setdefault(
+                (bucket, batch), collections.deque(maxlen=5))
+            last.append(max(seconds, 0.0))
+            # a new dict a reading: a scheduler thread reads its
+            # bucket's without the lock
+            costs = dict(self._prefill_cost.get(bucket, ()))
+            costs[batch] = sorted(last)[len(last) // 2]
+            self._prefill_cost[bucket] = costs
+
+    def _seats_now(self, rep, w, head):
+        """How many of the ``w`` requests a free slot of ``rep`` waits
+        for are seated in this iteration (``join_policy.seats_now``),
+        from what the engine has observed: the slots decoding now, the
+        step's time, the rate at which requests become seatable, and
+        what a prefill dispatch of the oldest one's bucket costs by its
+        batch.  ``head`` is that oldest request.  A join that rides the
+        step (no prefill program for its bucket) costs no dispatch and
+        is never held."""
+        st = rep.joins
+        st.seatable(w)
+        n = 0
+        if w:
+            bucket = next((b for b in rep.prefill_buckets
+                           if b >= len(head.prompt)), None) \
+                if rep.prefill_caches else None
+            costs = None if bucket is None else _join_policy.cost_table(
+                self._prefill_cost.get(bucket), self._prefill_grid[bucket])
+            n = _join_policy.seats_now(
+                w, int(rep.valid_np.sum()), st.step_s, st.rate, costs,
+                st.held_steps)
+        st.seated(n, w)
+        return n
 
     def _step_expert_work(self, step_sym, arg_params, token_name,
                           pos_name, valid_name):
@@ -3428,6 +3517,7 @@ class DecodeEngine(object):
             if live:
                 self._steps += 1
                 self._steps_ahead += ahead
+                self._slot_steps_held += rep.joins.held
                 self._step_ms.append(dt_ms)
         if self._tm is not None:
             if new_tokens:
@@ -3445,16 +3535,18 @@ class DecodeEngine(object):
         for slot, reason in leaving:
             self._finish_slot(rep, slot, reason)
 
-    def _step_args(self, live, tokens, dispatch_s, read_s, ahead=0,
+    def _step_args(self, held, live, tokens, dispatch_s, read_s, ahead=0,
                    discarded=0, extras=()):
         """The arguments of one step's ``decode.step`` event (nothing
         with the plane off): the step's counters go in as
-        ``<name>_max`` / ``<name>_mean``."""
+        ``<name>_max`` / ``<name>_mean``.  ``held`` alone is the writing
+        iteration's and not the read step's: the requests a free slot
+        waited for that its decision left in their queue."""
         if self._tl is None:
             return None
         args = {"live": live, "tokens": tokens,
                 "dispatch_ms": dispatch_s * 1e3, "read_ms": read_s * 1e3,
-                "ahead": ahead, "discarded": discarded}
+                "ahead": ahead, "discarded": discarded, "held": held}
         for name, arr in dict(extras).items():
             args[name + "_max"] = float(arr.max())
             args[name + "_mean"] = float(arr.mean())
@@ -3514,9 +3606,11 @@ class DecodeEngine(object):
             # re-route); a hang wedges the pool for the watchdog
             _faults.trip("decode.step", replica=rep.label)
         if self._spec_k:
+            t_spec = time.perf_counter()
             toks_mat, counts, rep.states = rep.program.step_spec(
                 rep.tokens_np, rep.pos_np, rep.valid_np, rep.spec_np,
                 rep.states, reset=rep.reset_np)
+            rep.joins.step_time(time.perf_counter() - t_spec)
             rep.reset_np.fill(0.0)
             if self._eff is not None:
                 # FLOPs ledger, BEFORE the slot advance (a slot that
@@ -3538,7 +3632,7 @@ class DecodeEngine(object):
                     rep, occ, toks_mat, counts)
                 self._booked(rep, len(occ), new_tokens, t0,
                              leaving=leaving)
-            return self._step_args(len(occ), new_tokens,
+            return self._step_args(rep.joins.held, len(occ), new_tokens,
                                    *(rep.program.last_split or (0, 0)))
         if occ:
             # a slot that holds ``FROM_PREVIOUS`` was stepped by the
@@ -3561,17 +3655,21 @@ class DecodeEngine(object):
         else:
             rep.flight = None
         if read is None:
+            # dispatched onto an idle device: the step begins now
+            rep.joins.t_flight = time.perf_counter()
+            rep.joins.stalled = False
             self._booked(rep, len(occ), 0, t0)
             return None
         before, seats, ahead = read
         ids = before.read()
+        rep.joins.step_read(time.perf_counter(), bool(occ))
         with sp.child("decode.step.deliver"):
             new_tokens, discarded, leaving = self._deliver(rep, seats, ids)
             self._booked(rep, len(occ), new_tokens, t0, int(bool(occ)),
                          discarded, leaving)
-        return self._step_args(len(seats), new_tokens, before.dispatch_s,
-                               before.read_s, ahead, discarded,
-                               before.extras)
+        return self._step_args(rep.joins.held, len(seats), new_tokens,
+                               before.dispatch_s, before.read_s, ahead,
+                               discarded, before.extras)
 
     def _seats_ahead(self, rep, occ):
         """The host's half of a dispatch, made before the step's ids
@@ -3990,6 +4088,14 @@ class DecodeEngine(object):
                 # thrown away because their request had left
                 "steps_ahead": self._steps_ahead,
                 "slot_steps_discarded": self._discarded,
+                # seatable requests x steps the join policy left in
+                # their queue for a fuller prefill dispatch, and the
+                # table it decides on: what a dispatch has cost the
+                # device, by bucket and batch (``_seats_now``)
+                "slot_steps_held": self._slot_steps_held,
+                "prefill_cost_ms": {
+                    b: {bb: c * 1e3 for bb, c in sorted(costs.items())}
+                    for b, costs in sorted(self._prefill_cost.items())},
                 "tokens_generated": self._tokens_out,
                 "joins": self._joins,
                 "steals": self._steals,

@@ -56,6 +56,7 @@ import collections
 import time
 
 from ..base import MXNetError
+from .join_policy import JoinState
 
 __all__ = ["replica_contexts", "resolve_replica_placements",
            "ServeReplica", "DecodeReplica", "replica_metric_families"]
@@ -306,7 +307,7 @@ class DecodeReplica(object):
                  "prefill_caches",
                  "prefill_buckets", "slots", "tokens_np", "pos_np",
                  "valid_np", "reset_np", "spec_np", "states", "flight",
-                 "pending", "healthy",
+                 "joins", "pending", "healthy",
                  "accepting", "in_step", "probations", "hb_t", "thread",
                  "tm_step_ms", "tm_failures")
 
@@ -342,6 +343,9 @@ class DecodeReplica(object):
         # out ahead of a read)``, or None.  Its output buffer feeds the
         # slots that generate
         self.flight = None
+        # what this pool's scheduler has observed for the decision when
+        # a join waits (join_policy.py)
+        self.joins = JoinState()
         self.pending = collections.deque()      # routed DecodeRequests
         self.healthy = True
         self.in_step = False

@@ -79,9 +79,11 @@ timeline ring, and keys of `DecodeEngine.stats()["decode"]`.
 | `decode.step` | `live`, `tokens` | slots the step stepped; tokens delivered from it |
 | `decode.step` | `dispatch_ms`, `read_ms` | the step's dispatch, an iteration back; the wait for its ids, after the step after it went out |
 | `decode.step` | `ahead`, `discarded` | 1 if dispatched before the step before it was read; slot results whose request had left |
+| `decode.step` | `held` | requests a free slot waited for that the writing iteration's decision left in their queue, for a prefill dispatch worth its cost (`serving/join_policy.py`); 0 where nothing was held |
 | `decode.step` | `<name>_max`, `<name>_mean` | a counter output of the step graph past its states (`expert_load`: live rows an expert got) |
 | `decode.step` | `expert_products`, `expert_routed` | (row, expert) products the step's `_moe_experts` nodes multiply at pool extent, by the op's own rule for the shapes built (every held expert over every slot on the plain path); `top_k` x live rows, summed over those nodes. Absent where the step has no expert layer |
 | `decode.prefill` | `bucket`, `group`, `tokens`, `padded` | padded prompt length; requests in the dispatch; live prompt positions; batch x bucket |
+| `decode.prefill` | `live` | slots decoding when the join of this dispatch began, which it stopped while it ran; 0 on an empty pool |
 | `decode.prefill` | `fused_attention`, `attention_nodes` | `_gqa_prefill` nodes of the dispatched program that take the fused kernel; how many it has |
 | `decode.prefill` | `row_states`, `cache_states` | states the dispatch's commit laid into the pool: plain rows (replaced whole: recurrent and convolution state) and positional caches (keys or values of every prompt position) |
 | `stats()["decode"]` | `state_rows` | rows of each cache state a slot |
@@ -89,6 +91,8 @@ timeline ring, and keys of `DecodeEngine.stats()["decode"]`.
 | `stats()["decode"]` | `prefill_token_budget`, `prefill_programs` | positions a prefill dispatch may hold; (batch, bucket) programs warmed |
 | `stats()["decode"]` | `steps_ahead`, `slot_steps_discarded` | totals of `ahead` and `discarded` |
 | `stats()["decode"]` | `prefill_fused_attention`, `prefill_attention_nodes` | totals over the prefill dispatches |
+| `stats()["decode"]` | `slot_steps_held` | total of `held` over the steps dispatched: seatable requests x steps left waiting |
+| `stats()["decode"]` | `prefill_cost_ms` | `{bucket: {batch: ms}}`: what a prefill dispatch has cost the device (its span less what the step in flight still had to run), the median of its last five readings over the live dispatches; the table the join decision reads |
 """
 
 
