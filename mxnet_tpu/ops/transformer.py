@@ -2,9 +2,12 @@
 linear map, grouped-query attention against a per-layer cache state
 (one query row a slot) and over a whole prompt (causal, banded: one
 fused Pallas kernel where a TPU can tile it, blockwise XLA elsewhere),
-a gated short convolution against a state of a few rows a slot (one
-step, or a whole padded prompt), and a sparse expert layer that is told
-which experts it holds and by which rule its router's scores choose.
+a short convolution, gated or plain, against a state of a few rows a
+slot (one step, or a whole padded prompt), Mamba-2's selective state
+space against a state of heads x head size x state size a slot (one
+recurrence a step, or a chunked scan over a whole padded prompt), and a
+sparse expert layer that is told which experts it holds and by which
+rule its router's scores choose.
 
 Every op declares what the analysis passes need of it where it is
 written (``row_local``, ``flops``, ``temp_bytes``: ROADMAP D13); the
@@ -13,9 +16,9 @@ shape rule is the implementation under ``jax.eval_shape`` plus
 
 Precision, whatever the storage dtype: a norm's statistics, the rotation,
 attention scores and softmaxes, the router's scores, choice and weights,
-the short convolution's multiply-adds and every product's accumulator
-are float32; results are rounded once to the dtype of the
-activations they join.
+the short convolution's multiply-adds, the state space's recurrence and
+every product's accumulator are float32; results are rounded once to
+the dtype of the activations (or the state) they join.
 """
 from __future__ import annotations
 
@@ -63,15 +66,20 @@ def _gamma_fill(attrs, in_shapes):
 def rms_norm(attrs, data, gamma):
     """``gamma * data / sqrt(mean(data**2, -1) + eps)``; the mean and
     the division in float32, rounded to ``data``'s dtype before the
-    gain is applied.  With ``head_dim`` the last axis is heads of that
-    many values laid side by side, each normed over its own values under
-    the one gain of ``head_dim`` (a query/key norm)."""
+    gain is applied.  With ``head_dim`` the last axis is heads (or
+    groups) of that many values laid side by side, each normed over its
+    own values, under the one gain of ``head_dim`` (a query/key norm)
+    or, where ``gamma`` is as wide as ``data``, a gain a value (a
+    grouped norm)."""
     d = attrs["head_dim"]
     x = data.astype(_acc(data))
+    g = gamma.astype(data.dtype)
     if d:
         x = x.reshape(data.shape[:-1] + (-1, d))
+        if g.shape[-1] != d:
+            g = g.reshape(-1, d)
     inv = lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + attrs["eps"])
-    out = (x * inv).astype(data.dtype) * gamma.astype(data.dtype)
+    out = (x * inv).astype(data.dtype) * g
     return out.reshape(data.shape)
 
 
@@ -481,79 +489,122 @@ def gated_act(attrs, gate, up):
             * up.astype(acc)).astype(gate.dtype)
 
 
-# ------------------------------------------------- gated short convolution
-def _conv_split(proj):
-    """The three blocks ``[B, C, x]`` of an input projection."""
+# ------------------------------------------------------ short convolution
+_CONV_PARAMS = {"taps": P(int, 3), "gated": P(bool, True),
+                "activation": P(str, "none",
+                                choices=["none"] + list(_ACTIVATIONS)),
+                "has_bias": P(bool, False)}
+
+
+def _conv_nin(attrs):
+    return 4 if (attrs or {}).get("has_bias") else 3
+
+
+def _conv_width(attrs, proj_width):
+    """Channels of the convolution: a third of a gated projection
+    ``[B, C, x]``, the whole of a plain one."""
+    return proj_width // 3 if attrs["gated"] else proj_width
+
+
+def _conv_in(attrs, proj, dtype):
+    """``(u, C)``: the convolution's input rounded to ``dtype`` and the
+    gate applied after it (``u = B * x`` and ``C`` of a gated
+    projection; a plain one is ``u`` itself and has no gate)."""
+    if not attrs["gated"]:
+        return proj.astype(dtype), None
     d = proj.shape[-1] // 3
-    return proj[..., :d], proj[..., d:2 * d], proj[..., 2 * d:]
+    b, c, x = proj[..., :d], proj[..., d:2 * d], proj[..., 2 * d:]
+    acc = _acc(proj)
+    return (b.astype(acc) * x.astype(acc)).astype(dtype), c
+
+
+def _conv_out(attrs, y, c, bias, dtype):
+    """The float32 sum over the taps, plus the bias, through the
+    activation, times the gate, rounded once to ``dtype``."""
+    if bias is not None:
+        y = y + bias.astype(y.dtype)
+    if attrs["activation"] != "none":
+        y = _ACTIVATIONS[attrs["activation"]](y)
+    if c is not None:
+        y = c.astype(y.dtype) * y
+    return y.astype(dtype)
 
 
 def _conv_flops(attrs, ins, out):
-    # B * x, a multiply-add a tap, C * y
-    return (2.0 * ins[-1][0] + 2.0) * _prod(ins[0]) / 3.0
+    # a multiply-add a tap; B * x and C * y where gated; the bias; silu
+    per = 2.0 * ins[2][0] + 2.0 * attrs["gated"] + attrs["has_bias"] \
+        + 5.0 * (attrs["activation"] != "none")
+    return per * _prod(ins[0]) / (3.0 if attrs["gated"] else 1.0)
 
 
 def _conv_fill(attrs, in_shapes):
     out = list(in_shapes)
-    if out[0] is not None and out[-1] is None:
-        out[-1] = (attrs["taps"], out[0][-1] // 3)
+    if out[0] is not None:
+        d = _conv_width(attrs, out[0][-1])
+        if out[2] is None:
+            out[2] = (attrs["taps"], d)
+        if len(out) > 3 and out[3] is None:
+            out[3] = (d,)
     return out
 
 
-@register("_short_conv_step", nin=3, nout=2,
-          input_names=["data", "state", "weight"],
-          params={"taps": P(int, 3)}, fill_shapes=_conv_fill,
+@register("_short_conv_step", nin=_conv_nin, nout=2,
+          input_names=["data", "state", "weight", "bias"],
+          params=_CONV_PARAMS, fill_shapes=_conv_fill,
           row_local="axis0", flops=_conv_flops)
-def short_conv_step(attrs, proj, state, weight):
-    """One position a slot of a gated short convolution.
+def short_conv_step(attrs, proj, state, weight, bias=None):
+    """One position a slot of a short causal depthwise convolution.
 
-    ``proj`` ``(slots, 3 * d)`` is the input projection's ``[B, C, x]``;
-    ``state`` ``(slots, taps - 1, d)`` holds ``u = B * x`` of the
-    ``taps - 1`` positions before this one, oldest first (zeros before
-    the sequence's start); ``weight`` ``(taps, d)`` is a tap a row, the
-    last on the current position.  Returns ``C * y`` with ``y =
-    sum_k weight[k] * u_{t - (taps - 1) + k}`` per channel, and the next
+    ``proj`` ``(slots, 3 * d)`` is a gated input projection's ``[B, C,
+    x]`` (``gated``, the default) or ``(slots, d)`` the convolution's
+    own input; ``state`` ``(slots, taps - 1, d)`` holds its input ``u``
+    (``B * x``, or ``proj`` itself) of the ``taps - 1`` positions before
+    this one, oldest first (zeros before the sequence's start);
+    ``weight`` ``(taps, d)`` is a tap a row, the last on the current
+    position, and ``bias`` (with ``has_bias``) ``(d,)``.  Returns
+    ``act(y + bias)``, times ``C`` where gated, with ``y = sum_k
+    weight[k] * u_{t - (taps - 1) + k}`` per channel, and the next
     state: the old one shifted by a row with ``u_t`` behind it.
 
     ``u`` is computed in float32 and rounded once to the state's dtype,
-    the value every later position reads of it; the multiply-adds and
-    the gate are float32, the result rounded once."""
-    b, c, x = _conv_split(proj)
+    the value every later position reads of it; the multiply-adds, the
+    bias, the activation and the gate are float32, the result rounded
+    once."""
+    u, c = _conv_in(attrs, proj, state.dtype)
     acc = _acc(proj)
-    u = (b.astype(acc) * x.astype(acc)).astype(state.dtype)
     held = jnp.concatenate([state, u[:, None]], axis=1)
     w = weight.astype(acc)
     y = sum(held[:, k].astype(acc) * w[k] for k in range(w.shape[0]))
-    return (c.astype(acc) * y).astype(proj.dtype), held[:, 1:]
+    return _conv_out(attrs, y, c, bias, proj.dtype), held[:, 1:]
 
 
 def _conv_seq_temp(attrs, ins, dts):
     # u padded at the front, and the float32 sum over the taps
-    b, t, d3 = ins[0]
-    return b * (t + attrs["taps"]) * (d3 // 3) * (_itemsize(dts[0]) + 4)
+    b, t, width = ins[0]
+    return b * (t + attrs["taps"]) * _conv_width(attrs, width) \
+        * (_itemsize(dts[0]) + 4)
 
 
-@register("_short_conv_seq", nin=3, nout=2,
-          input_names=["data", "length", "weight"],
-          params={"taps": P(int, 3)}, fill_shapes=_conv_fill,
+@register("_short_conv_seq", nin=_conv_nin, nout=2,
+          input_names=["data", "length", "weight", "bias"],
+          params=_CONV_PARAMS, fill_shapes=_conv_fill,
           row_local="axis0", flops=_conv_flops, temp_bytes=_conv_seq_temp)
-def short_conv_seq(attrs, proj, length, weight):
-    """A whole padded prompt of a gated short convolution, in XLA: one
+def short_conv_seq(attrs, proj, length, weight, bias=None):
+    """A whole padded prompt of a short convolution, in XLA: one
     shifted multiply-add a tap (``taps`` passes over ``u``; nothing here
     is bound by them).
 
-    ``proj`` ``(batch, T, 3 * d)``, ``length`` ``(batch,)`` live
-    positions a row, ``weight`` as ``_short_conv_step``.  Returns ``C *
-    y`` at every position (causal, so padding behind a row's length
-    touches no live position) and the state *at each row's own length*:
-    ``u`` of positions ``length - (taps - 1) .. length - 1``, zeros
-    where that is before the start, whatever the padding holds.  The
-    same roundings as the step."""
+    ``proj`` ``(batch, T, width)``, ``length`` ``(batch,)`` live
+    positions a row, ``weight``, ``bias`` and the attributes as
+    ``_short_conv_step``.  Returns the output at every position (causal,
+    so padding behind a row's length touches no live position) and the
+    state *at each row's own length*: ``u`` of positions ``length -
+    (taps - 1) .. length - 1``, zeros where that is before the start,
+    whatever the padding holds.  The same roundings as the step."""
     taps = weight.shape[0]
-    b, c, x = _conv_split(proj)
+    u, c = _conv_in(attrs, proj, proj.dtype)
     acc = _acc(proj)
-    n, t, d = b.shape
-    u = (b.astype(acc) * x.astype(acc)).astype(proj.dtype)
+    n, t, d = u.shape
     front = jnp.concatenate(
         [jnp.zeros((n, taps - 1, d), u.dtype), u], axis=1)
     w = weight.astype(acc)
@@ -563,7 +614,176 @@ def short_conv_seq(attrs, proj, length, weight):
         + jnp.arange(taps - 1, dtype=jnp.int32)[None, :]
     state = jnp.take_along_axis(
         front, jnp.clip(at, 0, t + taps - 2)[:, :, None], axis=1)
-    return (c.astype(acc) * y).astype(proj.dtype), state
+    return _conv_out(attrs, y, c, bias, proj.dtype), state
+
+
+# ------------------------------------------------- selective state space
+def _ssd_dims(attrs, x_shape, dt_shape, b_shape):
+    """``(heads, head size, groups, state size)``: head ``h`` reads
+    group ``h // (heads // groups)`` of ``B`` and ``C``."""
+    h, g = dt_shape[-1], attrs["num_groups"]
+    return h, x_shape[-1] // h, g, b_shape[-1] // g
+
+
+def _ssd_fill(attrs, in_shapes):
+    out = list(in_shapes)
+    if out[1] is not None:
+        for i in (5, 6, 7):
+            if out[i] is None:
+                out[i] = (out[1][-1],)
+    return out
+
+
+def _ssd_rates(a_log, dt, dt_bias, live=None):
+    """``(dt, dt * A)`` in float32: ``dt = softplus(dt + dt_bias)``,
+    nought where ``live`` is false; ``A = -exp(A_log)``."""
+    acc = _acc(dt)
+    rate = jax.nn.softplus(dt.astype(acc) + dt_bias.astype(acc))
+    if live is not None:
+        rate = jnp.where(live, rate, 0.0)
+    return rate, rate * -jnp.exp(a_log.astype(acc))
+
+
+def _ssd_step_flops(attrs, ins, out):
+    # decay, dt x B and the sum a state value; y = S C a multiply-add
+    # a state value; D x
+    h, p, _g, n = _ssd_dims(attrs, ins[0], ins[1], ins[2])
+    return ins[0][0] * (5.0 * h * p * n + 2.0 * h * p)
+
+
+@register("_ssd_step", nin=8, nout=2,
+          input_names=["data", "dt", "B", "C", "state", "A_log", "dt_bias",
+                       "D"],
+          params={"num_groups": P(int, 1)}, fill_shapes=_ssd_fill,
+          row_local="axis0", flops=_ssd_step_flops)
+def ssd_step(attrs, x, dt, b, c, state, a_log, dt_bias, d):
+    """One position a slot of Mamba-2's selective state space.
+
+    ``x`` ``(slots, heads * P)``, ``dt`` ``(slots, heads)`` before its
+    bias, ``B`` and ``C`` ``(slots, groups * N)``, ``state`` ``(slots,
+    heads, P, N)``; ``A_log``, ``dt_bias`` and ``D`` a value a head.
+    With ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)``, head
+    ``h`` of group ``g``::
+
+        S_h = exp(dt_h A_h) S_h + dt_h x_h (outer) B_g
+        y_h = S_h C_g + D_h x_h
+
+    Returns ``y`` in ``x``'s dtype and the next state in the state's.
+    The arithmetic is float32 and the state is rounded once; ``y`` is
+    read off the float32 state, before that rounding."""
+    h, p, g, n = _ssd_dims(attrs, x.shape, dt.shape, b.shape)
+    slots, k = x.shape[0], h // g
+    acc = _acc(x)
+    rate, decay = _ssd_rates(a_log, dt, dt_bias)
+    xs = x.astype(acc).reshape(slots, g, k, p)
+    s = state.astype(acc).reshape(slots, g, k, p, n)
+    s = jnp.exp(decay).reshape(slots, g, k, 1, 1) * s \
+        + (rate.reshape(slots, g, k, 1) * xs)[..., None] \
+        * b.astype(acc).reshape(slots, g, 1, 1, n)
+    y = jnp.einsum("bgkpn,bgn->bgkp", s, c.astype(acc).reshape(slots, g, n)) \
+        + d.astype(acc).reshape(g, k, 1) * xs
+    return (y.reshape(x.shape).astype(x.dtype),
+            s.reshape(state.shape).astype(state.dtype))
+
+
+def _ssd_chunks(attrs, t):
+    q = min(attrs["chunk"], t)
+    return q, -(-t // q)
+
+
+def _ssd_scan_flops(attrs, ins, out):
+    """By whole chunks, as the scan computes them: ``C B^T`` a group and
+    its weights times ``x`` a head (``q x q`` a chunk), each chunk's
+    contribution to the state and the state's to each position (``q x
+    P x N`` a head), the state carried (``P x N`` a head), ``D x``."""
+    b, t, _hp = ins[0]
+    h, p, g, n = _ssd_dims(attrs, ins[0], ins[1], ins[2])
+    q, nc = _ssd_chunks(attrs, t)
+    per_chunk = 2.0 * q * q * (g * n + h * p) + 3.0 * h * q * q \
+        + 4.0 * q * h * p * n + 2.0 * h * p * n + 2.0 * q * h * p
+    return b * nc * per_chunk
+
+
+def _ssd_scan_temp(attrs, ins, dts):
+    """In float32 a chunk's weights ``(batch, heads, q, q)`` twice, the
+    state carried, the chunk's contribution and the state its positions
+    read ``(batch, heads, P, N)``, the chunk's ``x`` and ``y``; ``y`` of
+    every chunk in ``x``'s dtype."""
+    b, t, hp = ins[0]
+    h, p, _g, n = _ssd_dims(attrs, ins[0], ins[1], ins[2])
+    q, nc = _ssd_chunks(attrs, t)
+    return b * (4 * (2 * h * q * q + 3 * h * p * n + 2 * q * hp)
+                + nc * q * hp * _itemsize(dts[0]))
+
+
+@register("_ssd_scan", nin=8, nout=2,
+          input_names=["data", "dt", "B", "C", "length", "A_log",
+                       "dt_bias", "D"],
+          params={"num_groups": P(int, 1), "chunk": P(int, 128)},
+          fill_shapes=_ssd_fill, row_local="axis0", flops=_ssd_scan_flops,
+          temp_bytes=_ssd_scan_temp)
+def ssd_scan(attrs, x, dt, b, c, length, a_log, dt_bias, d):
+    """A whole padded prompt of ``_ssd_step``'s state space from a zero
+    state, in chunks of ``chunk`` positions (Mamba-2's state space
+    duality), in XLA.
+
+    ``x`` ``(batch, T, heads * P)``, ``dt``, ``B``, ``C`` likewise a
+    position, ``length`` ``(batch,)`` live positions a row.  Within a
+    chunk the outputs are two matrix products: ``(C B^T)`` masked to the
+    causal band and weighted by ``dt_j exp(sum of dt A over j < .. <=
+    i)``, times ``x``.  Across chunks one state a head is carried, chunk
+    after chunk (unrolled: a prompt's bucket fixes how many; with the
+    chunks in one ``lax.scan`` a v5e never finished Falcon-H1's prefill
+    program over 8 prompts of 512, which it runs unrolled): each
+    position reads the state the chunk was entered with, decayed to it,
+    and the chunk leaves its own contribution behind.  No state a
+    position exists.  Positions at or past a row's
+    length take ``dt = 0``: they neither decay the state nor feed it,
+    so the state returned is the one *at the row's own length*, whatever
+    the padding holds.  Returns ``y`` at every position in ``x``'s dtype
+    and that state ``(batch, heads, P, N)``, rounded once to ``x``'s
+    dtype; the arithmetic is float32."""
+    h, p, g, n = _ssd_dims(attrs, x.shape, dt.shape, b.shape)
+    bsz, t = x.shape[:2]
+    k = h // g
+    q, nc = _ssd_chunks(attrs, t)
+    acc = _acc(x)
+    live = jnp.arange(t)[None, :] < length.astype(jnp.int32)[:, None]
+    rate, decay = _ssd_rates(a_log, dt, dt_bias, live[..., None])
+
+    def chunked(v, *tail):
+        """``(batch, T, ...)`` -> ``(batch, chunks * q, *tail)``, the
+        padding past ``T`` nought."""
+        v = jnp.pad(v, [(0, 0), (0, nc * q - t)] + [(0, 0)] * (v.ndim - 2))
+        return v.reshape((bsz, nc * q) + tail)
+    ins = (chunked(x, g, k, p), chunked(rate, g, k), chunked(decay, g, k),
+           chunked(b, g, n), chunked(c, g, n))
+    causal = jnp.tril(jnp.ones((q, q), bool))
+    skip = d.astype(acc).reshape(g, k, 1)
+
+    def one_chunk(s, lo):
+        xc, rc, dc, bc, cc = (v[:, lo:lo + q].astype(acc) for v in ins)
+        cum = jnp.cumsum(dc, axis=1)                        # (b, q, g, k)
+        # weight of position j's input at position i of the chunk
+        gap = jnp.moveaxis(cum, 1, -1)[..., :, None] \
+            - jnp.moveaxis(cum, 1, -1)[..., None, :]        # (b, g, k, i, j)
+        w = jnp.exp(jnp.where(causal, gap, -jnp.inf)) \
+            * jnp.einsum("bign,bjgn->bgij", cc, bc)[:, :, None] \
+            * jnp.moveaxis(rc, 1, -1)[..., None, :]
+        y = jnp.einsum("bgkij,bjgkp->bigkp", w, xc) \
+            + jnp.einsum("bign,bgkpn->bigkp", cc, s) * jnp.exp(cum)[..., None]
+        # the chunk's inputs decayed to its end, and the state carried
+        left = jnp.exp(cum[:, -1:] - cum) * rc              # (b, q, g, k)
+        s = jnp.exp(cum[:, -1])[..., None, None] * s \
+            + jnp.einsum("bjgn,bjgk,bjgkp->bgkpn", bc, left, xc)
+        return s, (y + skip * xc).astype(x.dtype)
+
+    s, ys = jnp.zeros((bsz, g, k, p, n), acc), []
+    for lo in range(0, nc * q, q):
+        s, y = one_chunk(s, lo)
+        ys.append(y)
+    y = jnp.concatenate(ys, axis=1).reshape(bsz, nc * q, h * p)[:, :t]
+    return y, s.reshape(bsz, h, p, n).astype(x.dtype)
 
 
 # ------------------------------------------------------------ expert layer

@@ -448,3 +448,95 @@ def test_lfm2_parts_compile_for_v5e(one_chip, case):
     compiled = jax.jit(fn, donate_argnums=donate).lower(
         *_described(args, one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < limit
+
+
+# ---------------------------------------------------------------------------
+# the hybrid decoder: a state space's state beside caches and conv rows
+# ---------------------------------------------------------------------------
+
+def _falcon_cfg():
+    import json
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "falcon-h1-34b-4l-bf16.json")) as f:
+        return json.load(f)
+
+
+def test_falcon_h1_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """``falcon-h1-decode-chat``'s step at its own size: 256 slots, four
+    layers at the published widths in bfloat16, a layer's two caches of
+    1,280 rows, conv row and state space row, the whole pool donated.
+    8.79 GB of weights and a 4.86 GB pool; the state space's update is
+    one loop fusion a layer that reads and writes the state in place, so
+    no float32 copy of the 2.15 GB of state appears among the
+    temporaries."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.executor import build_graph_fn
+    from mxnet_tpu.models import falcon_h1
+    cfg = _falcon_cfg()
+    slots, bf = 256, jnp.bfloat16
+    step, info = falcon_h1.decode_step(cfg, 1280)
+    pool = jax.ShapeDtypeStruct((slots, 1280, 512), bf)
+    monkeypatch.setenv("MXNET_CACHE_SCATTER_IMPL",
+                       _impl_auto_picks_on_tpu(monkeypatch, pool))
+    head = mx.sym.argmax(step[0], axis=1)
+    serve = mx.sym.Group([head] + [step[i] for i in range(1, len(step))])
+    names = serve.list_arguments()
+    fn = build_graph_fn(serve, names, [])
+    shapes = falcon_h1.param_shapes(cfg)
+    states = {i["name"]: (slots,) + tuple(i["shape"]) for i in info}
+    args = [jax.ShapeDtypeStruct(shapes[n], bf) if n in shapes
+            else jax.ShapeDtypeStruct(states[n], bf) if n in states
+            else jax.ShapeDtypeStruct((slots,), jnp.float32)
+            for n in names]
+    jitted = jax.jit(
+        lambda *flat: fn(list(flat), [], jax.random.PRNGKey(0), False)[0],
+        donate_argnums=tuple(names.index(n) for n in states))
+    compiled = jitted.lower(*_described(args, one_chip)).compile()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes == 256 * 4 * (
+        2 * 1280 * 512 + 3 * 5120 + 32 * 128 * 256) * 2
+    assert ma.temp_size_in_bytes < 0.1e9
+    assert _total_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("case", ["scan_32_prompts", "prefill_8_prompts"])
+def test_falcon_h1_prefill_compiles_for_v5e(one_chip, case):
+    """The chunked scan over a dispatch of 32 padded prompts of 512 alone
+    (4 chunks of 128 unrolled, a state carried in float32, no state a
+    position),
+    and the whole prefill program over 8: its temporaries beside 8.79 GB
+    of weights and the 4.86 GB pool leave it room on the chip."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.registry import get_op
+    bf = jnp.bfloat16
+
+    def sds(*shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    if case == "scan_32_prompts":
+        op = get_op("_ssd_scan")
+        fn = op.bound(op.normalize({"num_groups": 2, "chunk": 128}))
+        args = (sds(32, 512, 4096), sds(32, 512, 32), sds(32, 512, 512),
+                sds(32, 512, 512), sds(32, dtype=jnp.float32), sds(32),
+                sds(32), sds(32))
+        # 0.38 GB: a chunk's float32 products and state beside y
+        limit = 0.6e9
+    else:
+        from mxnet_tpu.executor import build_graph_fn
+        from mxnet_tpu.models import falcon_h1
+        cfg = _falcon_cfg()
+        pf = falcon_h1.prefill(cfg)(512)
+        names = pf.list_arguments()
+        graph = build_graph_fn(pf, names, [])
+        shapes = falcon_h1.param_shapes(cfg)
+        args = [sds(*shapes[n]) if n in shapes
+                else sds(8, 512, dtype=jnp.float32) if n == "prompt"
+                else sds(8, dtype=jnp.float32) for n in names]
+
+        def fn(*flat):
+            return graph(list(flat), [], jax.random.PRNGKey(0), False)[0]
+        limit = 1.0e9
+    compiled = jax.jit(fn).lower(*_described(args, one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < limit

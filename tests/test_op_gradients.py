@@ -209,6 +209,15 @@ SPECS["_short_conv_step"] = S(
     lambda: [_u(3, 6), _u(3, 2, 2), _u(3, 2)])
 SPECS["_short_conv_seq"] = S(
     lambda: [_u(2, 5, 6), np.array([5., 2.]), _u(3, 2)], wrt=[0, 2])
+# the selective state space: 2 heads of 2 over a state of 2 in one
+# group; the scan over 5 positions in chunks of 2, rows of lengths 5, 3
+SPECS["_ssd_step"] = S(
+    lambda: [_u(2, 4), _u(2, 2), _u(2, 2), _u(2, 2), _u(2, 2, 2, 2),
+             _u(2), _u(2), _u(2)], {"num_groups": 1})
+SPECS["_ssd_scan"] = S(
+    lambda: [_u(2, 5, 4), _u(2, 5, 2), _u(2, 5, 2), _u(2, 5, 2),
+             np.array([5., 3.]), _u(2), _u(2), _u(2)],
+    {"num_groups": 1, "chunk": 2}, wrt=[0, 1, 2, 3, 5, 6, 7])
 SPECS["Embedding"] = S(lambda: [np.array([0., 2., 1.]), _u(4, 3)],
                        {"input_dim": 4, "output_dim": 3}, wrt=[1])
 

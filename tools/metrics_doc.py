@@ -87,7 +87,7 @@ timeline ring, and keys of `DecodeEngine.stats()["decode"]`.
 | `decode.prefill` | `fused_attention`, `attention_nodes` | `_gqa_prefill` nodes of the dispatched program that take the fused kernel; how many it has |
 | `decode.prefill` | `row_states`, `cache_states` | states the dispatch's commit laid into the pool: plain rows (replaced whole: recurrent and convolution state) and positional caches (keys or values of every prompt position) |
 | `stats()["decode"]` | `state_rows` | rows of each cache state a slot |
-| `stats()["decode"]` | `row_state_bytes` | bytes of one slot's plain rows (the states that are no cache: zeroed at a join, replaced by a prefill) |
+| `stats()["decode"]` | `row_state_bytes` | bytes of one slot's plain rows (the states that are no cache: recurrent rows, convolution rows, a state space's state; zeroed at a join, replaced by a prefill). The benchmark's `ssm_state_step_share` prices what the decode steps read and write of them at the peak HBM rate against the steps' device time |
 | `stats()["decode"]` | `prefill_token_budget`, `prefill_programs` | positions a prefill dispatch may hold; (batch, bucket) programs warmed |
 | `stats()["decode"]` | `steps_ahead`, `slot_steps_discarded` | totals of `ahead` and `discarded` |
 | `stats()["decode"]` | `prefill_fused_attention`, `prefill_attention_nodes` | totals over the prefill dispatches |
