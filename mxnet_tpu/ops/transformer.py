@@ -7,7 +7,8 @@ slot (one step, or a whole padded prompt), Mamba-2's selective state
 space against a state of heads x head size x state size a slot (one
 recurrence a step, or a chunked scan over a whole padded prompt), and a
 sparse expert layer that is told which experts it holds and by which
-rule its router's scores choose.
+rule its router's scores choose (in a decode step on a TPU, one grouped
+Pallas kernel).
 
 Every op declares what the analysis passes need of it where it is
 written (``row_local``, ``flops``, ``temp_bytes``: ROADMAP D13); the
@@ -810,12 +811,86 @@ def _moe_dense(attrs, rows, held):
     return rows * held <= _moe_padded_rows(attrs, rows, held)
 
 
-def moe_products(attrs, shapes):
+def _moe_tile(pairs, held):
+    """Rows of the grouped kernel's tile: a whole bfloat16 sublane tile
+    (16), or twice that where the pairs per held expert fill it twice
+    over (LFM2's step: 1,024 pairs over 32 experts)."""
+    return 32 if pairs >= 32 * held else 16
+
+
+def _moe_grouped_rows(attrs, rows, held):
+    """Rows the grouped kernel may multiply: each held expert's pairs
+    padded to whole tiles, at most ``pairs + held * (tile - 1)``."""
+    pairs = rows * attrs["top_k"]
+    tile = _moe_tile(pairs, held)
+    return (pairs + held * (tile - 1)) // tile * tile
+
+
+# widest tile of an expert's width the kernel holds, in bytes of one of
+# its three matrices (double-buffered: six such blocks in VMEM)
+_GROUPED_BLOCK_BYTES = 4 * 1024 * 1024
+_GROUPED_VMEM_BYTES = 64 * 1024 * 1024
+
+
+def _moe_width_tile(f, d, item):
+    """Rows of an expert's matrices a grid step holds: the widest whole
+    number of 128-row tiles that divides ``f`` within the block bytes."""
+    return max(t for t in range(128, f + 1, 128)
+               if f % t == 0 and (t == 128 or t * d * item
+                                  <= _GROUPED_BLOCK_BYTES))
+
+
+def _moe_grouped_vmem(rows, f, d, item):
+    """VMEM the grouped kernel asks: the rows and the float32 output
+    resident (double-buffered), an expert's gathered rows, their weights
+    and float32 results, and three double-buffered tiles of an expert's
+    matrices."""
+    cap = -(-rows // 32) * 32           # rows an expert holds, whole tiles
+    tf = _moe_width_tile(f, d, item)
+    return 4 * rows * d * 4 + cap * (2 * d * 4 + 128 * 4) + 6 * tf * d * item
+
+
+def moe_groupable(attrs, shapes, dtypes=None, training=False):
+    """Whether ``_moe_experts`` over inputs of these shapes (and dtypes,
+    where known) is the grouped kernel's where the program is lowered
+    for a TPU: an inference trace (``pallas_call`` has no
+    differentiation rule) at a step's size (where the plain path is
+    chosen over the sorted one), the data and the three weights all
+    bfloat16 or all float32, hidden size and expert width in whole
+    128-lane tiles, and what the kernel holds within its VMEM."""
+    if training or len(shapes) < 5:
+        return False
+    rows, (held, f, d) = _moe_rows(shapes), tuple(shapes[2])
+    if not _moe_dense(attrs, rows, held) or d % 128 or f % 128 \
+            or shapes[0][-1] != d:
+        return False
+    item = 2
+    if dtypes is not None:
+        kinds = {jnp.dtype(x) for x in (dtypes[0],) + tuple(dtypes[2:5])}
+        if len(kinds) != 1 or not kinds <= {jnp.dtype(jnp.bfloat16),
+                                            jnp.dtype(jnp.float32)}:
+            return False
+        item = kinds.pop().itemsize
+    return _moe_grouped_vmem(rows, f, d, item) <= _GROUPED_VMEM_BYTES
+
+
+def moe_takes_kernel(attrs, shapes, dtypes=None):
+    """Whether an inference program this process builds runs
+    ``_moe_experts`` over these inputs as the grouped kernel: what the
+    declared ``flops`` and ``temp_bytes`` and the engine's
+    ``expert_products`` counter go by."""
+    return _lowers_for_tpu() and moe_groupable(attrs, shapes, dtypes)
+
+
+def moe_products(attrs, shapes, dtypes=None):
     """(row, expert) products a ``_moe_experts`` node over inputs of
-    these shapes multiplies: every held expert over every row on the
-    plain path, the rows the sorted path may pad its pairs to past it.
+    these shapes multiplies: on the grouped kernel each held expert's
+    pairs padded to whole tiles; every held expert over every row on the
+    plain path; the rows the sorted path may pad its pairs to past it.
     The rows that were routed are ``rows * top_k``."""
     rows, held = _moe_rows(shapes), shapes[2][0]
+    if moe_takes_kernel(attrs, shapes, dtypes):
+        return _moe_grouped_rows(attrs, rows, held)
     if _moe_dense(attrs, rows, held):
         return rows * held
     return _moe_padded_rows(attrs, rows, held)
@@ -823,6 +898,8 @@ def moe_products(attrs, shapes):
 
 def _moe_flops(attrs, ins, out):
     rows, held, f, d = (_moe_rows(ins),) + tuple(ins[2])
+    if moe_takes_kernel(attrs, ins):
+        return 6.0 * _moe_grouped_rows(attrs, rows, held) * f * d
     if _moe_dense(attrs, rows, held):
         return 6.0 * rows * held * f * d
     return 6.0 * rows * attrs["top_k"] * f * d
@@ -831,6 +908,10 @@ def _moe_flops(attrs, ins, out):
 def _moe_temp(attrs, ins, dts):
     rows, held, f, d = (_moe_rows(ins),) + tuple(ins[2])
     item = _itemsize(dts[0])
+    if moe_takes_kernel(attrs, ins, dts):
+        # the rows in float32 and the pairs' order, rows and weights;
+        # gathered rows, act(gate) * up and the results stay in VMEM
+        return rows * d * 4 + rows * attrs["top_k"] * 4 * 4
     if _moe_dense(attrs, rows, held):
         return rows * held * f * (3 * 4 + item)
     padded = _moe_padded_rows(attrs, rows, held)
@@ -882,7 +963,7 @@ def _moe_route(attrs, r, bias):
                   "norm_topk": P(bool, True),
                   "route_scale": P(float, 1.0)},
           fill_shapes=_moe_fill, row_local="leading", flops=_moe_flops,
-          temp_bytes=_moe_temp)
+          temp_bytes=_moe_temp, mode_dependent=True)
 def moe_experts(attrs, data, router, wg, wu, wd, bias=None):
     """Sparse gated-linear experts, dropless, over the experts held here.
 
@@ -900,22 +981,30 @@ def moe_experts(attrs, data, router, wg, wu, wd, bias=None):
     to the whole layer) and the ``(..., experts)`` routing weights, zero
     where an expert was not chosen.
 
-    Two formulations of the one sum, and in both an expert's gated
+    Three formulations of the one sum, and in each an expert's gated
     activation takes its routing weight in float32 before it is rounded
     for the down projection.  Where every held expert over every row is
     no more rows multiplied than the sorted path may pad to (up to 284
-    rows for 64 experts, 6 a row, blocks of 256: a decode step), three
-    plain products against the parameters as stored: at a few dozen rows
-    nearly every expert's weights are read anyway.  Past that (a
-    prefill), the (row, expert) pairs are sorted by expert, each
-    expert's run padded to a multiple of ``block``, and a loop
-    multiplies one block by one expert's weights: no pair is dropped,
-    and the padding is at most ``block`` rows an expert.  On a v5e, a
-    layer at the published widths: 32 rows 1.16 ms plain against 3.14
-    sorted (sorting, gathering and 64 short loop turns); 512 rows 2.48
-    against 3.44, so the rule leaves the plain products early; 8,192
-    rows 16 ms sorted, where the plain products would be 64/6 of the
-    work (40 ms at the peak) and 4.8 GB of activations."""
+    rows for 64 experts, 6 a row, blocks of 256: a decode step), an
+    inference trace lowered for a TPU takes ``moe_grouped`` (chosen by
+    ``moe_groupable`` and the platform at lowering): one Pallas kernel
+    that reads each hit expert's weights once and multiplies only its
+    routed rows, padded to tiles of 16 or 32.  Everything else at that
+    size (training traces, the CPU, other dtypes or widths) takes three
+    plain products against the parameters as stored, every held expert
+    over every row.  Past that size (a prefill), the (row, expert) pairs
+    are sorted by expert, each expert's run padded to a multiple of
+    ``block``, and a loop multiplies one block by one expert's weights:
+    no pair is dropped, and the padding is at most ``block`` rows an
+    expert.  On a v5e, a layer at the published widths in bfloat16 (ms;
+    PERF.md section 5): LFM2's step, 256 rows, 4 of 32: plain 1.18,
+    kernel 0.98 with every expert hit (0.94 of it the kernel, 92 % of
+    the peak HBM rate), 0.87 with 27 hit; SmallThinker's step, 32 rows,
+    6 of 64: plain 1.05, kernel 1.02 with 62 hit, 0.61 with 32;
+    ``ragged_dot`` 2.46 and 1.34; sorted at 32 rows 3.14, at 512 rows
+    3.44 against 2.48 plain, so the rule leaves the plain products
+    early; 8,192 rows 16 ms sorted, where the plain products would be
+    64/6 of the work (40 ms at the peak) and 4.8 GB of activations."""
     blk = attrs["block"]
     lead, d = data.shape[:-1], data.shape[-1]
     n_exp = router.shape[-1]
@@ -930,7 +1019,8 @@ def moe_experts(attrs, data, router, wg, wu, wd, bias=None):
     route = jnp.sum(jax.nn.one_hot(top_i, n_exp, dtype=acc)
                     * w[..., None], axis=1)
     nt = (((1,), (1,)), ((), ()))
-    if _moe_dense(attrs, m, held):
+
+    def plain(x, top_i, w, route, wg, wu, wd):
         local = route[:, first:first + held]
         gate = lax.dot_general(x, wg.reshape(held * f, d), nt,
                                preferred_element_type=acc)
@@ -938,12 +1028,166 @@ def moe_experts(attrs, data, router, wg, wu, wd, bias=None):
                              preferred_element_type=acc)
         act = (act_fn(gate) * up).reshape(m, held, f) \
             * local[:, :, None]
-        y = jnp.dot(act.reshape(m, held * f).astype(x.dtype),
-                    wd.reshape(held * f, d), preferred_element_type=acc)
+        return jnp.dot(act.reshape(m, held * f).astype(x.dtype),
+                       wd.reshape(held * f, d), preferred_element_type=acc)
+
+    def grouped(x, top_i, w, route, wg, wu, wd):
+        return moe_grouped(x, top_i, w, wg, wu, wd, first=first,
+                           activation=attrs["activation"])
+
+    ins = (x, top_i, w, route, wg, wu, wd)
+    if moe_groupable(attrs, [a.shape for a in (data, router, wg, wu, wd)],
+                     [a.dtype for a in (data, router, wg, wu, wd)],
+                     attrs.get("_training", False)):
+        y = lax.platform_dependent(*ins, tpu=grouped, default=plain)
+    elif _moe_dense(attrs, m, held):
+        y = plain(*ins)
     else:
         y = _moe_sorted(x, top_i, w, wg, wu, wd, first, held, blk, act_fn)
     return (y.reshape(lead + (d,)).astype(data.dtype),
             route.reshape(lead + (n_exp,)))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "first", "activation", "tile", "width_tile", "interpret"))
+def moe_grouped(x, top_i, w, wg, wu, wd, first=0, activation="relu",
+                tile=None, width_tile=None, interpret=False):
+    """The step's expert layer as one grouped product, a Pallas TPU
+    kernel: each held expert's weights read from HBM once and multiplied
+    by that expert's routed rows alone.  The weights are those of
+    experts ``first .. first + len(wg) - 1``; routing weights ``w`` are
+    float32, ``top_i`` the chosen experts, ``(rows, top_k)`` each.
+
+    The ``rows x top_k`` (row, expert) pairs are sorted by expert; each
+    pair's row index and routing weight are scalar-prefetched.  Grid
+    ``(expert, width tile)``: a step holds one ``(width_tile, hidden)``
+    tile of the expert's gate, up and down matrices.  An expert's first
+    step gathers its pairs' rows from the resident ``x`` into VMEM;
+    every step multiplies them in ``tile``-row tiles (the last one
+    padded), ``act(x gate^T) * (x up^T)`` in float32, weighted by the
+    pair's routing weight, rounded once for the down product, whose
+    float32 product adds into the expert's rows; its last step adds each
+    pair's float32 result into its row of the resident float32 output,
+    so a row's ``top_k`` results are summed in float32.  An expert no
+    pair chose maps its steps to the blocks of the step before it, so
+    its weights are never fetched.
+
+    The same mathematics as the plain products at the same precision:
+    the sum over a row's experts runs in a float32 add where the plain
+    path runs it in the down product's float32 accumulator."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, d = x.shape
+    k = top_i.shape[1]
+    held, f = wg.shape[:2]
+    pairs = m * k
+    acc = jnp.float32
+    item = jnp.dtype(wg.dtype).itemsize
+    t = tile or _moe_tile(pairs, held)
+    tf = width_tile or _moe_width_tile(f, d, item)
+    nj = f // tf
+    m_cap = -(-m // t) * t          # an expert holds each row at most once
+    act_fn = _ACTIVATIONS[activation]
+
+    # pairs choice-major (all rows' first choice, then their second, ...);
+    # the others' pairs sort last
+    e = top_i.T.reshape(-1) - first
+    mine = jnp.logical_and(e >= 0, e < held)
+    e = jnp.where(mine, e, held).astype(jnp.int32)
+    order = jnp.argsort(e, stable=True).astype(jnp.int32)
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[e].add(1)[:held]
+    starts = jnp.cumsum(sizes) - sizes
+    rows_of = order % m
+    weight_of = w.T.reshape(-1).astype(acc)[order]
+    # the blocks an expert with no pairs maps to: the last tile of the
+    # held expert before it, or the first tile of the first held one
+    idx = jnp.arange(held, dtype=jnp.int32)
+    some = sizes > 0
+    before = lax.cummax(jnp.where(some, idx, -1))
+    blk_e = jnp.where(before >= 0, before,
+                      jnp.argmax(some).astype(jnp.int32))
+    blk_j = jnp.where(some, -1, jnp.where(before >= 0, nj - 1, 0))
+
+    def weights(ei, j, sizes_r, starts_r, blk_e_r, blk_j_r, *_):
+        jj = blk_j_r[ei]
+        return (blk_e_r[ei], jnp.where(jj < 0, j, jj), 0)
+
+    def whole(ei, j, *_):
+        return (0, 0)
+
+    def kernel(sizes_r, starts_r, blk_e_r, blk_j_r, rows_r, weight_r,
+               x_ref, wg_ref, wu_ref, wd_ref, out_ref, xg_ref, wt_ref,
+               y_ref):
+        ei, j = pl.program_id(0), pl.program_id(1)
+        n, at = sizes_r[ei], starts_r[ei]
+
+        @pl.when(jnp.logical_and(ei == 0, j == 0))
+        def _zero():
+            out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+        @pl.when(j == 0)
+        def _gather():
+            def one(i, carry):
+                xg_ref[pl.ds(i, 1), :] = x_ref[pl.ds(rows_r[at + i], 1), :]
+                wt_ref[pl.ds(i, 1), :] = jnp.full((1, 128), weight_r[at + i],
+                                                  acc)
+                return carry
+            lax.fori_loop(0, n, one, 0)
+
+        def one_tile(c, carry):
+            r = pl.ds(pl.multiple_of(c * t, t), t)
+            xb = xg_ref[r, :].astype(wg_ref.dtype)
+            gate = lax.dot_general(xb, wg_ref[...], (((1,), (1,)), ((), ())),
+                                   preferred_element_type=acc)
+            up = lax.dot_general(xb, wu_ref[...], (((1,), (1,)), ((), ())),
+                                 preferred_element_type=acc)
+            a = act_fn(gate) * up * wt_ref[r, :][:, :1]
+            y = jnp.dot(a.astype(xb.dtype), wd_ref[...],
+                        preferred_element_type=acc)
+
+            @pl.when(j == 0)
+            def _first():
+                y_ref[r, :] = y
+
+            @pl.when(j > 0)
+            def _more():
+                y_ref[r, :] += y
+            return carry
+
+        lax.fori_loop(0, (n + t - 1) // t, one_tile, 0)
+
+        @pl.when(j == nj - 1)
+        def _scatter():
+            def one(i, carry):
+                row = pl.ds(rows_r[at + i], 1)
+                out_ref[row, :] += y_ref[pl.ds(i, 1), :]
+                return carry
+            lax.fori_loop(0, n, one, 0)
+
+    w_spec = pl.BlockSpec((None, tf, d), weights)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(held, nj),
+            in_specs=[pl.BlockSpec((m, d), whole), w_spec, w_spec, w_spec],
+            out_specs=pl.BlockSpec((m, d), whole),
+            scratch_shapes=[pltpu.VMEM((m_cap, d), acc),
+                            pltpu.VMEM((m_cap, 128), acc),
+                            pltpu.VMEM((m_cap, d), acc)]),
+        out_shape=jax.ShapeDtypeStruct((m, d), acc),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_GROUPED_VMEM_BYTES),
+        cost_estimate=pl.CostEstimate(
+            flops=6 * _moe_grouped_rows({"top_k": k}, m, held) * f * d,
+            transcendentals=pairs * f,
+            bytes_accessed=3 * held * f * d * item + 2 * m * d * 4),
+        name="moe_grouped",
+        interpret=interpret,
+    )(sizes, starts.astype(jnp.int32), blk_e, blk_j.astype(jnp.int32),
+      rows_of, weight_of, x.astype(acc), wg, wu, wd)
 
 
 def _moe_sorted(x, top_i, w, wg, wu, wd, first, held, blk, act_fn):

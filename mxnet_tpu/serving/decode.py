@@ -3319,9 +3319,10 @@ class DecodeEngine(object):
         """``(products, top_k)`` of the step graph's ``_moe_experts``
         nodes: the (row, expert) products they multiply a step by the
         op's own rule for the shapes built (``ops/transformer.py``
-        ``moe_products``: every held expert over every slot on the
-        plain path), and the experts a row is routed to, both summed
-        over the nodes.  None where the step has none."""
+        ``moe_products``: each held expert's pairs padded to whole
+        tiles on the grouped kernel, every held expert over every slot
+        on the plain path), and the experts a row is routed to, both
+        summed over the nodes.  None where the step has none."""
         from ..analysis.shapes import node_inputs
         from ..ops.transformer import moe_products
         try:
@@ -3336,7 +3337,7 @@ class DecodeEngine(object):
             nodes = []
         if not nodes:
             return None
-        return (sum(moe_products(a, s) for a, s, _d in nodes),
+        return (sum(moe_products(a, s, d) for a, s, d in nodes),
                 sum(a["top_k"] for a, _s, _d in nodes))
 
     def _fused_attention(self, rep, bucket, bb):
