@@ -272,3 +272,24 @@ def deprecated(msg):
         wrapper.__doc__ = fn.__doc__
         return wrapper
     return deco
+
+
+def named_program(fn, name):
+    """``fn`` under the name ``name``, for ``jax.jit``: the compiled
+    program is then ``jit_<name>``, which is what the device's trace
+    calls each of its runs (the ``XLA Modules`` line of a TPU plane).
+    A wrapper, and not ``fn`` renamed, so that a bound method (an
+    exported program's ``call``) takes a name too; it keeps ``fn``'s
+    signature for ``static_argnames`` and sets no ``__wrapped__``,
+    which JAX would follow back to ``fn``'s own name.  The compiled
+    code is the same; a dispatch never calls the wrapper."""
+    import inspect
+
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+    program.__name__ = program.__qualname__ = name
+    try:
+        program.__signature__ = inspect.signature(fn)
+    except (TypeError, ValueError):
+        pass
+    return program

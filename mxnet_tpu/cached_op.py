@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import contextlib
 
-from .base import MXNetError
+from .base import MXNetError, named_program
 from . import autograd
 from . import random as _random
 from .ndarray.ndarray import NDArray, _wrap
@@ -25,9 +25,14 @@ __all__ = ["CachedOp"]
 
 
 class CachedOp:
-    def __init__(self, sym, flags=None):
+    """``program`` names the compiled programs (``jit_<program>`` in
+    the device's trace): the owner's kind of program, the same for
+    every shape it compiles."""
+
+    def __init__(self, sym, flags=None, program="mx_cached_op"):
         self._sym = sym
         self._flags = dict(flags or {})
+        self._program = program
         self.arg_names = sym.list_arguments()
         self.aux_names = sym.list_auxiliary_states()
         self.input_names = sym.list_inputs()  # args + aux, topo order
@@ -91,7 +96,7 @@ class CachedOp:
                 outs, new_aux = g(args, aux, key, training)
                 return tuple(outs) + tuple(new_aux)
 
-            fn = jax.jit(call)
+            fn = jax.jit(named_program(call, self._program))
             self._jit[training] = fn
         return fn
 

@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import numpy as _np
 
-from .base import MXNetError
+from .base import MXNetError, named_program
 from .context import Context, current_context
 from . import random as _random
 from .ndarray.ndarray import NDArray, _wrap
@@ -392,7 +392,7 @@ class Executor:
                 _count_xla_trace()  # side effect: once per compile
                 return g(a, x, k, training)
 
-            fn = jax.jit(fwd)
+            fn = jax.jit(named_program(fwd, "mx_forward"))
             self._fwd_jit[training] = fn
         return fn
 
@@ -496,10 +496,13 @@ class Executor:
                 return outs, new_aux, tuple(new_grads)
 
             if with_head_grads:
-                fn = jax.jit(fwd_bwd, donate_argnums=(4,))
+                fn = jax.jit(named_program(fwd_bwd, "mx_forward_backward"),
+                             donate_argnums=(4,))
             else:
                 fn = jax.jit(
-                    lambda a, x, k, og: fwd_bwd(a, x, k, None, og),
+                    named_program(
+                        lambda a, x, k, og: fwd_bwd(a, x, k, None, og),
+                        "mx_forward_backward"),
                     donate_argnums=(3,))
             self._fwd_bwd_jit[with_head_grads] = fn
         return fn
@@ -579,21 +582,31 @@ class Executor:
         _count_dispatch("forward_backward")
         fn = self._get_fwd_bwd(out_grads is not None)
         grad_names = self._grad_names
-        old = tuple(self.grad_dict[n]._data for n in self._dense_grad_names)
         with _timeline.span("executor.forward_backward", "executor",
                             "executor",
                             chrome=("forward_backward", "backward"),
                             tl=self._tl):
-            if out_grads is None:
-                outs, new_aux, new_grads = fn(self._arg_vals(),
-                                              self._aux_vals(), key, old)
-            else:
-                if isinstance(out_grads, NDArray):
-                    out_grads = [out_grads]
-                head = tuple(o._data for o in out_grads)
-                outs, new_aux, new_grads = fn(self._arg_vals(),
-                                              self._aux_vals(), key, head,
-                                              old)
+            with _timeline.part("executor.args", "executor", "executor",
+                                self._tl):
+                old = tuple(self.grad_dict[n]._data
+                            for n in self._dense_grad_names)
+                args = (self._arg_vals(), self._aux_vals(), key)
+                if out_grads is not None:
+                    if isinstance(out_grads, NDArray):
+                        out_grads = [out_grads]
+                    args += (tuple(o._data for o in out_grads),)
+            with _timeline.part("executor.call", "executor", "executor",
+                                self._tl):
+                outs, new_aux, new_grads = fn(*args, old)
+            with _timeline.part("executor.outputs", "executor", "executor",
+                                self._tl):
+                self._write_back(outs, new_aux, grad_names, new_grads)
+        self._pending_train_fwd = False
+        self._pending_key = None
+
+    def _write_back(self, outs, new_aux, grad_names, new_grads):
+        """A forward-and-backward dispatch's results into the outputs,
+        the auxiliary states and the gradient arrays."""
         self._set_outputs(outs)
         for n, a in zip(self.aux_names, new_aux):
             self.aux_dict[n]._data = a
@@ -613,8 +626,6 @@ class Executor:
                          "indices": _wrap(gv.indices, self._ctx)}, gv.shape)
             else:
                 self.grad_dict[n]._data = gv
-        self._pending_train_fwd = False
-        self._pending_key = None
 
     def _materialize_pending(self):
         if self._pending_train_fwd and not getattr(self, "_materialized", True):

@@ -25,7 +25,7 @@ import pickle
 
 import numpy
 
-from .base import MXNetError
+from .base import MXNetError, named_program
 from .ndarray import NDArray, zeros, ones, full, invoke
 from .ndarray import sgd_update, sgd_mom_update, mp_sgd_update, \
     mp_sgd_mom_update, adam_update, rmsprop_update, rmspropalex_update, \
@@ -383,7 +383,8 @@ def _multi_sgd_jit():
     holds may alias a weight or a momentum, and must outlive the step."""
     import jax
     from .ops.optimizer_ops import multi_sgd_update
-    return jax.jit(multi_sgd_update, static_argnames=("clip_gradient",))
+    return jax.jit(named_program(multi_sgd_update, "mx_update_multi_sgd"),
+                   static_argnames=("clip_gradient",))
 
 
 @register

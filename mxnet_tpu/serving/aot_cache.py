@@ -570,8 +570,13 @@ def resolve_kernel(cache, jit_fn, kind, graph, args, meta_extra=None,
                    "AOT cache: loading a %s entry failed (%r); "
                    "compiling fresh" % (kind, e))
         exported = None
+    # hit and miss serve under the name of ``jit_fn``'s program, so
+    # that the device's trace calls a loaded program what it calls a
+    # freshly compiled one
+    from ..base import named_program
+    name = getattr(jit_fn, "__name__", "call")
     if exported is not None:
-        return jax.jit(exported.call,
+        return jax.jit(named_program(exported.call, name),
                        donate_argnums=donate_argnums), "hit"
     try:
         from jax import export as jexport
@@ -591,7 +596,8 @@ def resolve_kernel(cache, jit_fn, kind, graph, args, meta_extra=None,
         # policy the key never contained)
         extra["policy"] = {}
     cache.store(key, payload, extra)
-    return jax.jit(exp.call, donate_argnums=donate_argnums), "miss"
+    return jax.jit(named_program(exp.call, name),
+                   donate_argnums=donate_argnums), "miss"
 
 
 # --------------------------------------------------------------------------

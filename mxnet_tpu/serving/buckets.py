@@ -142,7 +142,7 @@ class ProgramCache(object):
 
     def __init__(self, symbol, arg_params, aux_params, data_names,
                  ctx=None, dtype=np.float32, aot=None, aot_kind="serve",
-                 plan=None):
+                 plan=None, program="mx_cached_op"):
         from ..context import cpu
         self._ctx = ctx or cpu()
         # model-parallel serving (parallel/mesh.py ShardingPlan): with a
@@ -193,7 +193,8 @@ class ProgramCache(object):
                         and n not in self._label_names}
         self._aux = {n: _upload(aux_params or {}, n)
                      for n in aux_names}
-        self._op = CachedOp(symbol)
+        # ``program``: the name of every bucket's compiled program
+        self._op = CachedOp(symbol, program=program)
         # flat-input template in the kernel's order (args then aux):
         # params/aux slots hold their device-resident jax array once,
         # data and label slots are filled per shape key / per dispatch —

@@ -123,7 +123,7 @@ from concurrent.futures import Future
 
 import numpy as np
 
-from ..base import MXNetError
+from ..base import MXNetError, named_program
 from .. import telemetry as _telemetry
 from ..telemetry import goodput as _goodput
 from . import faults as _faults
@@ -818,7 +818,11 @@ class StepProgram(object):
         # program (resolve_kernel donate_argnums) — the in-place HBM
         # slot-pool update must hold whether the program was traced
         # fresh or loaded from disk.
-        self._jit_kernel = jax.jit(call, donate_argnums=donate)
+        # the program's name is what the device's trace calls its runs
+        self._jit_kernel = jax.jit(
+            named_program(call, "mx_decode_step" if self._spec is None
+                          else "mx_decode_spec_step"),
+            donate_argnums=donate)
         self._donate = donate
         self._kernel = None if self._aot is not None else self._jit_kernel
         # the lazy resolution can be reached from two threads at once
@@ -882,7 +886,8 @@ class StepProgram(object):
         # copy of a state (a caller rebinds the dict ``write_row``
         # returns; the one it passed holds consumed buffers)
         self._set_row_jit = jax.jit(
-            set_row, donate_argnums=(0,) if donate else ())
+            named_program(set_row, "mx_decode_set_row"),
+            donate_argnums=(0,) if donate else ())
         self._row_kernels = {}
         self._jnp = jnp
 
@@ -898,7 +903,7 @@ class StepProgram(object):
         # program a (batch, prompt bucket) shape (``commit_prefill``),
         # resolved through the AOT cache like the row kernels
         self._commit_donate = tuple(range(2, 2 + n_s)) if donate else ()
-        self._commit_jit = jax.jit(commit,
+        self._commit_jit = jax.jit(named_program(commit, "mx_decode_commit"),
                                    donate_argnums=self._commit_donate)
         self._commit_kernels = {}
 
@@ -1001,8 +1006,9 @@ class StepProgram(object):
         ``pos mod window``, will look for it.  Batch rows are written
         last to first, so a dead row of a padded batch is given the
         slot and length of row 0 and is overwritten by it."""
-        jnp = self._jnp
-        args = [jnp.asarray(slots, jnp.int32), jnp.asarray(lens, jnp.int32)] \
+        # the slots and lengths go in as host int32 vectors: converted on
+        # the device they would be two small programs of their own
+        args = [np.asarray(slots, np.int32), np.asarray(lens, np.int32)] \
             + [states[n] for n in self.state_names] + list(rows)
         kernel = self._commit_jit
         if self._aot is not None:
@@ -2103,7 +2109,7 @@ class DecodeEngine(object):
             wrapped, arg_params, aux_params,
             data_names=[self._prefill_data_name, self._prefill_len_name],
             ctx=ctx, dtype=dtype, aot=self._aot, aot_kind="prefill",
-            plan=plan)
+            plan=plan, program="mx_decode_prefill")
 
     # ---------------------------------------------------------- preflight
     def _preflight(self, step_sym, which, token_name, pos_name,
@@ -3185,24 +3191,33 @@ class DecodeEngine(object):
             return
         bb = next(b for b in self._prefill_grid.get(
             bucket, self._prefill_batches) if b >= len(live))
-        arr = np.zeros((bb, bucket), np.float32)
-        lens = np.zeros((bb,), np.float32)
-        for r_i, req in enumerate(live):
-            plen = len(req.prompt)
-            arr[r_i, :plen] = req.prompt
-            lens[r_i] = plen
-        fused, attn_nodes = self._fused_attention(rep, bucket, bb)
-        # the states this dispatch's commit lays, of either kind
-        n_caches = len(rep.program.layout.caches("target"))
-        n_rows = len(rep.program.layout.target) - n_caches
+        lane = "decode:%s" % rep.label
         t_pf0 = time.perf_counter()
         ann = (self._tl.annotate("decode.prefill") if self._tl is not None
                else _telemetry.timeline.NO_SPAN)
+
+        def part(name):
+            return _telemetry.timeline.part(name, "decode", lane, self._tl)
         try:
             with ann:
-                outs = rep.prefill_caches[bucket].dispatch({
-                    self._prefill_data_name: arr,
-                    self._prefill_len_name: lens})
+                with part("decode.prefill.pad"):
+                    arr = np.zeros((bb, bucket), np.float32)
+                    lens = np.zeros((bb,), np.float32)
+                    for r_i, req in enumerate(live):
+                        plen = len(req.prompt)
+                        arr[r_i, :plen] = req.prompt
+                        lens[r_i] = plen
+                    fused, attn_nodes = self._fused_attention(rep, bucket,
+                                                              bb)
+                    # the states this dispatch's commit lays, of either
+                    # kind
+                    n_caches = len(rep.program.layout.caches("target"))
+                    n_rows = len(rep.program.layout.target) - n_caches
+                t_disp = time.perf_counter()
+                with part("decode.prefill.dispatch"):
+                    outs = rep.prefill_caches[bucket].dispatch({
+                        self._prefill_data_name: arr,
+                        self._prefill_len_name: lens})
                 with self._lock:
                     self._prefill_dispatches += 1
                     self._prefill_fused_attention += fused
@@ -3215,14 +3230,16 @@ class DecodeEngine(object):
                     plens = [len(live[0].prompt)] * bb
                     for r_i, req in enumerate(live):
                         slots[r_i], plens[r_i] = req.slot, len(req.prompt)
-                    rep.states = rep.program.commit_prefill(
-                        rep.states, outs[1:], slots, plens)
-                if self._sampler.greedy:
-                    first = np.asarray(outs[0])
-                else:
-                    first = rep.program.sample_tokens(outs[0])
-                rows_all = None if on_device \
-                    else [np.asarray(o) for o in outs[1:]]
+                    with part("decode.prefill.commit"):
+                        rep.states = rep.program.commit_prefill(
+                            rep.states, outs[1:], slots, plens)
+                with part("decode.prefill.read"):
+                    if self._sampler.greedy:
+                        first = np.asarray(outs[0])
+                    else:
+                        first = rep.program.sample_tokens(outs[0])
+                    rows_all = None if on_device \
+                        else [np.asarray(o) for o in outs[1:]]
                 t_pf1 = time.perf_counter()
         except Exception as e:
             for req in live:
@@ -3232,7 +3249,7 @@ class DecodeEngine(object):
         # its commit: less what the step in flight still had to run
         # when it went out, that is what it cost the decoding slots
         self._prefill_observed(
-            bucket, bb, t_pf1 - t_pf0 - rep.joins.in_flight_left(t_pf0))
+            bucket, bb, t_pf1 - t_disp - rep.joins.in_flight_left(t_disp))
         rep.joins.stalled = True
         # element split + FLOPs ledger for this one dispatch: the
         # program computed bb*bucket positions; Σ prompt lengths of
@@ -3244,8 +3261,7 @@ class DecodeEngine(object):
             self._tm.prefill_elems(bucket, live_elems,
                                    padded_elems - live_elems)
         if self._tl is not None:
-            self._tl.complete("decode.prefill", "decode",
-                              "decode:%s" % rep.label, t_pf0,
+            self._tl.complete("decode.prefill", "decode", lane, t_pf0,
                               time.perf_counter(),
                               args={"bucket": bucket, "group": len(live),
                                     "live": rep.joins.decoding,
@@ -3307,7 +3323,8 @@ class DecodeEngine(object):
                            if b >= len(head.prompt)), None) \
                 if rep.prefill_caches else None
             costs = None if bucket is None else _join_policy.cost_table(
-                self._prefill_cost.get(bucket), self._prefill_grid[bucket])
+                self._prefill_cost.get(bucket),
+                self._prefill_grid.get(bucket, self._prefill_batches))
             n = _join_policy.seats_now(
                 w, int(rep.valid_np.sum()), st.step_s, st.rate, costs,
                 st.held_steps)

@@ -656,7 +656,8 @@ class ServingEngine(object):
         for i, (rctx, rplan) in enumerate(placements):
             cache = ProgramCache(self._serve_sym, arg_params, aux_params,
                                  data_names, ctx=rctx, dtype=dtype,
-                                 aot=self._aot, plan=rplan)
+                                 aot=self._aot, plan=rplan,
+                                 program="mx_serve_batch")
             self._replicas.append(ServeReplica(i, rctx, cache,
                                                plan=rplan))
         self._cache = self._replicas[0].cache   # single-replica alias
@@ -1642,7 +1643,8 @@ class ServingEngine(object):
             cache = ProgramCache(self._serve_sym, c["arg_params"],
                                  c["aux_params"], c["data_names"],
                                  ctx=r.ctx, dtype=self._dtype,
-                                 aot=self._aot, plan=r.plan)
+                                 aot=self._aot, plan=r.plan,
+                                 program="mx_serve_batch")
             probe_key = None
             for key in sorted(keys):
                 feeds = {name: np.zeros(shape,

@@ -65,7 +65,8 @@ import time
 __all__ = [
     "enabled", "get", "reset", "wall_anchor", "wall_of_perf",
     "wall_of_mono", "Timeline", "export_chrome_trace",
-    "complete", "instant", "counter", "lock_feed", "span", "NO_SPAN",
+    "complete", "instant", "counter", "lock_feed", "span", "part",
+    "NO_SPAN",
 ]
 
 # one anchor, captured back-to-back at import: converts the monotonic
@@ -339,6 +340,14 @@ class _NoSpan(object):
 NO_SPAN = _NoSpan()
 
 
+def part(name, cat, lane, tl):
+    """A :class:`span` for one part of a hot site's interval, at a site
+    that caches its ring (``tl``): an ``mx:`` annotation and a ring
+    event that nest inside the enclosing span's; with the plane off
+    (``tl`` None) :data:`NO_SPAN`, so no clock is read."""
+    return NO_SPAN if tl is None else span(name, cat, lane, tl=tl)
+
+
 # ---------------------------------------------------------------- singleton
 
 _TL = None
@@ -349,7 +358,8 @@ def get():
     """The process-wide timeline (created on first use; capacity from
     ``MXNET_TELEMETRY_TIMELINE_CAP``).  Callers cache the result in
     the ``self._tl = timeline.get() if timeline.enabled() else None``
-    idiom so disabled runs hold no reference at all."""
+    idiom so disabled runs hold no reference at all.  Creating it
+    hooks Python's heap collections (:class:`_GcSpan`)."""
     global _TL
     tl = _TL
     if tl is None:
@@ -357,6 +367,7 @@ def get():
             if _TL is None:
                 from .. import config
                 _TL = Timeline(config.get("MXNET_TELEMETRY_TIMELINE_CAP"))
+                _GC_SPAN.install()
             tl = _TL
     return tl
 
@@ -374,6 +385,54 @@ def reset():
     global _TL
     with _TL_LOCK:
         _TL = None
+
+
+# ---------------------------------------------------------------- py.gc
+
+class _GcSpan(object):
+    """Each collection of Python's heap as the span ``py.gc``, from a
+    ``gc.callbacks`` pair: an ``mx:py.gc`` annotation from ``start`` to
+    ``stop`` (so a profiler's trace shows the host's pause beside the
+    device), and a ring event, with the collection's ``generation`` and
+    ``collected``, for one of ``MIN_S`` or more.  Installed once a
+    process, when the ring is first made, and idle while there is no
+    ring; a ``stop`` closes only the ``start`` of its own thread, and a
+    ``start`` while one is open is left alone."""
+    MIN_S = 1e-3
+
+    def __init__(self):
+        self._thread = None
+        self._ann = None
+        self._t0 = 0.0
+
+    def install(self):
+        import gc
+        if self not in gc.callbacks:
+            _annotation("mx:py.gc")     # the profiler's import, not here
+            gc.callbacks.append(self)
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            if self._thread is not None or _TL is None:
+                return
+            self._thread = threading.get_ident()
+            self._ann = _annotation("mx:py.gc")
+            self._ann.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        if self._thread != threading.get_ident():
+            return
+        t1 = time.perf_counter()
+        self._ann.__exit__(None, None, None)
+        self._ann = self._thread = None
+        tl = _TL
+        if tl is not None and t1 - self._t0 >= self.MIN_S and enabled():
+            tl.complete("py.gc", "python", "python", self._t0, t1,
+                        args={"generation": info.get("generation"),
+                              "collected": info.get("collected")})
+
+
+_GC_SPAN = _GcSpan()
 
 
 # -- module-level feeds for sites that cannot hold a reference -------------

@@ -414,3 +414,35 @@ def test_replica_loop_decides_the_same_from_pending(monkeypatch, decisions):
             assert len(f.result(timeout=120).tokens) == 60
     finally:
         eng.close()
+
+
+def test_a_bucket_outside_the_warm_grid_is_decided_on_the_ladder():
+    """A prefill bucket the warm grid does not hold (buckets swapped on a
+    running engine) is decided over the engine's batch ladder, the one
+    ``_prefill_group`` sizes its dispatch from: a joiner that arrives
+    while a slot decodes is dispatched, and the decision fails nobody."""
+    step, params, state_info = _lstm_step()
+    eng = DecodeEngine(step, params, {}, state_info, num_slots=2,
+                       max_len=64, default_deadline_ms=0)
+
+    class _Boom(object):
+        compile_count = 0
+
+        def dispatch(self, feeds):
+            raise RuntimeError("prefill boom")
+    try:
+        eng.warmup()
+        hog = eng.submit([1], max_new_tokens=40,
+                         on_token=lambda _tok: time.sleep(0.01))
+        _wait(lambda: eng.stats()["decode"]["tokens_generated"] >= 2)
+        assert 64 not in eng._prefill_grid
+        eng._prefill_buckets = (64,)
+        eng._prefill_caches = {64: _Boom()}
+        bad = eng.submit([2], max_new_tokens=3)
+        with pytest.raises(RuntimeError, match="prefill boom"):
+            bad.result(timeout=60)
+        eng._prefill_buckets = ()
+        eng._prefill_caches = {}
+        assert len(hog.result(timeout=120)) == 40
+    finally:
+        eng.close(drain=False)
